@@ -9,9 +9,9 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from _bootstrap import force_cpu_if_requested
+from _bootstrap import virtual_cpu_devices
 
-force_cpu_if_requested(virtual_devices=8)
+virtual_cpu_devices(8)
 
 import numpy as np
 

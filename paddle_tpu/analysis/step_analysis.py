@@ -6,11 +6,13 @@ the SOURCE linter cannot see, because it only exists after lowering:
 
 * **Donation coverage** — which donated buffers actually aliased an
   output in the compiled executable. This catches the PR-2 bug
-  mechanically: on jax 0.4.x a persistent-cache-served donating
-  executable can silently drop (or mismatch) its input/output aliasing
-  map — bit-correct results, 25% slower serving, and a step-corruption
-  hazard. The check compiles through the SAME cache path the runtime
-  uses, so a poisoned cache entry is visible here.
+  mechanically: on jax 0.4.37 a persistent-cache-served donating
+  executable could silently drop (or mismatch) its input/output
+  aliasing map — bit-correct results, 25% slower serving, and a
+  step-corruption hazard. jax 0.9.0 keeps the map (probed on the chip
+  and the CPU, PR 21), and the check stays as the guard: it compiles
+  through the SAME cache path the runtime uses, so a poisoned cache
+  entry is visible here.
 
 * **Dtype promotions** — every `convert_element_type` in the program,
   with the silent upcasts (bf16→f32, f16→f32, f32→f64) split out and
@@ -21,6 +23,11 @@ the SOURCE linter cannot see, because it only exists after lowering:
 * **Host callbacks / transfers** — `*_callback`, infeed/outfeed
   primitives in the step body. A compiled hot-path step should have
   none; each one is a per-step device↔host round trip.
+
+* **Kernel path** — the custom-call targets of the lowered program
+  (`custom_calls`): a Pallas kernel Mosaic compiled shows up as
+  `tpu_custom_call`, so a chip run can say whether the flash / paged
+  kernel ran or the jnp path did.
 
 * **Retrace hazards** — weak-typed inputs (python scalars riding as
   jit arguments hash differently from committed arrays — one stray
@@ -78,6 +85,10 @@ class StepReport:
     # {"n_collectives", "executions", "per_axis_bytes",
     # "per_axis_counts"} — {} when the program has no collectives
     collectives: dict = dataclasses.field(default_factory=dict)
+    # custom-call targets in the lowered program, {target: count} —
+    # which kernel path the step took: a Pallas kernel compiled by
+    # Mosaic is `tpu_custom_call`; the jnp path has none
+    custom_calls: dict = dataclasses.field(default_factory=dict)
 
     def ok(self):
         return not self.findings
@@ -208,7 +219,7 @@ def donation_coverage(jitfn, args, donate_argnums, names=None,
     try:
         kept = sorted(lowered._lowering.compile_args["kept_var_idx"])
     except (AttributeError, KeyError, TypeError):
-        pass                      # older jax: numbering is already flat
+        pass          # private attribute moved: treat numbering as flat
     if kept is not None:
         aliased_flat = {kept[j] for j in aliased_params
                         if j < len(kept)}
@@ -271,11 +282,13 @@ def analyze_jit(jitfn, args, donate_argnums=(), kind="jit", names=None,
             if getattr(a, "weak_type", False)]
     sig = _signature(closed.in_avals)
 
+    # traced.lower() reuses the trace above — one trace, not two
+    lowered = traced.lower()
+    custom_calls = dict(Counter(re.findall(
+        r'custom_call\s*@([\w.$-]+)', lowered.as_text())))
     if check_donation and donate_argnums:
-        # traced.lower() reuses the trace above — one trace, not two
         donation = donation_coverage(jitfn, args, donate_argnums,
-                                     names=names,
-                                     lowered=traced.lower())
+                                     names=names, lowered=lowered)
     else:
         donation = {"expected": 0, "aliased": 0, "held": True,
                     "dropped": []}
@@ -325,7 +338,8 @@ def analyze_jit(jitfn, args, donate_argnums=(), kind="jit", names=None,
                       conversions=conversions, promotions=promotions,
                       host_calls=dict(host_calls),
                       weak_type_args=weak, signature=sig,
-                      findings=findings, collectives=collectives)
+                      findings=findings, collectives=collectives,
+                      custom_calls=custom_calls)
 
 
 def _analyze_trainstep(step, batch, check_donation):
@@ -366,12 +380,6 @@ def _analyze_dist_trainstep(step, batch, check_donation):
                   for b in batch]
     if step._compiled is None:
         step._build(batch_vals)
-    if not hasattr(step._compiled, "trace"):
-        raise TypeError(
-            "analyze_step: this DistributedTrainStep was checkpoint-"
-            "restored onto an AOT executable (shape-frozen, compiled "
-            "outside the persistent cache) — analyze it before "
-            "restore, or rebuild")
     # the step's OWN layout helpers (parallel_step._step_args /
     # _donate_argnums / _STEP_ARG_NAMES) — one definition shared with
     # __call__, so probe-vs-runtime drift can't defeat the guard

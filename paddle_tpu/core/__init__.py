@@ -1,4 +1,3 @@
-from . import jax_compat  # noqa: F401  (must run before jax.shard_map use)
 from . import dtype, enforce, flags, place, rng  # noqa: F401
 from .dtype import (  # noqa: F401
     bfloat16,

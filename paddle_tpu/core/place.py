@@ -32,16 +32,6 @@ class Place:
     def __repr__(self):
         return f"Place({self.kind}:{self.device_id})"
 
-    def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == self._platform()]
-        if not devs:
-            # fall back to whatever the default backend exposes
-            devs = jax.devices()
-        return devs[min(self.device_id, len(devs) - 1)]
-
-    def _platform(self):
-        return {"tpu": "tpu", "cpu": "cpu", "gpu": "gpu"}.get(self.kind, "cpu")
-
 
 class CPUPlace(Place):
     kind = "cpu"
@@ -88,18 +78,12 @@ class CustomPlace(Place):
         super().__init__(device_id)
         self.kind = str(device_type)
 
-    def _platform(self):
-        return self.kind
-
 
 _current_place = None
 
 
 def _default_place():
-    try:
-        plat = jax.default_backend()
-    except Exception:
-        plat = "cpu"
+    plat = jax.default_backend()
     if plat == "tpu":
         return TPUPlace(0)
     if plat == "gpu":

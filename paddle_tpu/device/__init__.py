@@ -38,7 +38,8 @@ def _accel_devices():
 
 def set_device(device):
     """'tpu', 'tpu:0', 'cpu', or the reference's 'gpu:0' (mapped to the
-    accelerator)."""
+    accelerator). An index past the last device is an error, not a
+    clamp: 'tpu:9' on a 4-chip host names a chip that does not exist."""
     global _current
     name = str(device).lower()
     kind, _, idx = name.partition(":")
@@ -48,11 +49,12 @@ def set_device(device):
             jax.devices()
     else:  # tpu / gpu / xpu / custom names all mean "the accelerator"
         pool = _accel_devices()
-    _current = pool[min(idx, len(pool) - 1)]
-    try:
-        jax.config.update("jax_default_device", _current)
-    except Exception:  # ptlint: disable=PTL804 (knob probe; default-device knob may not exist)
-        pass
+    if not 0 <= idx < len(pool):
+        raise ValueError(
+            f"set_device({device!r}): index {idx} out of range — "
+            f"{len(pool)} {pool[0].platform} device(s) visible")
+    _current = pool[idx]
+    jax.config.update("jax_default_device", _current)
     return _current
 
 

@@ -30,7 +30,10 @@ class ParallelMode:
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
     """Run `func(*args)` in nprocs worker processes under the PADDLE_*
     env contract (reference: distributed/spawn.py). Each worker calls
-    init_parallel_env itself (as in the reference examples)."""
+    init_parallel_env itself (as in the reference examples). Workers
+    inherit the parent's environment, `JAX_PLATFORMS` included; on a
+    TPU host call this BEFORE the parent touches jax — a chip belongs
+    to one process at a time."""
     import multiprocessing as mp
     import socket
 
@@ -51,7 +54,6 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
             "PADDLE_TRAINERS_NUM": str(nprocs),
             "PADDLE_LOCAL_RANK": str(rank),
             "PADDLE_LOCAL_SIZE": str(nprocs),
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
         }
         p = ctx.Process(target=_spawn_entry, args=(func, args, env),
                         daemon=daemon)

@@ -977,15 +977,7 @@ class Checkpointer:
                 for k, v in st.items():
                     if isinstance(v, jax.Array) and v.committed:
                         shardings[f"train_step_opt/{n}/{k}"] = v.sharding
-        # sharded restore compiles reshard programs (make_array_from_
-        # callback / device_put onto NamedShardings) — keep those out of
-        # the persistent compile cache too: a cache-served reshard can
-        # hand back subtly-wrong restored state on this jax build (same
-        # aliasing hazard as the donating step executables, see
-        # core.jax_compat.no_persistent_cache)
-        from ..core.jax_compat import no_persistent_cache
-
-        with no_persistent_cache(), _trace_span("ckpt.load", step=step):
+        with _trace_span("ckpt.load", step=step):
             state = self.retry.run(load_state_dict, self._dir(step),
                                    shardings=shardings,
                                    name=f"ckpt.load:{step}")
@@ -1052,24 +1044,12 @@ def _restore_train_step_opt(ts, opt_sd):
                 val = jax.device_put(val, old[i][k].sharding)
             elif not isinstance(val, jax.Array) or val.committed:
                 # live accumulators are UNCOMMITTED (the single-device
-                # _build path) — restore them uncommitted too.
-                # device_put here yielded committed arrays, flipped the
-                # step's jit signature, and the post-restore recompile
-                # could be served from the persistent cache with a
-                # mismatched donation/aliasing map (jax-0.4.x platform
-                # bug, docs/RESILIENCE.md): the first resumed update
-                # silently diverged ~1-in-3 full-suite runs
-                # (test_fault_tolerant_resume_matches_uninterrupted).
+                # _build path) — restore them uncommitted too:
+                # device_put here yields committed arrays, which flips
+                # the step's jit signature and costs a second
+                # executable after the restore.
                 val = jnp.asarray(np.asarray(val))
             # donated next step — must be XLA-owned (see _xla_owned)
             d[k] = _xla_owned(val)
         states.append(d)
-    if getattr(ts, "_compiled", None) is None:
-        # restored BEFORE the step's first compile: flag it so the
-        # first post-restore dispatch compiles OUTSIDE the persistent
-        # compilation cache (jit.TrainStep.__call__ honors this; the
-        # DistributedTrainStep _build(restored) AOT path has its own
-        # guard) — a cache-served donating executable is the known
-        # jax-0.4.x aliasing-corruption window (docs/RESILIENCE.md)
-        ts._restored_pre_build = True
     ts._opt_states = states

@@ -23,15 +23,6 @@ def ensure_multihost_initialized():
         return False
     import jax
 
-    # A preloaded PJRT plugin (sitecustomize-style autoregistration) may
-    # have overridden the platform choice before user code ran; re-assert
-    # the env contract so all ranks come up on the same backend.
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        try:
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # ptlint: disable=PTL804 (knob probe; platform already initialized)
-            pass
     try:
         jax.distributed.initialize(
             coordinator_address=master,

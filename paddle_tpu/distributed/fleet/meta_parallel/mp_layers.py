@@ -34,9 +34,20 @@ __all__ = [
 
 
 def mark_sharding(param, *spec):
-    """Attach a PartitionSpec to a parameter and (eagerly) place it."""
+    """Attach a PartitionSpec to a parameter and (eagerly) place it.
+
+    On a one-device mesh there is nothing to place, and placing anyway
+    is not free: jax (>= 0.7, sharding in types) types an array by the
+    mesh it lives on, a jit fed some mesh-placed inputs returns ALL its
+    outputs mesh-placed, and the leaves this function never touched
+    (LayerNorm, biases, optimizer state) then change type between step
+    0 and step 1 — `jit.TrainStep` traced and compiled the GPT step
+    twice on jax 0.9.0 (chip_smoke.py, PR 21). Same rule as
+    `shard_activation` below."""
     param._pspec = P(*spec)
     mesh = mesh_mod.global_mesh()
+    if all(n == 1 for n in mesh.shape.values()):
+        return param
     if any(s is not None for s in param._pspec) and not isinstance(
             param._value, jax.core.Tracer):
         try:

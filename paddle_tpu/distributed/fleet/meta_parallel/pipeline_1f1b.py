@@ -456,12 +456,13 @@ def pipeline_forward_loss(block_fn, loss_fn, stacked_params, post_params,
         out_specs=(P(), P()),
         check_vma=False,
     )
-    # ALWAYS jit the shard_map: this jax version cannot evaluate a
-    # shard_map whose body stages closed_calls (remat/custom_vjp) outside
-    # a jit — and the eager eval path reaches here under jax.vjp's trace
-    # (engine.apply linearizes), which is equally unsupported. Under an
-    # outer jit the nested pjit is inlined by XLA; standalone it compiles
-    # the schedule.
+    # ALWAYS jit the shard_map. The eager eval path reaches here under
+    # jax.vjp's trace (engine.apply linearizes), not a jit; un-jitted,
+    # jax 0.9.0 evaluates the schedule op by op across the mesh
+    # (correct, and measurably slower: the pipeline tests take 16%
+    # longer), where 0.4.37 could not evaluate a body with closed_calls
+    # (remat/custom_vjp) at all. Under an outer jit the nested pjit is
+    # inlined by XLA; standalone it compiles the schedule.
     run = jax.jit(run)
     loss, aux = run(stacked_params, post_params, x_micro, y_micro)
     return (loss, aux) if has_aux else loss
@@ -573,9 +574,7 @@ def _pipeline_call(block_fn, loss_fn, stacked_params, post_params, batch,
         out_specs=(P(), P(), stack_spec, post_spec, x_spec),
         check_vma=False,
     )
-    # ALWAYS jit (see pipeline_forward_loss): shard_map bodies with
-    # closed_calls (the remat'd blocks / custom_vjp collectives) cannot
-    # run outside jit on this jax version, and eager model.loss() calls
+    # ALWAYS jit (see pipeline_forward_loss): eager model.loss() calls
     # arrive here under jax.vjp's trace, not a jit. Under TrainStep the
     # nested pjit is inlined at the (cached) outer trace; a PURE-eager
     # loop retraces per call because block_fn/loss_fn are fresh closures

@@ -249,8 +249,7 @@ def _gpipe_call(block_fn, loss_fn, stacked_params, post_params, batch,
         out_specs=(P(), P(), stack_spec, post_spec, x_spec),
         check_vma=False,
     )
-    # ALWAYS jit (same reasoning as _pipeline_call): shard_map bodies
-    # with closed_calls cannot run outside jit on this jax version
+    # ALWAYS jit (same reasoning as _pipeline_call)
     run = jax.jit(run)
     return run(stacked_params, post_params, x_micro, y_micro)
 

@@ -58,7 +58,8 @@ def parse_args(argv=None):
     p.add_argument("--log_dir", default="log", help="per-rank log directory")
     p.add_argument("--job_id", default="default", help="job id for log names")
     p.add_argument("--devices", default=None,
-                   help="restrict visible devices (sets TPU_VISIBLE_DEVICES)")
+                   help="restrict visible devices (sets TPU_VISIBLE_DEVICES; "
+                        "one process per host only)")
     p.add_argument("--max_restart", type=int, default=0,
                    help="restart the pod up to N times on failure")
     p.add_argument("--elastic_level", type=int, default=0,
@@ -282,6 +283,14 @@ def launch(argv=None):
         if args.nnodes > 1:
             sys.exit("--master is required when --nnodes > 1")
         master = f"127.0.0.1:{_free_port()}"
+    if args.devices is not None and args.nproc_per_node > 1:
+        # every local rank would get the SAME TPU_VISIBLE_DEVICES, and a
+        # chip belongs to one process at a time: the second rank to
+        # reach the chip fails or hangs
+        sys.exit("--devices with --nproc_per_node > 1 hands every local "
+                 "rank the same chips, and a chip belongs to one process "
+                 "at a time: run one process per host over all its chips "
+                 "(the default), or start one launcher per chip set")
     if args.elastic_level >= 1 and args.nnodes > 1:
         # membership (heartbeats/joins) is cross-host via the
         # MembershipMaster, but pod RE-FORMING at a new size is still

@@ -130,7 +130,6 @@ class DistributedTrainStep:
         self._trainable = [not p.stop_gradient for p in self._param_objs]
         self._opt_states = None
         self._compiled = None
-        self._aot_fallback = None   # retracing jit behind the AOT path
         # phase-trace state (observability.steptrace): batch-signature
         # set drives the quiet-warm-up exclusion + recompile sentinel
         # (same accounting as jit.TrainStep), prev_end anchors the
@@ -170,9 +169,8 @@ class DistributedTrainStep:
         param_objs = self._param_objs
         trainable = self._trainable
         # runtime argument, not a closure constant — a baked key makes
-        # each instance a distinct HLO, and the jax 0.4.x persistent
-        # compile cache can serve one instance's donating executable for
-        # another with a mismatched aliasing map (see jit.TrainStep)
+        # each instance a distinct HLO, so no two instances could share
+        # a compile-cache entry (see jit.TrainStep)
         self._base_key = rng_mod.next_key()
 
         def pure_loss(train_vals, frozen_vals, batch_vals, step_key):
@@ -229,8 +227,7 @@ class DistributedTrainStep:
         states = self.optimizer.init_states_tree(
             [p._value for p in train_objs])
         s_sh = self._state_shardings(train_objs, states)
-        restored = self._opt_states is not None
-        if restored:
+        if self._opt_states is not None:
             # restored from a checkpoint before the first step — keep the
             # values, (re)place them on the computed shardings
             states = self._opt_states
@@ -254,40 +251,7 @@ class DistributedTrainStep:
             out_shardings=(NamedSharding(mesh, P()), t_sh, s_sh, f_sh),
             donate_argnums=self._donate_argnums,
         )
-        if restored:
-            # checkpoint-restored before the first step: AOT-compile
-            # OUTSIDE the persistent compilation cache — a donating
-            # sharded executable served from that cache can corrupt the
-            # first post-restore update on jax 0.4.x CPU (see
-            # core.jax_compat.no_persistent_cache). The normal path
-            # keeps the cache: identical-structure steps share entries
-            # (the rng base key is an argument, not a baked constant).
-            from ..core.jax_compat import no_persistent_cache
-
-            with no_persistent_cache():
-                compiled = jitted.lower(
-                    [p._value for p in train_objs],
-                    [p._value for p in frozen_objs],
-                    self._opt_states, np.float32(self.optimizer.get_lr()),
-                    batch_vals,
-                    jnp.asarray(self.optimizer._step_count, jnp.uint32),
-                    self._base_key).compile()
-
-            def call(*args, _c=compiled, _j=jitted):
-                try:
-                    return _c(*args)
-                except (TypeError, ValueError):
-                    # batch shape changed after restore (e.g. a ragged
-                    # final batch): the AOT executable is shape-frozen —
-                    # fall back to the retracing jit wrapper, still
-                    # compiling outside the persistent cache
-                    with no_persistent_cache():
-                        return _j(*args)
-
-            self._compiled = call
-            self._aot_fallback = jitted
-        else:
-            self._compiled = jitted
+        self._compiled = jitted
 
     # ---- quantized gradient all-reduce (in-XLA EQuARX) ----
     def _validate_quant_path(self):
@@ -383,8 +347,7 @@ class DistributedTrainStep:
         frozen_vals = [p._value for p, t in zip(self._param_objs,
                                                 self._trainable) if not t]
         # committed f32, not a weak python float — same reasoning as
-        # jit.TrainStep (weak-vs-committed is a retrace hazard, and the
-        # AOT restored path is shape-AND-dtype frozen)
+        # jit.TrainStep (weak-vs-committed is a retrace hazard)
         return (train_vals, frozen_vals, self._opt_states,
                 np.float32(self.optimizer.get_lr()), list(batch_vals),
                 jnp.asarray(self.optimizer._step_count, jnp.uint32),
@@ -398,14 +361,7 @@ class DistributedTrainStep:
         ISSUE-10 retrace family, docs/RESILIENCE.md)."""
         if self._compiled is None:
             return {"executables": 0}
-        n = getattr(self._compiled, "_cache_size", None)
-        if callable(n):
-            return {"executables": int(n())}
-        # checkpoint-restored AOT path: one frozen executable plus any
-        # ragged-batch fallback retraces through the jit wrapper
-        fb = self._aot_fallback
-        n_fb = fb._cache_size() if fb is not None else 0
-        return {"executables": 1 + int(n_fb)}
+        return {"executables": int(self._compiled._cache_size())}
 
     def __call__(self, *batch):
         t_entry = _steptrace.now()

@@ -1025,8 +1025,7 @@ class SparseTrainStep(_TrainStepBase):
         train_objs = [p for p, t in zip(param_objs, trainable) if t]
         # per-step dropout keys, as TrainStep — and like there, a runtime
         # ARGUMENT, not a closure constant: baked keys make per-instance
-        # HLOs, which the jax 0.4.x persistent compile cache can serve
-        # across instances with a mismatched donation aliasing map
+        # HLOs, so no two instances could share a compile-cache entry
         self._base_key = rng_mod.next_key()
 
         def pure_loss(train_vals, rows_vals, frozen_vals, inv_vals,

@@ -106,17 +106,15 @@ def ring_flash_attention(q, k, v, causal=False, axis_name="sp",
     merge is plain jnp.
 
     q, k, v: [batch, seq_local, heads, head_dim]. Same contract as
-    ring_attention.
+    ring_attention. `interpret=True` runs the kernel in the Pallas
+    interpreter; the caller chooses it (the CPU tests do), nothing here
+    looks at the backend.
     """
     from ..ops.pallas_kernels.flash_attention import (
         DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention_lse_bhd)
 
-    import jax
-
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
-    if not interpret and jax.default_backend() != "tpu":
-        interpret = True  # CPU test tier runs the Pallas interpreter
     sp = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape

@@ -476,30 +476,15 @@ class LLMEngineConfig:
 
 
 class _CompiledStepBase:
-    """Shared dispatch shell of every compiled decode executable
-    (single-tick, fused window, speculative propose/verify): the
-    first call compiles OUTSIDE the persistent cache — a cache-loaded
-    donating executable on jax 0.4.x drops (or worse, mismatches) its
-    aliasing map, measured 25% slower serving from the silent
-    donation loss alone (docs/RESILIENCE.md) — and every later call
-    dispatches the warm jit directly. Subclasses build `self._jit`
-    (weights as ARGUMENTS, kv pytree DONATED) and call `_run`."""
+    """Shared shell of every compiled decode executable (single-tick,
+    fused window, speculative propose/verify). Subclasses build
+    `self._jit` (weights as ARGUMENTS, kv pytree DONATED) and dispatch
+    it; the executables go through the persistent compilation cache
+    like any other (a cache-loaded donating executable keeps its
+    aliasing map on jax 0.9.0 — probed on the chip and the CPU, PR 21,
+    docs/RESILIENCE.md)."""
 
     _jit = None
-    _warm = False
-
-    def _run(self, *args):
-        if self._warm:
-            return self._jit(*args)
-        # guard the compile only: the no-persistent-cache flag is
-        # process-global, so flipping it every tick from the serving
-        # thread would race other threads' compiles
-        from ..core.jax_compat import no_persistent_cache
-
-        with no_persistent_cache():
-            out = self._jit(*args)
-        self._warm = True
-        return out
 
     def cache_size(self):
         n = getattr(self._jit, "_cache_size", None)
@@ -555,7 +540,7 @@ class _CompiledPagedStep(_CompiledStepBase):
         self._jit = jax.jit(pure, donate_argnums=(8,))
 
     def __call__(self, tok, pos, sid, widx, pt, klen, smp, kv_state):
-        return self._run([p._value for p in self._params], tok, pos,
+        return self._jit([p._value for p in self._params], tok, pos,
                          sid, widx, pt, klen, smp, kv_state)
 
 
@@ -565,9 +550,8 @@ class _CompiledFusedStep(_CompiledStepBase):
     and EOS/budget masking INSIDE the scan — one host round trip per k
     tokens. Built exactly like `_CompiledPagedStep` (weights as jit
     ARGUMENTS, the kv pytree — pools + scale planes + PRNG key —
-    DONATED, first compile outside the persistent cache). k is baked
-    into the scan length, so one engine holds ONE fused executable per
-    (k, geometry); window spill (pool pressure / short budgets) rides
+    DONATED). k is baked into the scan length, so one engine holds ONE
+    fused executable per (k, geometry); window spill (pool pressure / short budgets) rides
     the `rem` argument instead of re-tracing a shorter scan."""
 
     def __init__(self, model, k, page_size):
@@ -599,7 +583,7 @@ class _CompiledFusedStep(_CompiledStepBase):
 
     def __call__(self, tok0, pos0, rem, fin0, eos, temps, top_ps,
                  streams, gstate0, gtrans, gmask, pt, kv_state):
-        return self._run([p._value for p in self._params], tok0, pos0,
+        return self._jit([p._value for p in self._params], tok0, pos0,
                          rem, fin0, eos, temps, top_ps, streams,
                          gstate0, gtrans, gmask, pt, kv_state)
 

@@ -100,7 +100,7 @@ class _CompiledProposeStep(_CompiledStepBase):
     instead of costing a separate catch-up tick on the steady-state
     hot path. Same compilation contract as every decode executable
     (`_CompiledStepBase`): weights as jit arguments, (pools, scales,
-    key) donated, first compile outside the persistent cache."""
+    key) donated."""
 
     def __init__(self, model, k, page_size):
         self._params = list(model.state_dict().values())
@@ -131,7 +131,7 @@ class _CompiledProposeStep(_CompiledStepBase):
 
     def __call__(self, tok0, pos0, rem, fin0, eos, temps, top_ps,
                  streams, lag, frontier, pt, kv_state):
-        return self._run([p._value for p in self._params], tok0, pos0,
+        return self._jit([p._value for p in self._params], tok0, pos0,
                          rem, fin0, eos, temps, top_ps, streams, lag,
                          frontier, pt, kv_state)
 
@@ -142,9 +142,9 @@ class _CompiledVerifyStep(_CompiledStepBase):
     (`GPTGenerationMixin._paged_verify_fused`) with exact-match
     acceptance, EOS and budget masking in-executable. Built exactly
     like `_CompiledFusedStep` (weights as jit ARGUMENTS, the kv pytree
-    — pools + scale planes + PRNG key — DONATED, first compile outside
-    the persistent cache). k is baked into the flat geometry, so one
-    engine holds ONE verify executable per (k, geometry); narrow
+    — pools + scale planes + PRNG key — DONATED). k is baked into the
+    flat geometry, so one engine holds ONE verify executable per (k,
+    geometry); narrow
     windows (pool pressure / short budgets) ride the width/rem
     arguments instead of re-tracing."""
 
@@ -179,7 +179,7 @@ class _CompiledVerifyStep(_CompiledStepBase):
 
     def __call__(self, tok0, pos0, drafts, width, rem, fin0, eos, temps,
                  top_ps, streams, gstate0, gtrans, gmask, pt, kv_state):
-        return self._run([p._value for p in self._params], tok0, pos0,
+        return self._jit([p._value for p in self._params], tok0, pos0,
                          drafts, width, rem, fin0, eos, temps, top_ps,
                          streams, gstate0, gtrans, gmask, pt, kv_state)
 

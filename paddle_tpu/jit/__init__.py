@@ -50,7 +50,7 @@ _DONATION_HELD = _obs.gauge(
     "pt_step_donation_held",
     "1 when every donated buffer of the compiled step aliased an "
     "output at the last compile_stats(check_donation=True) probe — 0 "
-    "is the jax-0.4.x persistent-cache aliasing bug resurfacing "
+    "means the executable copies instead of updating in place "
     "(analysis.donation_coverage; docs/ANALYSIS.md)",
     labelnames=("step",))
 
@@ -465,12 +465,6 @@ class TrainStep:
         self._compiled = None
         self._last_batch_avals = None
         self._telemetry_full = False
-        # set by Checkpointer._restore_train_step_opt when state is
-        # restored BEFORE the first compile: the first dispatch then
-        # compiles outside the persistent compilation cache (the
-        # jax-0.4.x donating-executable aliasing hazard — the same
-        # guard DistributedTrainStep's restored AOT path carries)
-        self._restored_pre_build = False
         # shape-churn accounting (see __call__'s recompile guard)
         self._batch_signatures = set()
         self._sig_warned = False
@@ -500,11 +494,9 @@ class TrainStep:
         # inside the compiled program (constant-baked keys would replay the
         # same mask every step). The key is a RUNTIME ARGUMENT, not a
         # closure constant: a baked key makes every TrainStep instance a
-        # distinct HLO, and on jax 0.4.x the persistent compile cache can
-        # serve one instance's donating executable for another's — with a
-        # mismatched input/output aliasing map that silently corrupts the
-        # step (flaky checkpoint-resume divergence). As an argument, all
-        # structurally-equal steps share one (correct) cache entry.
+        # distinct HLO, so no two instances could share a persistent-
+        # cache entry. As an argument, all structurally-equal steps
+        # share one.
         self._base_key = rng_mod.next_key()
 
         def pure_loss(train_vals, frozen_vals, batch_vals, step_key):
@@ -678,23 +670,9 @@ class TrainStep:
         t0 = _time.perf_counter()
         with _trace_span("jit.TrainStep",
                          step=int(self.optimizer._step_count)):
-            if self._restored_pre_build:
-                # first dispatch after a pre-compile checkpoint restore:
-                # compile OUTSIDE the persistent cache — a cache-served
-                # donating executable can carry a mismatched aliasing
-                # map on this jax build (docs/RESILIENCE.md); later
-                # dispatches reuse the in-memory executable as usual
-                from ..core.jax_compat import no_persistent_cache
-
-                with no_persistent_cache():
-                    out = self._compiled(
-                        train_vals, frozen_vals, self._opt_states, lr,
-                        batch_vals, step_idx, self._base_key)
-                self._restored_pre_build = False
-            else:
-                out = self._compiled(
-                    train_vals, frozen_vals, self._opt_states, lr,
-                    batch_vals, step_idx, self._base_key)
+            out = self._compiled(
+                train_vals, frozen_vals, self._opt_states, lr,
+                batch_vals, step_idx, self._base_key)
         tr.stamp("dispatch")
         if self._telemetry_full:
             loss, new_vals, self._opt_states, new_frozen, grad_norm = out
@@ -745,8 +723,8 @@ class TrainStep:
         signature through the live compile-cache path and reports
         whether every donated buffer (params/buffers/opt state)
         actually aliased an output in the executable — the mechanical
-        regression guard for the jax 0.4.x persistent-cache bug that
-        silently dropped donation (docs/RESILIENCE.md). Adds a
+        regression guard for donation silently dropping, e.g. through a
+        cache-served executable (docs/RESILIENCE.md). Adds a
         `"donation"` key: {"expected", "aliased", "held", "dropped"}.
         """
         n = getattr(self._compiled, "_cache_size", None)

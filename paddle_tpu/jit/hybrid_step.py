@@ -159,12 +159,7 @@ class HybridTrainStep(TrainStep):
             # dispatch's signature already matches step 2's — otherwise
             # the commitment flip costs a second executable, exactly
             # the retrace the save+restore one-executable probe pins.
-            # The reshard compiles stay outside the persistent cache
-            # (same hazard as Checkpointer.load's sharded restore).
-            from ..core.jax_compat import no_persistent_cache
-
-            with no_persistent_cache():
-                self._opt_states = jax.device_put(self._opt_states, s_sh)
+            self._opt_states = jax.device_put(self._opt_states, s_sh)
         return jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                        donate_argnums=self._donate_argnums)
 

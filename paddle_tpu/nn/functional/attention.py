@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from ...ops._helpers import apply_jfn, ensure_tensor
 
 __all__ = ["scaled_dot_product_attention", "dense_attention_bshd",
-           "paged_attention"]
+           "paged_attention", "paged_attention_jnp"]
 
 
 def dense_attention_bshd(q, k, v, is_causal=False, attn_mask=None,
@@ -187,117 +187,122 @@ def paged_attention(query, k_pool, v_pool, page_tables, slot_ids, kv_lens,
     has_off = frontier_offset is not None
     off = (ensure_tensor(frontier_offset),) if has_off else ()
 
-    if _paged_pallas_eligible(q, kp):
-        from ...ops.pallas_kernels import paged_attention as pa_kernel
-
-        # the blocked-query kernel variant needs the slot-major
-        # contract: q rows arrive in contiguous blocks of
-        # max_tokens_per_slot, one slot per block (the verify layout)
-        qps = (max_tokens_per_slot
-               if max_tokens_per_slot is not None
-               and q.shape[0] % max_tokens_per_slot == 0 else None)
-
-        def jfn_pallas(qv, kpool, vpool, tables, sids, ls, *rest):
-            off_v, sc = ((rest[0], rest[1:]) if has_off
-                         else (None, rest))
-            return pa_kernel.ragged_paged_attention(
-                qv, kpool, vpool, tables, sids, ls,
-                k_scales=sc[0] if sc else None,
-                v_scales=sc[1] if sc else None,
-                frontier_offset=off_v, q_per_slot=qps)
-
-        return apply_jfn("paged_attention", jfn_pallas, q, kp, vp, pt,
-                         sid, lens, *off, *scales)
+    use_pallas = _paged_pallas_eligible(q, kp)
 
     def jfn(qv, kpool, vpool, tables, sids, ls, *rest):
-        import jax
-
-        n_pages, page_size, h, d = kpool.shape
-        # a quantized pool whose rows are HALF the query head_dim holds
-        # PACKED int4 (kv_dtype="int4"): unpack after the gather, then
-        # dequant by the same per-row scale planes. The shape mismatch
-        # is the discriminator — an unpacked pool always matches q.
-        packed4 = bool(scales) and d * 2 == qv.shape[-1]
-        n_slots, pages_per_seq = tables.shape
-        tokens = qv.shape[0]
-        L = pages_per_seq * page_size
-        ls = ls.astype(jnp.int32)
         off_v, sc = (rest[0], rest[1:]) if has_off else (None, rest)
-        if has_off:
-            # advance every live token's frontier; padding rows stay 0
-            ls = jnp.where(ls > 0, ls + off_v.astype(jnp.int32), 0)
-        sids = sids.astype(jnp.int32)
-        # gather each SLOT's kv once ([S, L, h, d]) and scatter the
-        # queries onto a [S, C] slot grid, so the per-TOKEN [T, L, h, d]
-        # materialization never forms — 2× fewer bytes moved than the
-        # naive per-token gather at serving shapes, and the slot-level
-        # einsum is a clean batched matmul. (The Pallas kernel avoids
-        # even the [S, L] gather by DMA-ing pages from the table.)
-        l_idx = jnp.arange(L, dtype=jnp.int32)
-        phys = (tables.astype(jnp.int32)[:, l_idx // page_size]
-                * page_size + (l_idx % page_size)[None, :])   # [S, L]
-        k_all = kpool.reshape(n_pages * page_size, h, d)
-        v_all = vpool.reshape(n_pages * page_size, h, d)
-        ks = k_all[phys]                            # [S, L, h, d]
-        vs = v_all[phys]
-        if sc:  # int8/int4 pool: dequant-on-gather by per-row scales
-            if packed4:
-                from ...quantization.runtime import unpack_int4
+        ks, vs = sc if sc else (None, None)
+        if use_pallas:
+            from ...ops.pallas_kernels import paged_attention as pa_kernel
 
-                ks = unpack_int4(ks, axis=-1)   # [S, L, h, 2d] int8
-                vs = unpack_int4(vs, axis=-1)
-                d = d * 2
-            ksc = sc[0].reshape(n_pages * page_size, h)[phys]  # [S,L,h]
-            vsc = sc[1].reshape(n_pages * page_size, h)[phys]
-            ks = ks.astype(jnp.float32) * ksc[..., None]
-            vs = vs.astype(jnp.float32) * vsc[..., None]
-        # chunk position of each token within its slot (order-stable):
-        # cpos[t] = #earlier tokens with the same slot — collision-free
-        # grid coordinates whatever order the scheduler packed
-        eq = sids[:, None] == sids[None, :]
-        cpos = jnp.sum(jnp.tril(eq, -1), axis=1)    # [T]
-        # worst case one slot owns every token; a caller-provided
-        # per-slot bound (the verify step: exactly k+1) shrinks the
-        # grid — and the [S, h, C, L] score tensor — accordingly
-        C = (tokens if max_tokens_per_slot is None
-             else min(tokens, int(max_tokens_per_slot)))
-        qs = jnp.zeros((n_slots, C, h, d), qv.dtype).at[
-            (sids, cpos)].set(qv)
-        lgrid = jnp.zeros((n_slots, C), jnp.int32).at[
-            (sids, cpos)].set(ls)
-        sc = jnp.einsum("schd,slhd->shcl", qs, ks) / math.sqrt(d)
-        allowed = (l_idx[None, None, None, :]
-                   < lgrid[:, None, :, None])
-        sc = jnp.where(allowed, sc, jnp.float32(-1e30))
-        # softmax statistics in f32 even for bf16 pools (same contract
-        # as _cached_attention); empty grid cells softmax to uniform
-        # garbage but are never gathered back
-        w = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(
-            vs.dtype)
-        o = jnp.einsum("shcl,slhd->schd", w, vs).astype(qv.dtype)
-        out = o[(sids, cpos)]                       # [T, h, d]
-        # padding tokens (kv_len 0): the fully-masked softmax row is
-        # uniform garbage — zero it explicitly
-        return jnp.where((ls > 0)[:, None, None], out,
-                         jnp.zeros_like(out))
+            # the only caller that bounds tokens per slot (the verify
+            # step) also packs them slot-major in blocks of that size:
+            # the kernel then DMAs each slot's pages once per block
+            return pa_kernel.ragged_paged_attention(
+                qv, kpool, vpool, tables, sids, ls, k_scales=ks,
+                v_scales=vs, frontier_offset=off_v,
+                q_per_slot=max_tokens_per_slot)
+        return paged_attention_jnp(
+            qv, kpool, vpool, tables, sids, ls, k_scales=ks,
+            v_scales=vs, frontier_offset=off_v,
+            max_tokens_per_slot=max_tokens_per_slot)
 
     return apply_jfn("paged_attention", jfn, q, kp, vp, pt, sid, lens,
                      *off, *scales)
 
 
+def paged_attention_jnp(qv, kpool, vpool, tables, sids, ls, k_scales=None,
+                        v_scales=None, frontier_offset=None,
+                        max_tokens_per_slot=None):
+    """`paged_attention` on raw arrays in plain jnp: the path every
+    non-TPU backend runs, and the reference the Pallas kernel is
+    compared with (interpret mode in the tests, compiled on the chip in
+    chip_smoke.py). Mirrors the dense decode path in text/models/gpt.py
+    `_cached_attention` op for op, so engine greedy decode stays
+    token-identical to `generate()`."""
+    import jax
+
+    n_pages, page_size, h, d = kpool.shape
+    # a quantized pool whose rows are HALF the query head_dim holds
+    # PACKED int4 (kv_dtype="int4"): unpack after the gather, then
+    # dequant by the same per-row scale planes. The shape mismatch
+    # is the discriminator — an unpacked pool always matches q.
+    packed4 = k_scales is not None and d * 2 == qv.shape[-1]
+    n_slots, pages_per_seq = tables.shape
+    tokens = qv.shape[0]
+    L = pages_per_seq * page_size
+    ls = ls.astype(jnp.int32)
+    if frontier_offset is not None:
+        # advance every live token's frontier; padding rows stay 0
+        ls = jnp.where(ls > 0, ls + frontier_offset.astype(jnp.int32), 0)
+    sids = sids.astype(jnp.int32)
+    # gather each SLOT's kv once ([S, L, h, d]) and scatter the
+    # queries onto a [S, C] slot grid, so the per-TOKEN [T, L, h, d]
+    # materialization never forms — 2× fewer bytes moved than the
+    # naive per-token gather at serving shapes, and the slot-level
+    # einsum is a clean batched matmul. (The Pallas kernel avoids
+    # even the [S, L] gather by DMA-ing pages from the table.)
+    l_idx = jnp.arange(L, dtype=jnp.int32)
+    phys = (tables.astype(jnp.int32)[:, l_idx // page_size]
+            * page_size + (l_idx % page_size)[None, :])   # [S, L]
+    k_all = kpool.reshape(n_pages * page_size, h, d)
+    v_all = vpool.reshape(n_pages * page_size, h, d)
+    ks = k_all[phys]                            # [S, L, h, d]
+    vs = v_all[phys]
+    if k_scales is not None:
+        # int8/int4 pool: dequant-on-gather by per-row scales
+        if packed4:
+            from ...quantization.runtime import unpack_int4
+
+            ks = unpack_int4(ks, axis=-1)   # [S, L, h, 2d] int8
+            vs = unpack_int4(vs, axis=-1)
+            d = d * 2
+        ksc = k_scales.reshape(n_pages * page_size, h)[phys]  # [S,L,h]
+        vsc = v_scales.reshape(n_pages * page_size, h)[phys]
+        ks = ks.astype(jnp.float32) * ksc[..., None]
+        vs = vs.astype(jnp.float32) * vsc[..., None]
+    # chunk position of each token within its slot (order-stable):
+    # cpos[t] = #earlier tokens with the same slot — collision-free
+    # grid coordinates whatever order the scheduler packed
+    eq = sids[:, None] == sids[None, :]
+    cpos = jnp.sum(jnp.tril(eq, -1), axis=1)    # [T]
+    # worst case one slot owns every token; a caller-provided
+    # per-slot bound (the verify step: exactly k+1) shrinks the
+    # grid — and the [S, h, C, L] score tensor — accordingly
+    C = (tokens if max_tokens_per_slot is None
+         else min(tokens, int(max_tokens_per_slot)))
+    qs = jnp.zeros((n_slots, C, h, d), qv.dtype).at[
+        (sids, cpos)].set(qv)
+    lgrid = jnp.zeros((n_slots, C), jnp.int32).at[
+        (sids, cpos)].set(ls)
+    sc = jnp.einsum("schd,slhd->shcl", qs, ks) / math.sqrt(d)
+    allowed = (l_idx[None, None, None, :]
+               < lgrid[:, None, :, None])
+    sc = jnp.where(allowed, sc, jnp.float32(-1e30))
+    # softmax statistics in f32 even for bf16 pools (same contract
+    # as _cached_attention); empty grid cells softmax to uniform
+    # garbage but are never gathered back
+    w = jax.nn.softmax(sc.astype(jnp.float32), axis=-1).astype(
+        vs.dtype)
+    o = jnp.einsum("shcl,slhd->schd", w, vs).astype(qv.dtype)
+    out = o[(sids, cpos)]                       # [T, h, d]
+    # padding tokens (kv_len 0): the fully-masked softmax row is
+    # uniform garbage — zero it explicitly
+    return jnp.where((ls > 0)[:, None, None], out,
+                     jnp.zeros_like(out))
+
+
 def _pallas_backend_ok():
     """The shared Pallas gate policy: kernels flag on AND a real TPU
-    backend (ONE place — both the flash and the paged gates call it)."""
+    backend (ONE place — both the flash and the paged gates call it).
+    Selects on the platform only; a backend that cannot initialise
+    raises here, it does not become "use jnp"."""
+    import jax
+
     from ...core import flags
 
-    if not flags.get_flag("use_pallas_kernels"):
-        return False
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return bool(flags.get_flag("use_pallas_kernels")
+                and jax.default_backend() == "tpu")
 
 
 def _paged_pallas_eligible(q, k_pool):

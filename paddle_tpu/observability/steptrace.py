@@ -56,8 +56,7 @@ from .metrics import _STATE, counter, gauge, histogram, \
 __all__ = ["StepTrace", "PHASES", "begin_step", "end_step", "active",
            "now", "note_ckpt_snapshot", "note_recompile", "model_flops",
            "arm_goodput", "goodput_armed", "recent_steps", "reset",
-           "phase_summary", "straggler_of", "collective_bytes_per_second",
-           "DEFAULT_PEAK_FLOPS"]
+           "phase_summary", "straggler_of", "collective_bytes_per_second"]
 
 # segment END-stamp names in temporal order (the internal "start"
 # anchor stamp opens the chain and is never a histogram label). A step
@@ -67,12 +66,6 @@ __all__ = ["StepTrace", "PHASES", "begin_step", "end_step", "active",
 # telemetry on (the sync is skipped when nothing would record it).
 PHASES = ("ckpt_snapshot", "data_wait", "h2d", "dispatch",
           "device_step", "opt_publish")
-
-# nominal peak used for MFU when the caller doesn't pass one:
-# PT_PEAK_FLOPS env override, else the v5e bf16 chip peak bench.py
-# normalizes against (bench and the live gauge must agree on the
-# denominator or their MFU numbers diverge by a constant factor).
-DEFAULT_PEAK_FLOPS = 197e12
 
 _PHASE_SECONDS = histogram(
     "pt_train_phase_seconds",
@@ -90,7 +83,9 @@ _RECOMPILES = counter(
 _MFU_GAUGE = gauge(
     "pt_train_mfu",
     "model FLOPs utilization of the last completed non-quiet step: "
-    "arm_goodput()'s analytic FLOPs / step wall time / peak FLOPs")
+    "arm_goodput()'s analytic FLOPs / step wall time / peak FLOPs "
+    "(the published bf16 peak of the device_kind that runs — "
+    "device/peaks.py; not published on a device without one)")
 _TOKENS_PER_S = gauge(
     "pt_train_tokens_per_second",
     "training goodput of the last completed non-quiet step: "
@@ -250,16 +245,26 @@ def arm_goodput(flops_per_step=None, tokens_per_step=None,
     """Arm the continuous MFU/goodput gauges: every completed
     non-quiet step sets pt_train_mfu = flops_per_step / wall /
     peak_flops and pt_train_tokens_per_second = tokens_per_step /
-    wall. Call with no args to disarm. Returns the previous arming."""
+    wall. Call with no args to disarm. Returns the previous arming.
+
+    peak_flops defaults to the published bf16 peak of ONE chip of the
+    `device_kind` that is running (device/peaks.py — the denominator
+    bench.py uses); pass chips × peak for a multi-chip step. On a
+    device with no published peak (the CPU) pt_train_mfu is simply not
+    published: there is no utilization of a peak that does not exist."""
     prev = dict(_GOODPUT)
     _GOODPUT["flops"] = None if flops_per_step is None \
         else float(flops_per_step)
     _GOODPUT["tokens"] = None if tokens_per_step is None \
         else float(tokens_per_step)
-    if peak_flops is None:
-        peak_flops = float(os.environ.get("PT_PEAK_FLOPS",
-                                          DEFAULT_PEAK_FLOPS))
-    _GOODPUT["peak"] = float(peak_flops)
+    if peak_flops is None and flops_per_step is not None:
+        from ..device.peaks import UnknownDeviceKind, running_device_peaks
+
+        try:
+            peak_flops = running_device_peaks()["bf16_flops"]
+        except UnknownDeviceKind:
+            peak_flops = None
+    _GOODPUT["peak"] = None if peak_flops is None else float(peak_flops)
     return prev
 
 
@@ -280,7 +285,8 @@ def end_step(tr):
         if len(_RING) > _RING_MAX:
             del _RING[:len(_RING) - _RING_MAX]
         if total > 0.0:
-            if _GOODPUT["flops"] is not None:
+            if _GOODPUT["flops"] is not None and \
+                    _GOODPUT["peak"] is not None:
                 _MFU_GAUGE.set(
                     _GOODPUT["flops"] / total / _GOODPUT["peak"])
             if _GOODPUT["tokens"] is not None:

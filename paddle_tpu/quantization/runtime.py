@@ -75,15 +75,23 @@ def pack_int4(codes, axis=0):
     return (lo_u | hi_u).astype(jnp.int8)
 
 
+def unpack_int4_halves(packed):
+    """Packed int8 bytes → the two sign-extended nibble planes
+    (low nibbles = the first half of the packed axis, high nibbles =
+    the second), as int32. Pure shift/mask arithmetic in int32 (the
+    `(x ^ 8) - 8` sign-extension), so it lowers identically under XLA
+    and inside Pallas kernels — the paged-attention kernel consumes the
+    planes as they are, `unpack_int4` concatenates them."""
+    p = jnp.asarray(packed).astype(jnp.int32) & 0xFF
+    return ((p & 0xF) ^ 8) - 8, (((p >> 4) & 0xF) ^ 8) - 8
+
+
 def unpack_int4(packed, axis=0):
     """Inverse of `pack_int4`: packed int8 bytes → sign-extended int8
-    codes, double the size along `axis`. Pure shift/mask arithmetic in
-    int32 (the `(x ^ 8) - 8` sign-extension), so it lowers identically
-    under XLA and inside Pallas kernels."""
-    p = jnp.asarray(packed).astype(jnp.int32) & 0xFF
-    lo = (((p & 0xF) ^ 8) - 8).astype(jnp.int8)
-    hi = ((((p >> 4) & 0xF) ^ 8) - 8).astype(jnp.int8)
-    return jnp.concatenate([lo, hi], axis=axis)
+    codes, double the size along `axis`."""
+    lo, hi = unpack_int4_halves(packed)
+    return jnp.concatenate([lo.astype(jnp.int8), hi.astype(jnp.int8)],
+                           axis=axis)
 
 
 # ---------------------------------------------------------------- weights
@@ -427,9 +435,9 @@ def dequantize_kv(q, scale):
 def quantize_kv_rows_int4(x):
     """[T, H, D] float → (packed int4 values [T, H, D/2], fp32 scales
     [T, H]). Per-(token, head) absmax against qmax 7 (15 levels);
-    dequant error ≤ absmax/14 per element — measurably coarser than
-    int8, which is why the engine acceptance pins greedy token-match
-    ≥ 0.95 rather than int8's 0.98. Packed split-halves along head_dim
+    dequant error ≤ absmax/14 per element — 18× coarser than int8,
+    which is why the engine acceptance pins its logits error, not a
+    token-match rate. Packed split-halves along head_dim
     (`pack_int4`), so the pool's last dim is D/2 and the existing
     per-row scale planes carry the dequant exactly as for int8."""
     f = x.astype(jnp.float32)

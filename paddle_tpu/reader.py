@@ -218,7 +218,13 @@ def multiprocess_reader(readers, use_pipe=True, queue_size=1000):
     """Run each reader in its own PROCESS, merging samples as they
     arrive (reference decorator.py:505). Uses the fork context (readers
     are usually closures over open files — unpicklable); samples cross
-    via an mp.Queue either way (`use_pipe` kept for API compat)."""
+    via an mp.Queue either way (`use_pipe` kept for API compat).
+
+    Start iterating BEFORE the process touches a device: the children
+    are forks, and a fork of a process that already holds the chip (or
+    runs jax's threads) inherits a runtime it cannot use — a chip
+    belongs to one process at a time. `io.DataLoader` workers
+    (io/multiprocess.py, forkserver) have no such ordering rule."""
     import multiprocessing as mp
 
     assert len(readers) > 0, "readers must not be empty"

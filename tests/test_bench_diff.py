@@ -98,6 +98,15 @@ def test_backend_mismatch_never_compares():
     assert not rep["comparable"]
     assert "backend mismatch" in rep["reason"]
     assert not rep["rows"]
+    # the device record bench.py stamps since PR 21: a one-chip and a
+    # four-chip capture do not compare either, equal records do
+    one = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    four = dict(one, count=4)
+    a, b = _stamp(ms_per_step=100.0), _stamp(ms_per_step=100.0)
+    a["device"], b["device"] = one, four
+    assert not bd.diff(a, b)["comparable"]
+    b["device"] = dict(one)
+    assert bd.diff(a, b)["comparable"]
 
 
 # -------------------------------------------------------- stamps on disk

@@ -271,13 +271,13 @@ def test_nan_rollback_resumes_in_process_and_reconverges(tmp_path):
     NaN step on a FUSED-update compiled TrainStep triggers the sentinel
     → run_with_fault_tolerance restores the last COMPLETE checkpoint
     (no process restart), the poisoned data window is skipped, and the
-    run re-converges to the clean run's final loss within 5% — with the
+    run rejoins the clean run's trajectory one update behind — with the
     rollback journaled and counted in pt_rollback_total{reason=nan}."""
     from paddle_tpu.distributed import resilience as res
     from paddle_tpu.distributed.fleet import elastic as fleet_elastic
     from paddle_tpu.observability import metrics as obs_metrics
 
-    STEPS = 16    # enough post-rollback runway to re-converge within 5%
+    STEPS = 16
 
     def build(seed=0):
         paddle.seed(seed)
@@ -299,7 +299,7 @@ def test_nan_rollback_resumes_in_process_and_reconverges(tmp_path):
         cp = Checkpointer(str(root), model=m, train_step=st,
                           async_save=True)
         sentinel = res.DivergenceSentinel(max_rollbacks=2)
-        last = [None]
+        hist = []
 
         def train_fn(start):
             step = start
@@ -309,32 +309,42 @@ def test_nan_rollback_resumes_in_process_and_reconverges(tmp_path):
                     continue
                 loss = st(xs, ys)
                 sentinel.check(loss, step=step)
-                last[0] = float(loss.numpy())
+                hist.append(float(loss.numpy()))
                 cp.save(step + 1)
                 step += 1
             cp.wait()
-            return last[0]
+            return hist[-1]
 
         try:
             final = fleet_elastic.run_with_fault_tolerance(
                 train_fn, cp, max_restarts=0)
         finally:
             chaos.clear()
-        return final, sentinel
+        return final, sentinel, hist
 
-    clean, _ = run(tmp_path / "clean")
+    clean, _, clean_hist = run(tmp_path / "clean")
     before = obs_metrics.registry().get(
         "pt_rollback_total").labels(reason="nan").value
-    faulted, sentinel = run(tmp_path / "faulted", poisoned_at=5)
+    faulted, sentinel, _ = run(tmp_path / "faulted", poisoned_at=5)
     assert sentinel.rollbacks == 1
     assert sentinel.should_skip(5)
     assert resilience.events("rollback")
     assert resilience.events("train_rollback")
     assert obs_metrics.registry().get(
         "pt_rollback_total").labels(reason="nan").value == before + 1
-    # one good update was sacrificed with the poisoned window; the run
-    # still re-converges to the clean trajectory within 5%
-    np.testing.assert_allclose(faulted, clean, rtol=0.05)
+    # The poisoned step's fused update is rolled back and its batch
+    # skipped, so on this fixed batch the faulted run makes exactly ONE
+    # update fewer than the clean run: it must land ON the clean
+    # trajectory, one update earlier (the restore is bit-exact, so the
+    # same float). Its distance from the clean final is then the clean
+    # run's own last-update drop — the band is that measured spread, not
+    # a constant: it is 6.6% under jax 0.9.0's trajectory (0.6572 vs
+    # 0.6164), which is why the fixed 5% this test used to carry failed
+    # without anything being wrong.
+    one_update = clean_hist[-2] - clean_hist[-1]
+    assert one_update > 0, clean_hist[-3:]
+    np.testing.assert_allclose(faulted, clean_hist[-2], rtol=1e-6)
+    assert abs(faulted - clean) <= one_update * (1 + 1e-6)
 
 
 def test_run_with_fault_tolerance_escalates_on_stale_peer(tmp_path,

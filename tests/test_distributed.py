@@ -451,7 +451,7 @@ class TestRingAttention:
         for causal in (False, True):
             f = dist.spmd(
                 lambda qq, kk, vv: dist.ring_flash_attention(
-                    qq, kk, vv, causal=causal),
+                    qq, kk, vv, causal=causal, interpret=True),
                 in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
                 out_specs=P(None, "sp"), group_axes=("sp",))
             out = np.asarray(f(q, k, v))
@@ -461,7 +461,7 @@ class TestRingAttention:
             def loss_ring(qq, kk, vv):
                 f_in = dist.spmd(
                     lambda a, bb, c: dist.ring_flash_attention(
-                        a, bb, c, causal=causal),
+                        a, bb, c, causal=causal, interpret=True),
                     in_specs=(P(None, "sp"), P(None, "sp"),
                               P(None, "sp")),
                     out_specs=P(None, "sp"), group_axes=("sp",))

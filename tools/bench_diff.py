@@ -19,9 +19,10 @@ an absolute floor ``--abs-tol`` so micro-noise near zero never trips).
 
 Honesty rules, enforced before any comparison:
 
-* stamps from different backends are NEVER compared — a cpu_fallback
-  capture (dead chip, ROADMAP standing caveat) vs a chip capture is
-  apples-to-oranges and exits 2 (not-comparable), not 0 or 1;
+* stamps from different devices are NEVER compared — the `device`
+  record (platform, device_kind, count) must be equal, and an old
+  cpu_fallback capture vs a chip capture is apples-to-oranges: exit 2
+  (not-comparable), not 0 or 1;
 * a stamp whose payload is missing (the driver-shell ``parsed: null``
   of a timed-out capture) also exits 2 — "no data" must not read as
   "no regression".
@@ -97,13 +98,17 @@ def diff(old, new, tol=0.10, abs_tol=1e-9):
     """Compare two headline stamps. Returns a report dict:
     {"comparable", "reason", "backend", "rows", "regressions",
     "improvements"} — rows only for metrics present in BOTH stamps."""
-    b_old = old.get("backend")
-    b_new = new.get("backend")
+    # what the stamp ran on: the `device` record bench.py writes since
+    # PR 21 (platform, device_kind, count), or the `backend` word of the
+    # older stamps
+    b_old = old.get("device") or old.get("backend")
+    b_new = new.get("device") or new.get("backend")
     if b_old != b_new:
         return {"comparable": False,
                 "reason": f"backend mismatch: {b_old!r} vs {b_new!r} — "
-                          "a cpu_fallback capture never compares "
-                          "against a chip capture",
+                          "captures from different devices (or an old "
+                          "cpu_fallback capture and a chip capture) "
+                          "never compare",
                 "backend": (b_old, b_new), "rows": [],
                 "regressions": [], "improvements": []}
     f_old = flatten(old)

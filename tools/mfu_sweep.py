@@ -15,9 +15,8 @@ Sweeps, one dimension at a time around the bench configuration
 
 printing a table of ms/step and MFU so the best point can be promoted
 into bench.py. Each config runs in-process (one backend init); the
-persistent compile cache keeps reruns cheap. IMPORTANT: exits cleanly —
-never leave this holding the chip (the round-2 capture died behind a
-stale sweep process).
+persistent compile cache keeps reruns cheap. One process holds the
+chip while this runs; nothing else can use it until it exits.
 """
 import argparse
 import os
@@ -25,9 +24,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-CACHE = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_cache")
 
 
 def measure(batch, seq, block_q, block_k, iters=8, fused_head=False,
@@ -39,7 +35,8 @@ def measure(batch, seq, block_q, block_k, iters=8, fused_head=False,
     from paddle_tpu.ops.pallas_kernels import flash_attention as fa
     from paddle_tpu.text.models import (
         GPTForCausalLM, GPTPretrainingCriterion, gpt_small)
-    from bench import V5E_PEAK_BF16, gpt_flops_per_step
+    from bench import gpt_flops_per_step
+    from paddle_tpu.device.peaks import running_device_peaks
 
     old_q, old_k = fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K
     fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K = block_q, block_k
@@ -74,7 +71,8 @@ def measure(batch, seq, block_q, block_k, iters=8, fused_head=False,
             last = step(ids)
         float(last.numpy())
         dt = (time.perf_counter() - t0) / iters
-        mfu = gpt_flops_per_step(cfg, batch, seq) / dt / V5E_PEAK_BF16
+        mfu = (gpt_flops_per_step(cfg, batch, seq) / dt
+               / running_device_peaks()["bf16_flops"])
         return dt * 1e3, mfu, compile_s
     finally:
         fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K = old_q, old_k
@@ -89,13 +87,11 @@ def main():
                          "hypothesis 2 re-sweeps flash tiles at s1024)")
     args = ap.parse_args()
 
-    os.makedirs(CACHE, exist_ok=True)
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", CACHE)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.enable()
     print(f"devices: {jax.devices()}", flush=True)
 
     seq = args.seq

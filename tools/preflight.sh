@@ -13,11 +13,11 @@ MARK=(-m "not slow")
 [ "${1:-}" = "--full" ] && MARK=()
 
 echo "== [1/3] pytest gate"
-python -m pytest tests/ -x -q "${MARK[@]}" -p no:cacheprovider
+JAX_PLATFORMS=cpu python -m pytest tests/ -x -q "${MARK[@]}" -p no:cacheprovider
 
 echo "== [2/3] entry() compile check"
 JAX_PLATFORMS=cpu python - <<'EOF'
-import jax; jax.config.update("jax_platforms", "cpu")
+import jax
 import __graft_entry__ as g
 fn, args = g.entry()
 jax.jit(fn)(*args)
@@ -27,7 +27,6 @@ EOF
 echo "== [3/3] multichip dryrun (8 virtual devices)"
 JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 python - <<'EOF'
-import jax; jax.config.update("jax_platforms", "cpu")
 import __graft_entry__ as g
 g.dryrun_multichip(8)
 EOF

@@ -18,7 +18,7 @@ then open the dump with xprof/tensorboard and check
 
 Usage:
   python tools/xprof_pipeline.py [--cpu8] [--pp 4] [--virtual 2]
-      [--micro 8] [--logdir tools/onchip_out/xprof]
+      [--micro 8] [--logdir chiprun_out/xprof]
 """
 import argparse
 import os
@@ -35,16 +35,15 @@ def main():
     ap.add_argument("--pp", type=int, default=4)
     ap.add_argument("--virtual", type=int, default=2)
     ap.add_argument("--micro", type=int, default=8)
-    ap.add_argument("--logdir", default="tools/onchip_out/xprof")
+    ap.add_argument("--logdir", default="chiprun_out/xprof")
     args = ap.parse_args()
 
     if args.cpu8:
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
             " --xla_force_host_platform_device_count=8"
     import jax
 
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     import paddle_tpu as paddle
