@@ -1,0 +1,8 @@
+"""slot_occupancy.tpot: mean share of the engine's slots in use over the
+window's steps.
+"""
+from harness import metric_lib
+
+
+def read(ctx):
+    return metric_lib.slot_occupancy(ctx)

@@ -1,0 +1,257 @@
+"""BENCHMARK.json against the contract's schema, and the harness's
+finder-by-name: a configuration, a mix, a per-layer metric and a cell
+added as NEW files and NEW entries run without an edit to any file that
+is there."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+SOURCES = {"device_trace", "program_span", "program_counter",
+           "host_clock"}
+
+
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _line(text, limit=200):
+    return (isinstance(text, str) and 1 <= len(text) <= limit
+            and "\n" not in text and "\t" not in text)
+
+
+def test_top_level_keys_and_limits():
+    m = manifest()
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 65536
+    assert 1 <= len(m["command"]) <= 32 and all(
+        _line(w) for w in m["command"])
+    assert 1 <= len(m["paths"]) <= 16
+    for p in m["paths"]:
+        assert PATH.match(p) and not p.startswith("/") and ".." not in p
+        assert os.path.isdir(os.path.join(ROOT, p))
+    rs = m["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    # a full check with the full 24 cells has to fit
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 180 + 1200 <= 43200
+    assert 1 <= len(m["configs"]) <= 24
+    assert 1 <= len(m["workloads"]) <= 24
+    assert 1 <= len(m["end_to_end"]) <= 16
+    assert 1 <= len(m["per_layer"]) <= 128
+
+
+def test_every_file_under_paths_is_named_from_a_names_characters():
+    for p in manifest()["paths"]:
+        for d, dirs, files in os.walk(os.path.join(ROOT, p)):
+            dirs[:] = [x for x in dirs if x != "__pycache__"]
+            for f in files:
+                rel = os.path.relpath(os.path.join(d, f), ROOT)
+                assert PATH.match(rel), rel
+
+
+def test_configs():
+    m = manifest()
+    names = [c["name"] for c in m["configs"]]
+    files = [c["file"] for c in m["configs"]]
+    assert len(set(names)) == len(names) and len(set(files)) == len(files)
+    used = {w["config"] for w in m["workloads"]}
+    for c in m["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] in used
+        assert _line(c["source"]) and _line(c["why"])
+        assert any(c["file"].startswith(p + "/") for p in m["paths"])
+        assert len(c["reduced"]) <= 16 and all(
+            NAME.match(k) for k in c["reduced"])
+        with open(os.path.join(ROOT, c["file"])) as f:
+            body = json.load(f)
+        assert body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"]
+
+
+def test_workloads():
+    m = manifest()
+    cfgs = {c["name"] for c in m["configs"]}
+    names = [w["name"] for w in m["workloads"]]
+    pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
+    assert len(set(names)) == len(names) and len(set(pairs)) == len(pairs)
+    for w in m["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["config"] in cfgs and w["chips"] in (1, 4)
+        assert _line(w["why"])
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmarks", "traffic", w["traffic"] + ".json"))
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmarks", "limits", w["name"] + ".json"))
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    assert four <= max(1, len(names) // 4)
+
+
+def test_metrics():
+    m = manifest()
+    cells = {w["name"] for w in m["workloads"]}
+    e2e = {x["name"]: x for x in m["end_to_end"]}
+    every = [x["name"] for x in m["end_to_end"] + m["per_layer"]]
+    assert len(set(every)) == len(every)
+    assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
+    assert e2e["setup_s"]["bound"] <= 0.1
+
+    def cells_of(metric):
+        return set(metric.get("workloads", cells))
+
+    for x in m["end_to_end"]:
+        assert set(x) - {"workloads"} == {"name", "unit", "better",
+                                          "bound", "source"}
+        assert NAME.match(x["name"]) and UNIT.match(x["unit"])
+        assert x["better"] in ("lower", "higher")
+        assert x["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= x["bound"] <= 0.1
+        assert cells_of(x) <= cells and cells_of(x)
+    layers = set()
+    for x in m["per_layer"]:
+        assert set(x) - {"workloads"} == {"name", "unit", "better",
+                                          "source", "layer", "moves"}
+        assert NAME.match(x["name"]) and UNIT.match(x["unit"])
+        assert x["better"] in ("lower", "higher")
+        assert x["source"] in SOURCES and _line(x["layer"])
+        layers.add(x["layer"])
+        assert x["moves"] in e2e
+        # every listed cell reports the end-to-end metric it moves
+        assert cells_of(x) and cells_of(x) <= cells_of(e2e[x["moves"]])
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmarks", "layer_metrics", x["name"] + ".py"))
+        if x["name"].endswith("_roofline") or "mfu" in x["name"]:
+            assert x["unit"] == "%"
+    for c in cells:                 # every cell reports enough
+        assert sum(c in cells_of(x) for x in m["end_to_end"]) >= 2
+        assert any(c in cells_of(x) for x in m["per_layer"])
+        # a whole-step share of peak beside the kernels' rooflines
+        mine = [x for x in m["per_layer"] if c in cells_of(x)]
+        roof = {x["moves"] for x in mine if x["name"].endswith(
+            "_roofline") or "_roofline." in x["name"]}
+        mfu = {x["moves"] for x in mine if "mfu" in x["name"]}
+        assert roof and roof <= mfu
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        perf = f.read()
+    for layer in layers:
+        assert layer in perf, f"PERF.md's list of layers lacks {layer!r}"
+
+
+def _clean_env():
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_ENABLE_X64", "BENCH_RUN")}
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def _run(root, *args, timeout=420):
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "benchmarks", "run.py"),
+         *args], cwd=root, env=_clean_env(), capture_output=True,
+        text=True, timeout=timeout)
+
+
+def test_a_cell_refuses_the_cpu_and_prints_no_result():
+    p = _run(ROOT, "--workload", manifest()["workloads"][0]["name"],
+             "--seed", "1", "--seconds", "1", "--trace", "0")
+    assert p.returncode != 0
+    assert "platform=cpu" in p.stderr
+    assert not [ln for ln in p.stdout.splitlines()
+                if ln.strip().startswith("{")]
+
+
+@pytest.fixture(scope="module")
+def copy_with_a_dummy_cell(tmp_path_factory):
+    """A temporary copy of the benchmark with a configuration, a mix, a
+    per-layer metric and a cell ADDED: new files and new manifest
+    entries only."""
+    root = str(tmp_path_factory.mktemp("copy"))
+    shutil.copytree(os.path.join(ROOT, "benchmarks"),
+                    os.path.join(root, "benchmarks"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(ROOT, "paddle_tpu"),
+               os.path.join(root, "paddle_tpu"))
+    m = manifest()
+    b = os.path.join(root, "benchmarks")
+    with open(os.path.join(b, "configs", "gpt2-medium.json")) as f:
+        cfg = json.load(f)
+    cfg["rehearse"]["n_layer"] = 3
+    with open(os.path.join(b, "configs", "dummy-model.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(b, "traffic", "pretrain_s1024.json")) as f:
+        mix = json.load(f)
+    mix["rehearse"]["batch"] = 2
+    with open(os.path.join(b, "traffic", "dummy_mix.json"), "w") as f:
+        json.dump(mix, f)
+    shutil.copy(os.path.join(b, "limits", "gpt2m_train.json"),
+                os.path.join(b, "limits", "dummy_cell.json"))
+    with open(os.path.join(b, "layer_metrics", "dummy_steps.py"),
+              "w") as f:
+        f.write('def read(ctx):\n'
+                '    return ctx["obs"]["window"]["steps"]\n')
+    m["configs"].append({
+        "name": "dummy-model", "source": cfg["source"],
+        "file": "benchmarks/configs/dummy-model.json", "reduced": [],
+        "why": "a dummy"})
+    m["workloads"].append({
+        "name": "dummy_cell", "config": "dummy-model",
+        "traffic": "dummy_mix", "chips": 1, "why": "a dummy"})
+    for x in m["end_to_end"]:
+        if x["name"] == "train_tok_s":
+            x["workloads"].append("dummy_cell")
+    m["per_layer"].append({
+        "name": "dummy_steps", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "compiled steps",
+        "moves": "train_tok_s", "workloads": ["dummy_cell"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    return root
+
+
+def test_a_new_cell_runs_from_new_files_alone(copy_with_a_dummy_cell):
+    p = _run(copy_with_a_dummy_cell, "--workload", "dummy_cell", "--seed",
+             str(2 ** 31 + 12345), "--seconds", "1", "--trace", "1",
+             "--rehearse-cpu")
+    assert p.returncode == 0, p.stderr[-3000:]
+    last = json.loads(p.stdout.strip().splitlines()[-1])
+    # the last line's keys, `compared` last among them
+    assert list(last)[:5] == ["correct", "attempted", "failed", "metrics",
+                              "device"]
+    assert list(last)[-1] == "compared"
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["device"]["platform"] == "cpu" and last["rehearsal"]
+    # counts only: the new metric, found by its name; no time, no share
+    assert last["metrics"]["dummy_steps"]["value"] == last["attempted"]
+    assert set(last["metrics"]) == {"dummy_steps"}
+    for c in last["compared"].values():
+        assert set(c) == {"value", "limit", "ok"}
+    # each number compared is printed beside its limit as the last
+    # lines of standard error
+    tail = p.stderr.strip().splitlines()[-len(last["compared"]):]
+    assert all(ln.startswith("compared ") and "limit" in ln
+               for ln in tail)
+
+
+def test_a_directory_with_only_the_benchmark_gives_no_result(tmp_path):
+    root = str(tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmarks"),
+                    os.path.join(root, "benchmarks"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    p = _run(root, "--workload", manifest()["workloads"][0]["name"],
+             "--seed", "1", "--seconds", "1", "--trace", "0",
+             "--rehearse-cpu")
+    assert p.returncode != 0
+    assert not [ln for ln in p.stdout.splitlines()
+                if ln.strip().startswith("{")]
