@@ -1735,185 +1735,6 @@ def bench_overload_storm_ab():
     return result
 
 
-def bench_tracing_overhead_ab():
-    """Full-mode tracing overhead A/B (ISSUE-15 satellite): the SAME
-    Poisson llm_serve-shaped workload served once per telemetry mode —
-    `full` (spans + per-request phase chrome events + flight-recorder
-    feed live) vs the default `metrics` mode — interleaved F/M/F/M,
-    each side scoring its best run (the llm_serve noise defense).
-    Bar: full-mode wall time <= 1.05x metrics mode; greedy outputs
-    must be identical across modes (tracing must observe, not
-    perturb)."""
-    import tempfile
-
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu import inference, observability
-    from paddle_tpu.observability import tracing
-    from paddle_tpu.text.models import GPTForCausalLM
-    from paddle_tpu.text.models.gpt import gpt_small
-
-    paddle.seed(0)
-    cfg, n_req, name = gpt_small(), 64, "gpt-small-tracing-ab"
-    model = GPTForCausalLM(cfg)
-    model.eval()
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, cfg.vocab_size, (int(L),)).astype(
-        np.int32) for L in rng.integers(8, 48, n_req)]
-    gens = rng.integers(16, 33, n_req)
-    arrive = np.cumsum(rng.exponential(0.002, n_req))
-    # span flushes must not land in the repo: scratch telemetry dir
-    # (setdefault would call mkdtemp eagerly and orphan a dir per run)
-    if "PT_TELEMETRY_DIR" not in os.environ:
-        os.environ["PT_TELEMETRY_DIR"] = tempfile.mkdtemp(
-            prefix="pt_trace_ab_")
-    fused_k = int(os.environ.get("BENCH_DECODE_K", "8"))
-    ecfg = dict(num_slots=4, page_size=16, token_budget=48,
-                max_model_len=96, decode_k=fused_k)
-
-    def run(mode):
-        prev = observability.set_mode(mode)
-        n_events = 0
-        try:
-            # servers are built SEQUENTIALLY over one model (the
-            # shared-model warm caveat: only one engine traces at a
-            # time), and each warms outside its timed window
-            server = inference.LLMServer(
-                model, inference.LLMEngineConfig(**ecfg))
-            with server:
-                server.submit(np.zeros((2,), np.int32),
-                              max_new_tokens=fused_k + 1,
-                              trace=_quiet_trace()).result(
-                                  timeout=300)
-                futs, nxt = [None] * n_req, 0
-                t0 = time.perf_counter()
-                while nxt < n_req:
-                    now = time.perf_counter() - t0
-                    if arrive[nxt] <= now:
-                        futs[nxt] = server.submit(
-                            prompts[nxt], max_new_tokens=int(gens[nxt]))
-                        nxt += 1
-                    else:
-                        time.sleep(min(0.002, arrive[nxt] - now))
-                outs = [f.result(timeout=600) for f in futs]
-                total = time.perf_counter() - t0
-                n_events = len(tracing.chrome_events())
-        finally:
-            observability.set_mode(prev)
-            tracing.reset()
-        return outs, total, n_events
-
-    totals = {"full": [], "metrics": []}
-    ref, match, events_full = None, True, 0
-    for rep in range(2):
-        for mode in ("full", "metrics"):
-            outs, t, nev = run(mode)
-            totals[mode].append(round(t, 3))
-            if mode == "full":
-                events_full = max(events_full, nev)
-            if ref is None:
-                ref = outs
-            else:
-                match = match and all(np.array_equal(a, b)
-                                      for a, b in zip(ref, outs))
-            log(f"[bench] tracing_overhead_ab {mode}[{rep}]: {t:.2f}s")
-    f_best, m_best = min(totals["full"]), min(totals["metrics"])
-    ratio = f_best / m_best
-    log(f"[bench] tracing_overhead_ab: full {f_best:.2f}s vs metrics "
-        f"{m_best:.2f}s = {ratio:.3f}x (bar 1.05), match={match}")
-    return {"model": name, "requests": n_req, "decode_k": fused_k,
-            "totals_s": totals,
-            "best_s": {"full": f_best, "metrics": m_best},
-            "overhead_ratio": round(ratio, 4),
-            "within_bar": bool(ratio <= 1.05),
-            "greedy_match": bool(match),
-            "trace_events_full": events_full}
-
-
-def bench_steptrace_overhead_ab():
-    """Steptrace overhead A/B (ISSUE-18 satellite): the SAME train-step
-    workload run once per telemetry mode — `full` (phase stamps + chrome
-    step events + flight feed + grad-norm aux live) vs `metrics` —
-    interleaved F/M/F/M, each side scoring its best run. Bar: full-mode
-    wall time <= 1.05x metrics mode, and the per-step losses must be
-    BIT-identical across modes (the phase plane must observe the step,
-    never perturb its numerics)."""
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu import observability
-    from paddle_tpu.observability import steptrace, tracing
-    from paddle_tpu.text.models import (GPTForCausalLM,
-                                        GPTPretrainingCriterion)
-    from paddle_tpu.text.models.gpt import GPTConfig
-
-    cfg = GPTConfig(vocab_size=256, hidden_size=64, num_layers=4,
-                    num_heads=4, max_seq_len=64)
-    batch, seq, steps = 8, 32, 30
-    rng = np.random.default_rng(0)
-    ids_np = rng.integers(0, cfg.vocab_size, (batch, seq))
-    crit = GPTPretrainingCriterion()
-    if "PT_TELEMETRY_DIR" not in os.environ:
-        import tempfile
-
-        os.environ["PT_TELEMETRY_DIR"] = tempfile.mkdtemp(
-            prefix="pt_steptrace_ab_")
-
-    def run(mode):
-        prev = observability.set_mode(mode)
-        try:
-            steptrace.reset()
-            steptrace.arm_goodput(
-                flops_per_step=gpt_flops_per_step(cfg, batch, seq),
-                tokens_per_step=batch * seq)
-            paddle.seed(0)
-            m = GPTForCausalLM(cfg)
-            opt = paddle.optimizer.AdamW(1e-3,
-                                         parameters=m.parameters())
-            step = paddle.jit.TrainStep(m, lambda mm, i: crit(mm(i), i),
-                                        opt)
-            ids = paddle.to_tensor(ids_np)
-            step(ids)            # compile (quiet warm-up)
-            step(ids)            # warm
-            losses = []
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                losses.append(step(ids))
-            total = time.perf_counter() - t0
-            loss_vals = [float(lo.numpy()) for lo in losses]
-            summary = steptrace.phase_summary()
-        finally:
-            observability.set_mode(prev)
-            steptrace.reset()
-            tracing.reset()
-        return loss_vals, total, summary
-
-    totals = {"full": [], "metrics": []}
-    ref, match, phases_full = None, True, {}
-    for rep in range(2):
-        for mode in ("full", "metrics"):
-            losses, t, summary = run(mode)
-            totals[mode].append(round(t, 4))
-            if mode == "full":
-                phases_full = summary
-            if ref is None:
-                ref = losses
-            else:
-                match = match and losses == ref   # BIT-identical floats
-            log(f"[bench] steptrace_overhead_ab {mode}[{rep}]: "
-                f"{t:.3f}s for {steps} steps")
-    f_best, m_best = min(totals["full"]), min(totals["metrics"])
-    ratio = f_best / m_best
-    log(f"[bench] steptrace_overhead_ab: full {f_best:.3f}s vs metrics "
-        f"{m_best:.3f}s = {ratio:.3f}x (bar 1.05), loss_match={match}")
-    return {"model": "gpt-bench-4l", "steps": steps,
-            "totals_s": totals,
-            "best_s": {"full": f_best, "metrics": m_best},
-            "overhead_ratio": round(ratio, 4),
-            "within_bar": bool(ratio <= 1.05),
-            "loss_match": bool(match),
-            "phase_seconds_full": phases_full}
-
-
 def bench_train_3d():
     """3D-parallel (DP × TP × PP) train-step arm: per-config step time +
     mesh shape for the tier-1-size GPT over the hybrid3d subsystem. The
@@ -2513,8 +2334,6 @@ _WORKERS = {"gpt": bench_gpt, "resnet": bench_resnet, "bert": bench_bert,
             "llm_fleet": bench_llm_fleet,
             "llm_fleet_multi": bench_llm_fleet_multi,
             "overload_storm_ab": bench_overload_storm_ab,
-            "tracing_overhead_ab": bench_tracing_overhead_ab,
-            "steptrace_overhead_ab": bench_steptrace_overhead_ab,
             "kv_tier_ab": bench_kv_tier_ab,
             "llm_structured_ab": bench_llm_structured_ab,
             "train_3d": bench_train_3d}
@@ -2667,17 +2486,14 @@ def main():
     for which in ("resnet", "bert", "deepfm", "mnist", "generate",
                   "serving", "llm_serve", "llm_serve_int8", "llm_fleet",
                   "llm_fleet_multi", "overload_storm_ab",
-                  "tracing_overhead_ab", "steptrace_overhead_ab",
                   "kv_tier_ab", "llm_structured_ab", "train_3d"):
         # the llm_serve/llm_fleet arms run TWO serving phases each
         # (engine vs baseline / int8 vs fp32 / fleet vs fifo) plus both
-        # compiles — and the tracing A/B runs FOUR — so they need a
-        # wider cap than the single-model arms
+        # compiles, so they need a wider cap than the single-model arms
         status, res = _run_worker(
             which,
-            timeout_s=900 if which.startswith(("llm_", "tracing_",
-                                               "steptrace_",
-                                               "overload_", "kv_"))
+            timeout_s=900 if which.startswith(("llm_", "overload_",
+                                               "kv_"))
             else 420)
         if status == "ok":
             log(f"[bench] {which} result: {json.dumps(res)}")

@@ -391,8 +391,9 @@ class DistributedTrainStep:
         loss, new_vals, self._opt_states, new_frozen = self._compiled(
             *self._step_args(batch_vals))
         tr.stamp("dispatch")
-        if _steptrace.active():
-            # device_step = block_until_ready delta (see jit.TrainStep)
+        if _steptrace.full():
+            # device_step = block_until_ready delta: full telemetry only
+            # (see jit.TrainStep — a sync per step stalls the pipeline)
             jax.block_until_ready(
                 (loss, new_vals, self._opt_states, new_frozen))
             tr.stamp("device_step")

@@ -2201,7 +2201,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         return True
 
     def _admit(self):
+        """Admit from the queue while slots and pages last; returns how
+        many requests joined."""
         now = _time.perf_counter()
+        admitted = 0
         while self.sched:
             req = self.sched.pop_next(now)
             if req is None:
@@ -2209,6 +2212,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             if not self._try_admit(req):
                 self.sched.push_front(req)
                 break
+            admitted += 1
+        return admitted
 
     def _active(self):
         """Running sequences in admission order (deterministic plan)."""
@@ -2297,10 +2302,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         The straggler joins windows at the boundary after its prefill
         completes, and per-request greedy/sampled outputs are
         schedule-invariant, so nothing observable changes per request."""
-        self._sync_brownout()
-        if self._deadlines_armed:
-            self._expire_deadlines()
-        self._admit()
+        with _trace_span("llm_engine.admit",
+                         waiting=len(self.waiting)) as span:
+            self._sync_brownout()
+            if self._deadlines_armed:
+                self._expire_deadlines()
+            span.set(admitted=self._admit())
         if self._spec is not None or self.decode_k > 1:
             active = self._active()
             frontier = [(s, r) for s, r in active
@@ -2343,6 +2350,40 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         spill never recompiles."""
         if not active:
             return None
+        with _trace_span("llm_engine.reserve", rows=len(active)):
+            window = self._reserve_window(active)
+        if window is None:
+            return None
+        (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst, gtrans,
+         gmask, gen_before) = window
+        fused = self._ensure_fused()
+        t0 = _time.perf_counter()
+        try:
+            with _trace_span("llm_engine.fused_step", k=self.decode_k,
+                             rows=len(active), prefill_tokens=0,
+                             decode_tokens=int(rem.sum())):
+                emits, (self._kv, self._kv_scales, self._key) = fused(
+                    tok0, pos0, rem, fin0, eos, temps, tops, streams,
+                    gst, gtrans, gmask, self._page_tables,
+                    (self._kv, self._kv_scales, self._key))
+                with _trace_span("llm_engine.sync"):
+                    emits = np.asarray(emits)  # once-per-k host sync
+        except Exception as e:
+            # same contract as the single tick: the donated pytree may
+            # already be consumed — fail in-flight work and re-zero
+            self.abort_all(e)
+            raise
+        # k-boundary SLO accounting: tell the scheduler how long a
+        # window runs so escalation checks fire a boundary EARLY
+        # instead of a boundary late (docs/SERVING.md)
+        self.sched.note_boundary(_time.perf_counter() - t0)
+        with _trace_span("llm_engine.emit"):
+            return self._emit_window(active, emits, rem, gen_before)
+
+    def _reserve_window(self, active):
+        """Page reservation and host array fill of one fused window:
+        the arguments of the dispatch, or None when the pool cannot
+        cover a 1-token window."""
         ps = self.page_size
         k = self.decode_k
 
@@ -2410,26 +2451,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             gen_before[slot] = req.num_generated
 
         gst, gtrans, gmask = self._grammar_args(active)
-        fused = self._ensure_fused()
-        t0 = _time.perf_counter()
-        try:
-            with _trace_span("llm_engine.fused_step", k=k,
-                             live=len(active)):
-                emits, (self._kv, self._kv_scales, self._key) = fused(
-                    tok0, pos0, rem, fin0, eos, temps, tops, streams,
-                    gst, gtrans, gmask, self._page_tables,
-                    (self._kv, self._kv_scales, self._key))
-                emits = np.asarray(emits)   # the once-per-k host sync
-        except Exception as e:
-            # same contract as the single tick: the donated pytree may
-            # already be consumed — fail in-flight work and re-zero
-            self.abort_all(e)
-            raise
-        # k-boundary SLO accounting: tell the scheduler how long a
-        # window runs so escalation checks fire a boundary EARLY
-        # instead of a boundary late (docs/SERVING.md)
-        self.sched.note_boundary(_time.perf_counter() - t0)
+        return (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst,
+                gtrans, gmask, gen_before)
 
+    def _emit_window(self, active, emits, rem, gen_before):
+        """A fused window's tokens into their requests: append, finish,
+        counters and gauges. Returns the requests finished."""
         self.stats["steps"] += 1
         self.stats["fused_steps"] += 1
         self.stats["occupancy_sum"] += len(active) / self.num_slots
@@ -2517,9 +2544,44 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         """One single-tick compiled step: plan → dispatch → sample
         frontiers on the host → evict finished. `only_slots` is the
         ragged-window straggler tick (prefill-only rows; see step())."""
+        with _trace_span("llm_engine.plan"):
+            planned = self._plan_tick(only_slots)
+        if planned is None:
+            return []
+        (plan, i, tok, pos, sid, widx, klen, sample_idx,
+         sample_slots) = planned
+        with _trace_span("llm_engine.step", rows=len(plan),
+                         prefill_tokens=i - len(sample_slots),
+                         decode_tokens=len(sample_slots)):
+            try:
+                logits, (self._kv, self._kv_scales, self._key) = \
+                    self._step_fn(
+                        tok, pos, sid, widx, self._page_tables, klen,
+                        sample_idx,
+                        (self._kv, self._kv_scales, self._key))
+            except Exception as e:
+                # the donated pools may already be consumed by the
+                # failed dispatch — fail the in-flight work and re-zero
+                # so a direct-drive caller's engine stays serviceable
+                # (the server loop's own abort_all then finds nothing
+                # left to do)
+                self.abort_all(e)
+                raise
+            # the span encloses the host's read of the result, so the
+            # program it launched runs inside it on a profile
+            with _trace_span("llm_engine.sync"):
+                nxt = self._read_frontier(logits, sample_slots)
+        with _trace_span("llm_engine.emit"):
+            return self._emit_tick(plan, i, sample_slots, nxt,
+                                   only_slots)
+
+    def _plan_tick(self, only_slots):
+        """The single tick's planning up to the dispatch: the plan, its
+        pages, and the step's host arrays (staged copies reused for a
+        pure-decode tick). None when nothing is runnable."""
         plan = self._plan(only_slots)
         if plan is None:
-            return []
+            return None
 
         T = self.token_budget
         # pure-decode staging cache: when every planned row is a
@@ -2604,23 +2666,37 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             sharding = mesh_mod.named_sharding()
             sid = jax.device_put(sid, sharding)
             sample_idx = jax.device_put(sample_idx, sharding)
+        return (plan, i, tok, pos, sid, widx, klen, sample_idx,
+                sample_slots)
 
-        try:
-            with _trace_span("llm_engine.step", tokens=i,
-                             live=len(plan)):
-                logits, (self._kv, self._kv_scales, self._key) = \
-                    self._step_fn(
-                        tok, pos, sid, widx, self._page_tables, klen,
-                        sample_idx,
-                        (self._kv, self._kv_scales, self._key))
-        except Exception as e:
-            # the donated pools may already be consumed by the failed
-            # dispatch — fail the in-flight work and re-zero so a
-            # direct-drive caller's engine stays serviceable (the server
-            # loop's own abort_all then finds nothing left to do)
-            self.abort_all(e)
-            raise
+    def _read_frontier(self, logits, sample_slots):
+        """The tick's host read: the next token of every slot at its
+        sampling frontier (greedy argmax or `sample_tokens`), as a numpy
+        array; empty when the tick carried prefill rows only."""
+        if not sample_slots:
+            return []
+        rows = jnp.asarray(sample_slots, jnp.int32)
+        lv = jnp.take(logits[0], rows, axis=0).astype(jnp.float32)
+        frontier = [self._slots[s] for s in sample_slots]
+        if any(r.grammar is not None for r in frontier):
+            # host-path grammar masking: mask the logit VALUES before
+            # the (single-trace) jitted sampler / argmax — identical
+            # picks to the in-scan mask, zero new traces
+            allow = np.ones((len(frontier), lv.shape[1]), bool)
+            for jr, r in enumerate(frontier):
+                if r.grammar is not None:
+                    allow[jr] = r.grammar.allowed_np(r.gstate)
+            lv = jnp.where(jnp.asarray(allow), lv, jnp.float32(-1e30))
+        if any(r.do_sample for r in frontier):
+            return np.asarray(self._host_sample_rows(lv, frontier))
+        # greedy frontier sampling — same pick as generate()'s default
+        # path, so outputs stay token-identical
+        return np.asarray(jnp.argmax(lv, axis=-1))
 
+    def _emit_tick(self, plan, i, sample_slots, nxt, only_slots):
+        """A single tick's result into its requests: counters and
+        gauges, prefill progress, token append, finish. Returns the
+        requests finished."""
         self.stats["steps"] += 1
         self.stats["tokens_in"] += i
         self.stats["occupancy_sum"] += len(plan) / self.num_slots
@@ -2644,28 +2720,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         _LIVE_SLOTS.set(live_now)
         _SLOT_OCC.set(live_now / self.num_slots)
         _PAGE_OCC.set(self.pool.num_live / (self.pool.num_pages - 1))
-
-        nxt = []
-        if sample_slots:
-            rows = jnp.asarray(sample_slots, jnp.int32)
-            lv = jnp.take(logits[0], rows, axis=0).astype(jnp.float32)
-            frontier = [self._slots[s] for s in sample_slots]
-            if any(r.grammar is not None for r in frontier):
-                # host-path grammar masking: mask the logit VALUES
-                # before the (single-trace) jitted sampler / argmax —
-                # identical picks to the in-scan mask, zero new traces
-                allow = np.ones((len(frontier), lv.shape[1]), bool)
-                for jr, r in enumerate(frontier):
-                    if r.grammar is not None:
-                        allow[jr] = r.grammar.allowed_np(r.gstate)
-                lv = jnp.where(jnp.asarray(allow), lv,
-                               jnp.float32(-1e30))
-            if any(r.do_sample for r in frontier):
-                nxt = np.asarray(self._host_sample_rows(lv, frontier))
-            else:
-                # greedy frontier sampling — same pick as generate()'s
-                # default path, so outputs stay token-identical
-                nxt = np.asarray(jnp.argmax(lv, axis=-1))
 
         finished = []
         for slot, req, take in plan:

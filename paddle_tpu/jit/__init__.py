@@ -28,8 +28,9 @@ from ..tensor_core import Parameter, Tensor
 # runtime telemetry (docs/OBSERVABILITY.md). Step time is dispatch-side
 # wall time — donated-buffer steps chain, so once the pipeline fills it
 # converges to true device step time (same reasoning as profiler's
-# _StepTimer). Loss/grad-norm are FULL-telemetry only: reading them
-# forces a device sync that would stall the async dispatch pipeline.
+# _StepTimer). Loss, grad-norm and the `device_step` phase stamp are
+# FULL-telemetry only: reading them forces a device sync that would
+# stall the async dispatch pipeline.
 _STEP_SECONDS = _obs.histogram(
     "pt_train_step_seconds", "compiled train-step wall time")
 _STEPS_TOTAL = _obs.counter(
@@ -536,8 +537,12 @@ class TrainStep:
             (loss, new_frozen), grads = jax.value_and_grad(
                 pure_loss, has_aux=True)(
                 train_vals, frozen_vals, batch_vals, step_key)
-            new_vals, new_states = opt.apply_gradients_tree(
-                train_vals, grads, opt_states, lr, param_objs=train_objs)
+            # named scope (metadata only): the optimizer's share of a
+            # device profile, beside the model's words (gpt.py)
+            with jax.named_scope("optimizer"):
+                new_vals, new_states = opt.apply_gradients_tree(
+                    train_vals, grads, opt_states, lr,
+                    param_objs=train_objs)
             if telemetry_full:
                 gn = jnp.sqrt(sum(
                     jnp.sum(jnp.square(g.astype(jnp.float32)))
@@ -610,8 +615,9 @@ class TrainStep:
         train_vals, frozen_vals = self._split_vals()
         if self._opt_states is None:
             self._opt_states = self._init_opt_states(train_vals)
-        batch_vals = [b._value if isinstance(b, Tensor) else jnp.asarray(b)
-                      for b in batch]
+        with _trace_span("jit.TrainStep.h2d"):
+            batch_vals = [b._value if isinstance(b, Tensor)
+                          else jnp.asarray(b) for b in batch]
         t_h2d = _steptrace.now()
         # recompile guard: every distinct batch signature is a separate
         # XLA compile. Ragged text pipelines that skip bucketing
@@ -679,23 +685,26 @@ class TrainStep:
         else:
             loss, new_vals, self._opt_states, new_frozen = out
             grad_norm = None
-        if _steptrace.active():
-            # device_step = the block_until_ready delta. Only paid
-            # with telemetry on — and cheap even then: donated-buffer
-            # steps chain, so the dispatch-side wall this sync exposes
-            # is time the NEXT dispatch would have blocked on anyway.
+        if _steptrace.full():
+            # device_step = the block_until_ready delta: full telemetry
+            # accepts a device sync per step. Below it nothing waits
+            # here — a loop that does not read the loss every step
+            # keeps the next batch's conversion and dispatch under the
+            # device's work, and a step's wall time (previous step's
+            # end to this one's) converges to the device step once the
+            # dispatch queue is full.
             jax.block_until_ready(
                 (loss, new_vals, self._opt_states, new_frozen))
             tr.stamp("device_step")
         _STEP_SECONDS.observe(_time.perf_counter() - t0)
         _STEPS_TOTAL.inc()
-        it = iter(new_vals)
-        it_f = iter(new_frozen)
-        for p, t in zip(self._param_objs, self._trainable):
-            p._value = next(it) if t else next(it_f)
-        self.optimizer._step_count += 1
+        with _trace_span("jit.TrainStep.publish"):
+            it = iter(new_vals)
+            it_f = iter(new_frozen)
+            for p, t in zip(self._param_objs, self._trainable):
+                p._value = next(it) if t else next(it_f)
+            self.optimizer._step_count += 1
         if grad_norm is not None:
-            # full telemetry accepts the device sync these reads force
             _LOSS_GAUGE.set(float(np.asarray(loss)))
             _GRAD_NORM.observe(float(np.asarray(grad_norm)))
         tr.stamp("opt_publish")

@@ -11,7 +11,8 @@ identity those phases share, mirroring the reqtrace/TTFT contract
 
 * :class:`StepTrace` — one step's first-wins phase timeline. The
   instrumented steps stamp ``data_wait`` / ``ckpt_snapshot`` / ``h2d``
-  / ``dispatch`` / ``device_step`` (the ``block_until_ready`` delta) /
+  / ``dispatch`` / ``device_step`` (the ``block_until_ready`` delta,
+  full mode only: no stamp stalls a step in the default mode) /
   ``opt_publish``; each new stamp emits the segment since the previous
   stamp three ways: a ``pt_train_phase_seconds{phase}`` histogram
   sample, a flight-recorder ``train_phase`` event, and in full mode a
@@ -54,7 +55,8 @@ from .metrics import _STATE, counter, gauge, histogram, \
     summarize_histogram_cell
 
 __all__ = ["StepTrace", "PHASES", "begin_step", "end_step", "active",
-           "now", "note_ckpt_snapshot", "note_recompile", "model_flops",
+           "full", "now", "note_ckpt_snapshot", "note_recompile",
+           "model_flops",
            "arm_goodput", "goodput_armed", "recent_steps", "reset",
            "phase_summary", "straggler_of", "collective_bytes_per_second"]
 
@@ -62,8 +64,9 @@ __all__ = ["StepTrace", "PHASES", "begin_step", "end_step", "active",
 # anchor stamp opens the chain and is never a histogram label). A step
 # only takes the stamps its path crosses: the first step of a process
 # has no previous step to wait on (no data_wait), a run without
-# checkpointing never stamps ckpt_snapshot, and device_step needs
-# telemetry on (the sync is skipped when nothing would record it).
+# checkpointing never stamps ckpt_snapshot, and device_step needs FULL
+# telemetry (the only mode that accepts a device sync per step; below
+# it the device's time rides in the next step's data_wait/dispatch).
 PHASES = ("ckpt_snapshot", "data_wait", "h2d", "dispatch",
           "device_step", "opt_publish")
 
@@ -101,9 +104,16 @@ def now():
 
 def active():
     """True when steptrace should measure (telemetry metrics mode or
-    up). The instrumented steps skip the device_step sync when nothing
-    would record it — tracing must not change OFF-mode pipelining."""
+    up): the stamps that cost nothing."""
     return bool(_STATE.mode)
+
+
+def full():
+    """True in full telemetry only: the mode that accepts a device
+    sync per step, so the one stamp that needs one (`device_step`, the
+    `block_until_ready` delta) is taken there and nowhere else — a
+    stamp must not stall the step it times."""
+    return _STATE.mode >= _STATE.FULL
 
 
 class StepTrace:

@@ -1,18 +1,20 @@
 """Span tracer — chrome://tracing-compatible host-side spans.
 
 ``trace_span("name", key=val)`` is a context manager AND a decorator.
-In full-telemetry mode (``PT_TELEMETRY=1``) each span records one
+In every mode but ``off`` entering a span enters a
+``jax.profiler.TraceAnnotation(name, **args)``: a TraceMe, recorded
+only while a ``jax.profiler`` capture runs, on the capture's clock,
+beside the device planes — so a profile names the host's part of a
+step in the program's words and an idle gap on the device can be laid
+against the span over it. With no capture running it costs about a
+microsecond (the overhead test pins the bundle; PERF.md §6, PR 25 has
+the chip host's number), so spans stay at step granularity, never per
+token or per request row.
+In full-telemetry mode (``PT_TELEMETRY=1``) each span ALSO records one
 complete ("ph": "X") chrome trace event: wall-clock ``ts`` (µs since the
 unix epoch, so per-rank files from different processes align when
 merged), monotonic ``dur``, ``pid`` = trainer rank, ``tid`` = thread id.
-Below full mode entering a span is a single attribute check — the
-overhead test pins it.
-
-Composition with the xprof path: spans optionally ALSO enter the
-existing ``profiler.RecordEvent`` (a jax TraceAnnotation), so the same
-scopes show up on the device timeline when a ``jax.profiler`` capture is
-active. Gated by ``PT_TRACE_ANNOTATE=1`` because TraceAnnotation has a
-per-call cost even without an active capture.
+In ``off`` mode entering a span is a single attribute check.
 
 Export: events buffer in memory (bounded; drops counted) and flush to
 ``<PT_TELEMETRY_DIR>/trace.rank<r>.jsonl`` — one JSON event per line.
@@ -23,6 +25,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .metrics import _STATE, counter
 
@@ -126,10 +130,6 @@ def _rank():
     return int(os.environ.get("PADDLE_TRAINER_ID", "0"))
 
 
-def _annotate_enabled():
-    return os.environ.get("PT_TRACE_ANNOTATE", "0") == "1"
-
-
 class _Span:
     """One span use. Context manager (enter/exit records an event) and
     decorator (wraps fn; a fresh span per call)."""
@@ -143,23 +143,26 @@ class _Span:
         self._ann = None
 
     def __enter__(self):
-        if _STATE.mode < 2:
+        mode = _STATE.mode
+        if not mode:
             return self
-        self._wall0 = time.time()
-        self._t0 = time.perf_counter()
-        if _annotate_enabled():
-            try:
-                from ..profiler import RecordEvent
-
-                self._ann = RecordEvent(self.name)
-                self._ann.begin()
-            except Exception:
-                self._ann = None
+        self._ann = _TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if mode >= 2:
+            self._wall0 = time.time()
+            self._t0 = time.perf_counter()
         return self
+
+    def set(self, **args):
+        """Add args learned inside the span (how many requests an
+        admission pass took in)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __exit__(self, exc_type, exc, tb):
         if self._ann is not None:
-            self._ann.end()
+            self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
         if self._t0 is None:
             return False
@@ -192,7 +195,8 @@ class _Span:
 
 def trace_span(name, **args):
     """Span factory: ``with trace_span("x", k=v): ...`` or
-    ``@trace_span("x")``. No-op (one mode check) below full telemetry."""
+    ``@trace_span("x")``. A profiler annotation in every mode but off
+    (one mode check there); a chrome event besides in full mode."""
     return _Span(name, args)
 
 

@@ -18,6 +18,8 @@ TPU-first choices:
 """
 import math
 
+import jax
+
 from ... import nn
 from ...distributed.fleet.meta_parallel.mp_layers import (
     ColumnParallelLinear,
@@ -35,6 +37,16 @@ __all__ = [
     "GPTPretrainingCriterion", "gpt_tiny", "gpt_small", "gpt_medium",
     "gpt_1p3b", "sample_tokens",
 ]
+
+
+# Named scopes (`jax.named_scope`, metadata only) put ONE small
+# vocabulary into every operation's `op_name`, shared by the training
+# and the serving forward, so a device profile can be read by block:
+# embed, attn, mlp, norm (nested inside attn/mlp, and ln_f), lm_head,
+# loss, sample — plus `optimizer` in jit.TrainStep. Backward operations
+# inherit the word through `transpose(jvp(...))`. The catalogue and the
+# metrics that read it: docs/OBSERVABILITY.md "Spans and scopes".
+_scope = jax.named_scope
 
 
 class GPTConfig:
@@ -98,14 +110,19 @@ class GPTDecoderLayer(nn.Layer):
     def forward(self, x):
         b = x.shape[0]
         s = x.shape[1]
-        h = self.ln1(x)
-        qkv = self.qkv(h)  # [b, s, 3d] (mp-sharded last dim)
-        q, k, v = split_fused_qkv(qkv, b, s, self.nh, self.hd)
-        attn = F.scaled_dot_product_attention(q, k, v, is_causal=True)
-        attn = manip.reshape(attn, [b, s, self.nh * self.hd])
-        x = x + self.dropout(self.proj(attn))
-        h = self.ln2(x)
-        x = x + self.dropout(self.fc2(F.gelu(self.fc1(h))))
+        with _scope("attn"):
+            with _scope("norm"):
+                h = self.ln1(x)
+            qkv = self.qkv(h)  # [b, s, 3d] (mp-sharded last dim)
+            q, k, v = split_fused_qkv(qkv, b, s, self.nh, self.hd)
+            attn = F.scaled_dot_product_attention(q, k, v,
+                                                  is_causal=True)
+            attn = manip.reshape(attn, [b, s, self.nh * self.hd])
+            x = x + self.dropout(self.proj(attn))
+        with _scope("mlp"):
+            with _scope("norm"):
+                h = self.ln2(x)
+            x = x + self.dropout(self.fc2(F.gelu(self.fc1(h))))
         return x
 
 
@@ -127,10 +144,11 @@ class GPTModel(nn.Layer):
         s = input_ids.shape[1]
         from ...ops.creation import arange
 
-        pos = arange(0, s, dtype="int64")
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = self.drop(x)
-        x = shard_activation(x, "dp", "sp", None)
+        with _scope("embed"):
+            pos = arange(0, s, dtype="int64")
+            x = self.wte(input_ids) + self.wpe(pos)
+            x = self.drop(x)
+            x = shard_activation(x, "dp", "sp", None)
         rc = self.config.recompute
         if rc:
             from ...distributed.fleet.recompute import recompute as _rc
@@ -141,7 +159,8 @@ class GPTModel(nn.Layer):
         else:
             for layer in self.layers:
                 x = layer(x)
-        return self.ln_f(x)
+        with _scope("norm"):
+            return self.ln_f(x)
 
 
 # ------------------------------------------------------------ generation
@@ -201,15 +220,19 @@ def _layer_forward_cached(layer, x, cache, index, pad_lens=None):
     decode step can be captured by to_static and dispatched as ONE
     compiled program per token."""
     b, s = x.shape[0], x.shape[1]
-    h = layer.ln1(x)
-    qkv = layer.qkv(h)
-    q, k, v = split_fused_qkv(qkv, b, s, layer.nh, layer.hd)
-    attn, ck, cv = _cached_attention(q, k, v, cache["k"], cache["v"],
-                                     index, pad_lens=pad_lens)
-    attn = manip.reshape(attn, [b, s, layer.nh * layer.hd])
-    x = x + layer.proj(attn)
-    h = layer.ln2(x)
-    return x + layer.fc2(F.gelu(layer.fc1(h))), {"k": ck, "v": cv}
+    with _scope("attn"):
+        with _scope("norm"):
+            h = layer.ln1(x)
+        qkv = layer.qkv(h)
+        q, k, v = split_fused_qkv(qkv, b, s, layer.nh, layer.hd)
+        attn, ck, cv = _cached_attention(q, k, v, cache["k"], cache["v"],
+                                         index, pad_lens=pad_lens)
+        attn = manip.reshape(attn, [b, s, layer.nh * layer.hd])
+        x = x + layer.proj(attn)
+    with _scope("mlp"):
+        with _scope("norm"):
+            h = layer.ln2(x)
+        return x + layer.fc2(F.gelu(layer.fc1(h))), {"k": ck, "v": cv}
 
 
 def _paged_cache_write(k_pool, v_pool, k_new, v_new, write_idx):
@@ -299,30 +322,35 @@ def _layer_forward_paged(layer, x, cache_k, cache_v, write_idx,
     exactly k+1) lets attention size its slot grid [S, k+1] instead of
     the worst-case [S, T]."""
     T = x.shape[1]
-    h = layer.ln1(x)
-    qkv = layer.qkv(h)
-    q, k, v = split_fused_qkv(qkv, 1, T, layer.nh, layer.hd)
-    q = manip.reshape(q, [T, layer.nh, layer.hd])
-    k = manip.reshape(k, [T, layer.nh, layer.hd])
-    v = manip.reshape(v, [T, layer.nh, layer.hd])
-    if k_scales is None:
-        ck, cv = _paged_cache_write(cache_k, cache_v, k, v, write_idx)
-        attn = F.paged_attention(q, ck, cv, page_tables, slot_ids,
-                                 kv_lens,
-                                 frontier_offset=frontier_offset,
-                                 max_tokens_per_slot=max_q_per_slot)
-        cks = cvs = None
-    else:
-        ck, cv, cks, cvs = _paged_cache_write_quant(
-            cache_k, cache_v, k_scales, v_scales, k, v, write_idx)
-        attn = F.paged_attention(q, ck, cv, page_tables, slot_ids,
-                                 kv_lens, k_scales=cks, v_scales=cvs,
-                                 frontier_offset=frontier_offset,
-                                 max_tokens_per_slot=max_q_per_slot)
-    attn = manip.reshape(attn, [1, T, layer.nh * layer.hd])
-    x = x + layer.proj(attn)
-    h = layer.ln2(x)
-    out = x + layer.fc2(F.gelu(layer.fc1(h)))
+    with _scope("attn"):
+        with _scope("norm"):
+            h = layer.ln1(x)
+        qkv = layer.qkv(h)
+        q, k, v = split_fused_qkv(qkv, 1, T, layer.nh, layer.hd)
+        q = manip.reshape(q, [T, layer.nh, layer.hd])
+        k = manip.reshape(k, [T, layer.nh, layer.hd])
+        v = manip.reshape(v, [T, layer.nh, layer.hd])
+        if k_scales is None:
+            ck, cv = _paged_cache_write(cache_k, cache_v, k, v,
+                                        write_idx)
+            attn = F.paged_attention(q, ck, cv, page_tables, slot_ids,
+                                     kv_lens,
+                                     frontier_offset=frontier_offset,
+                                     max_tokens_per_slot=max_q_per_slot)
+            cks = cvs = None
+        else:
+            ck, cv, cks, cvs = _paged_cache_write_quant(
+                cache_k, cache_v, k_scales, v_scales, k, v, write_idx)
+            attn = F.paged_attention(q, ck, cv, page_tables, slot_ids,
+                                     kv_lens, k_scales=cks, v_scales=cvs,
+                                     frontier_offset=frontier_offset,
+                                     max_tokens_per_slot=max_q_per_slot)
+        attn = manip.reshape(attn, [1, T, layer.nh * layer.hd])
+        x = x + layer.proj(attn)
+    with _scope("mlp"):
+        with _scope("norm"):
+            h = layer.ln2(x)
+        out = x + layer.fc2(F.gelu(layer.fc1(h)))
     if k_scales is None:
         return out, ck, cv
     return out, ck, cv, cks, cvs
@@ -356,38 +384,39 @@ def sample_tokens(logits, temps, top_ps, streams, positions, key,
     reshapes the distribution but not the key: constrained +
     speculative composes losslessly because acceptance is exact-match
     against this same keyed pick, masked or not."""
-    import jax
     import jax.numpy as jnp
 
-    if allowed is not None:
-        logits = jnp.where(allowed, logits, jnp.float32(-1e30))
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with _scope("sample"):
+        if allowed is not None:
+            logits = jnp.where(allowed, logits, jnp.float32(-1e30))
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def drawn(_):
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        # top-p: keep the smallest prefix of the descending-prob list
-        # whose EXCLUSIVE cumulative mass is < top_p (always keeps the
-        # top-1)
-        srt = jnp.sort(scaled, axis=-1)[:, ::-1]
-        probs = jax.nn.softmax(srt, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < top_ps[:, None]
-        thresh = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1)
-        masked = jnp.where(scaled >= thresh[:, None], scaled,
-                           jnp.float32(-1e30))
-        keys = jax.vmap(
-            lambda s, p: jax.random.fold_in(jax.random.fold_in(key, s),
-                                            p)
-        )(streams.astype(jnp.uint32), positions.astype(jnp.uint32))
-        pick = jax.vmap(jax.random.categorical)(keys, masked)
-        return jnp.where(temps > 0, pick, greedy).astype(jnp.int32)
+        def drawn(_):
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            # top-p: keep the smallest prefix of the descending-prob
+            # list whose EXCLUSIVE cumulative mass is < top_p (always
+            # keeps the top-1)
+            srt = jnp.sort(scaled, axis=-1)[:, ::-1]
+            probs = jax.nn.softmax(srt, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            keep = (cum - probs) < top_ps[:, None]
+            thresh = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1)
+            masked = jnp.where(scaled >= thresh[:, None], scaled,
+                               jnp.float32(-1e30))
+            keys = jax.vmap(
+                lambda s, p: jax.random.fold_in(
+                    jax.random.fold_in(key, s), p)
+            )(streams.astype(jnp.uint32), positions.astype(jnp.uint32))
+            pick = jax.vmap(jax.random.categorical)(keys, masked)
+            return jnp.where(temps > 0, pick, greedy).astype(jnp.int32)
 
-    # all-greedy batches skip the whole sort/cumsum/draw branch at RUN
-    # time (lax.cond executes one side): the fused scan calls this every
-    # iteration, and a vocab-wide sort per tick would tax exactly the
-    # dispatch-bound serving the fused window exists to speed up
-    return jax.lax.cond(jnp.any(temps > 0), drawn,
-                        lambda _: greedy, None)
+        # all-greedy batches skip the whole sort/cumsum/draw branch at
+        # RUN time (lax.cond executes one side): the fused scan calls
+        # this every iteration, and a vocab-wide sort per tick would
+        # tax exactly the dispatch-bound serving the fused window
+        # exists to speed up
+        return jax.lax.cond(jnp.any(temps > 0), drawn,
+                            lambda _: greedy, None)
 
 
 def grammar_allowed(gmask, gstate, vocab):
@@ -416,20 +445,22 @@ class GPTGenerationMixin:
 
         model = self.gpt
         s = input_ids.shape[1]
-        pos = arange(0, s, dtype="int64") + index
-        if pad_lens is not None:
-            # left-padded rows start their position ids AFTER the pads
-            # (clamped at 0 for the pad slots themselves, which attention
-            # masks out anyway)
-            pos = (pos.unsqueeze(0) - pad_lens.unsqueeze(1)).clip(
-                0, self.config.max_seq_len - 1)
-        x = model.wte(input_ids) + model.wpe(pos)
+        with _scope("embed"):
+            pos = arange(0, s, dtype="int64") + index
+            if pad_lens is not None:
+                # left-padded rows start their position ids AFTER the
+                # pads (clamped at 0 for the pad slots themselves, which
+                # attention masks out anyway)
+                pos = (pos.unsqueeze(0) - pad_lens.unsqueeze(1)).clip(
+                    0, self.config.max_seq_len - 1)
+            x = model.wte(input_ids) + model.wpe(pos)
         new_caches = []
         for layer, cache in zip(model.layers, caches):
             x, nc = _layer_forward_cached(layer, x, cache, index,
                                           pad_lens=pad_lens)
             new_caches.append(nc)
-        x = model.ln_f(x)
+        with _scope("norm"):
+            x = model.ln_f(x)
         return self._logits_from_hidden(x, shard=False), new_caches
 
     def _decode_core(self, tok, idx, pad_lens, kv):
@@ -496,7 +527,8 @@ class GPTGenerationMixin:
         `_layer_forward_paged`) — the caller guarantees no slot owns
         more than this many flat tokens this step."""
         model = self.gpt
-        x = model.wte(tok.unsqueeze(0)) + model.wpe(pos_ids)
+        with _scope("embed"):
+            x = model.wte(tok.unsqueeze(0)) + model.wpe(pos_ids)
         flat, scale_flat = [], []
         for i, layer in enumerate(model.layers):
             if kv_scales is None:
@@ -515,8 +547,10 @@ class GPTGenerationMixin:
                     max_q_per_slot=max_q_per_slot)
                 scale_flat += [cks, cvs]
             flat += [ck, cv]
-        x = model.ln_f(x)
-        x = manip.gather(x, sample_idx, axis=1)  # [1, S, d] frontiers
+        with _scope("norm"):
+            x = model.ln_f(x)
+        with _scope("lm_head"):
+            x = manip.gather(x, sample_idx, axis=1)  # [1, S, d] frontiers
         return (self._logits_from_hidden(x, shard=False), *flat,
                 *scale_flat)
 
@@ -615,28 +649,29 @@ class GPTGenerationMixin:
             n = len(kv_c)
             kv2 = [x._value for x in new[:n]]
             kvs2 = [x._value for x in new[n:]]
-            lv = logits._value[0].astype(jnp.float32)  # [S, vocab]
-            allowed = None
-            if structured:
-                V = lv.shape[1]
-                allowed = jax.lax.cond(
-                    any_g,
-                    lambda s: grammar_allowed(gmask, s, V),
-                    lambda s: jnp.ones((S, V), jnp.bool_), gs)
-            nxt = sample_tokens(lv, temps, top_ps, streams, pos_in + 1,
-                                key, allowed=allowed)
-            if lag is not None:
-                # propose mode: a lag row's iteration-0 output IS the
-                # already-known frontier token — force it so later
-                # proposals condition on the true sequence
-                nxt = jnp.where((i == 0) & (lag > 0), frontier, nxt)
-            emit = jnp.where(live, nxt, pad)
-            fin2 = (fin | (live & (eos_ids >= 0) & (nxt == eos_ids))
-                    | (live & (i + 1 >= rem)))
-            tok2 = jnp.where(live, nxt, tok)
-            if structured:
-                gs2 = jnp.where(live, gtrans[gs, nxt], gs)
-                return (tok2, fin2, gs2, kv2, kvs2), emit
+            with _scope("sample"):
+                lv = logits._value[0].astype(jnp.float32)  # [S, vocab]
+                allowed = None
+                if structured:
+                    V = lv.shape[1]
+                    allowed = jax.lax.cond(
+                        any_g,
+                        lambda s: grammar_allowed(gmask, s, V),
+                        lambda s: jnp.ones((S, V), jnp.bool_), gs)
+                nxt = sample_tokens(lv, temps, top_ps, streams,
+                                    pos_in + 1, key, allowed=allowed)
+                if lag is not None:
+                    # propose mode: a lag row's iteration-0 output IS
+                    # the already-known frontier token — force it so
+                    # later proposals condition on the true sequence
+                    nxt = jnp.where((i == 0) & (lag > 0), frontier, nxt)
+                emit = jnp.where(live, nxt, pad)
+                fin2 = (fin | (live & (eos_ids >= 0) & (nxt == eos_ids))
+                        | (live & (i + 1 >= rem)))
+                tok2 = jnp.where(live, nxt, tok)
+                if structured:
+                    gs2 = jnp.where(live, gtrans[gs, nxt], gs)
+                    return (tok2, fin2, gs2, kv2, kvs2), emit
             return (tok2, fin2, kv2, kvs2), emit
 
         init = ((tok0, fin0, gstate0, list(kv), list(kv_scales or []))
@@ -935,13 +970,14 @@ class GPTForCausalLM(GPTGenerationMixin, nn.Layer):
     def _logits_from_hidden(self, x, shard=True):
         """ONE head projection shared by training forward and cached
         decode (shard hints only matter on a mesh)."""
-        if self.lm_head is not None:
-            return self.lm_head(x)
-        w = self.gpt.wte.weight  # [vocab, d], mp-sharded on vocab
-        logits = F.linear(x, manip.transpose(w, [1, 0]))
-        if shard:
-            logits = shard_activation(logits, "dp", "sp", "mp")
-        return logits
+        with _scope("lm_head"):
+            if self.lm_head is not None:
+                return self.lm_head(x)
+            w = self.gpt.wte.weight  # [vocab, d], mp-sharded on vocab
+            logits = F.linear(x, manip.transpose(w, [1, 0]))
+            if shard:
+                logits = shard_activation(logits, "dp", "sp", "mp")
+            return logits
 
     def forward(self, input_ids):
         return self._logits_from_hidden(self.gpt(input_ids))
@@ -965,23 +1001,26 @@ class GPTForCausalLM(GPTGenerationMixin, nn.Layer):
         if labels is None:
             labels = input_ids
         x = self.gpt(input_ids)  # [b, s, d]
-        shift_x = manip.slice(x, [1], [0], [x.shape[1] - 1])
-        shift_labels = manip.slice(labels, [1], [1], [labels.shape[1]])
-        # sum/total-count, NOT mean-over-valid: GPTPretrainingCriterion
-        # means over ALL positions (ignored ones contribute 0), and the
-        # two paths must stay loss- and grad-scale identical for the
-        # BENCH_GPT_FUSED_HEAD A/B to be meaningful
-        total = shift_labels.shape[0] * shift_labels.shape[1]
-        if self.lm_head is not None:
-            s = F.fused_linear_cross_entropy(
-                shift_x, self.lm_head.weight, shift_labels,
-                reduction="sum", block_size=block_size)
-        else:
-            s = F.fused_linear_cross_entropy(
-                shift_x, self.gpt.wte.weight, shift_labels,
-                transpose_weight=True, reduction="sum",
-                block_size=block_size)
-        return s / float(total)
+        with _scope("lm_head"):
+            shift_x = manip.slice(x, [1], [0], [x.shape[1] - 1])
+            shift_labels = manip.slice(labels, [1], [1],
+                                       [labels.shape[1]])
+            # sum/total-count, NOT mean-over-valid:
+            # GPTPretrainingCriterion means over ALL positions (ignored
+            # ones contribute 0), and the two paths must stay loss- and
+            # grad-scale identical for the BENCH_GPT_FUSED_HEAD A/B to
+            # be meaningful
+            total = shift_labels.shape[0] * shift_labels.shape[1]
+            if self.lm_head is not None:
+                s = F.fused_linear_cross_entropy(
+                    shift_x, self.lm_head.weight, shift_labels,
+                    reduction="sum", block_size=block_size)
+            else:
+                s = F.fused_linear_cross_entropy(
+                    shift_x, self.gpt.wte.weight, shift_labels,
+                    transpose_weight=True, reduction="sum",
+                    block_size=block_size)
+            return s / float(total)
 
 
 class GPTPretrainingCriterion(nn.Layer):
@@ -994,10 +1033,12 @@ class GPTPretrainingCriterion(nn.Layer):
     def forward(self, logits, labels):
         from ...ops.math import mean
 
-        shift_logits = manip.slice(
-            logits, [1], [0], [logits.shape[1] - 1])
-        shift_labels = manip.slice(labels, [1], [1], [labels.shape[1]])
-        loss = self.ce(shift_logits, shift_labels)
-        return mean(loss)
+        with _scope("loss"):
+            shift_logits = manip.slice(
+                logits, [1], [0], [logits.shape[1] - 1])
+            shift_labels = manip.slice(labels, [1], [1],
+                                       [labels.shape[1]])
+            loss = self.ce(shift_logits, shift_labels)
+            return mean(loss)
 
 

@@ -146,7 +146,9 @@ def test_instrumentation_overhead_pinned(mode):
     """Acceptance: with telemetry off, per-step instrumentation costs
     <1% of a step. A generous CPU step is ~2 ms; one step's worth of
     instrumentation is ~10 metric writes + a span, so pin the per-call
-    cost well under 2 µs (10 calls × 2 µs = 20 µs = 1% of 2 ms)."""
+    cost well under 2 µs (10 calls × 2 µs = 20 µs = 1% of 2 ms). In
+    the default mode the span is a profiler annotation (no capture
+    running here): it rides inside the same 40 µs budget."""
     reg = _reg()
     c = reg.counter("ovh_total")
     h = reg.histogram("ovh_seconds")

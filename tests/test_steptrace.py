@@ -32,7 +32,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # telescoping, so pin much tighter
 SUM_TOL = 1e-9
 
-EMITTING = {"data_wait", "h2d", "dispatch", "device_step", "opt_publish"}
+# `device_step` is the block_until_ready delta: full mode only (no
+# stamp stalls a step in the default mode)
+EMITTING = {"data_wait", "h2d", "dispatch", "opt_publish"}
+EMITTING_FULL = EMITTING | {"device_step"}
 
 
 @pytest.fixture
@@ -75,11 +78,15 @@ def _assert_identity(rec):
 
 # ------------------------------------------------ phase decomposition
 
-def test_trainstep_phase_identity_and_quiet_warmup(mode):
+@pytest.mark.parametrize("telemetry,emitting", [
+    ("metrics", EMITTING), ("full", EMITTING_FULL)])
+def test_trainstep_phase_identity_and_quiet_warmup(mode, telemetry,
+                                                   emitting):
     """4 calls → 3 ring records (the compile step runs quiet); each
     record's segments sum exactly to its wall time, stamps arrive in
-    the canonical order, and the histogram carries every phase."""
-    obs.set_mode("metrics")
+    the canonical order, and the histogram carries every phase the
+    mode takes: `device_step` in full mode only."""
+    obs.set_mode(telemetry)
     steptrace.reset()
     ps0 = steptrace.phase_summary()
     _, _, step, x, y = _tiny_step()
@@ -96,11 +103,12 @@ def test_trainstep_phase_identity_and_quiet_warmup(mode):
         assert names[0] == "start"
         idx = [order[n] for n in names]
         assert idx == sorted(idx), names
-        assert EMITTING <= set(names)
+        assert set(names) - {"start"} == emitting
     ps = steptrace.phase_summary()
-    for phase in EMITTING:
-        delta = ps[phase]["count"] - ps0.get(phase, {}).get("count", 0)
-        assert delta == 3, (phase, delta)
+    for phase in EMITTING_FULL:
+        delta = ps.get(phase, {}).get("count", 0) \
+            - ps0.get(phase, {}).get("count", 0)
+        assert delta == (3 if phase in emitting else 0), (phase, delta)
     # the internal chain anchor is never a histogram label
     assert "start" not in ps
 
@@ -307,6 +315,46 @@ def test_goodput_gauges_continuous(mode):
     assert not steptrace.goodput_armed()
 
 
+def test_goodput_gauges_read_step_intervals_without_a_sync(
+        mode, monkeypatch):
+    """Default mode: no train step waits for the device (the calls to
+    jax.block_until_ready are counted), a step's chain runs from the
+    previous step's last stamp to its own, and the goodput gauge is
+    tokens over that interval. Full mode syncs once a step and stamps
+    `device_step`."""
+    import jax
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or real(x))
+    obs.set_mode("metrics")
+    steptrace.reset()
+    steptrace.arm_goodput(tokens_per_step=4096)
+    _, _, step, x, y = _tiny_step()
+    for _ in range(4):
+        step(x, y)
+    assert calls == []
+    recs = steptrace.recent_steps()
+    assert [r["step"] for r in recs] == [1, 2, 3]
+    for prev, rec in zip(recs, recs[1:]):
+        assert rec["timeline"][0]["phase"] == "start"
+        assert rec["timeline"][0]["t"] == prev["timeline"][-1]["t"]
+        assert "device_step" not in {e["phase"] for e in rec["timeline"]}
+    assert obs.registry().get("pt_train_tokens_per_second").value == \
+        pytest.approx(4096 / recs[-1]["total_s"])
+    steptrace.arm_goodput()
+
+    obs.set_mode("full")
+    steptrace.reset()
+    _, _, step, x, y = _tiny_step()
+    for _ in range(3):
+        step(x, y)
+    assert len(calls) == 3
+    assert all("device_step" in {e["phase"] for e in r["timeline"]}
+               for r in steptrace.recent_steps())
+
+
 def test_model_flops_accountant():
     """The analytic accountant: dict and object configs agree, the
     default ffn is 4·d, and bench.py's gpt_flops_per_step IS this
@@ -380,7 +428,8 @@ def test_full_mode_chrome_events_feed_train_report(mode):
         step(x, y)
     evs = [e for e in obs.chrome_events()
            if e["name"].startswith("step.")]
-    assert {"step." + p for p in EMITTING} <= {e["name"] for e in evs}
+    assert {"step." + p for p in EMITTING_FULL} <= {e["name"]
+                                                    for e in evs}
     assert all("step" in e["args"] and "family" in e["args"]
                for e in evs)
     report = _load_trace_merge().train_report(evs)
