@@ -43,6 +43,13 @@ import traceback
 # over page rows in another order. Outputs are O(1); measured on the
 # v5e: <= 2.4e-6 over every variant (PR 21).
 TOL_PAGED = 2e-5
+# the same at the decode cell's dtypes: bf16 q and pool, f32 inside,
+# bf16 out — the reference runs in f32 on the same bf16-rounded inputs,
+# so what is left is the output's rounding: 2^-9 relative, on values
+# that reach 4 where a row attends one or two N(0, 1) tokens (7.8e-3);
+# measured on the v5e 1.4e-3 (24 long rows) and 7.5e-3 (rows of 1..69
+# tokens) (PR 26).
+TOL_PAGED_BF16 = 2e-2
 # flash fwd, bf16 operands: the kernel rounds p to bf16 before p·v and
 # returns bf16 (8 mantissa bits, 2^-8 = 3.9e-3 relative); the reference
 # is f32/highest on the same bf16-rounded inputs. Outputs are O(1);
@@ -335,6 +342,47 @@ def _paged_cases(sm):
             return {"max_abs_err": err, "table_bytes": pt.size * 4}
 
         sm.case("paged_smem_table_256x128", run_case, out)
+
+        # the decode cell's own launches (cerebras-gpt-1.3b: 16 heads ×
+        # 128, bf16 pool, 24 slots × 128 pages of 16): the fused
+        # window's 24 rows all live, and a single tick's 256 rows of
+        # which 69 prefill a fresh prompt and the rest are padding.
+        # These shapes take the kernel's in-kernel page walk.
+        rng = np.random.default_rng(11)
+        n_pool = 1024
+        pools = [jnp.asarray(rng.standard_normal((n_pool, 16, 16, 128)),
+                             jnp.bfloat16) for _ in range(2)]
+        pt = jnp.asarray(rng.integers(1, n_pool, (24, 128)), jnp.int32)
+        window = (np.arange(24), np.concatenate(
+            [rng.integers(70, 1371, 22), [1, 2048]]))
+        tick = (np.where(np.arange(256) < 69, 3, 0),
+                np.where(np.arange(256) < 69, np.arange(256) + 1, 0))
+        for name, (sid, lens) in (("window_t24", window),
+                                  ("tick_t256_69live", tick)):
+            q = jnp.asarray(rng.standard_normal((len(sid), 16, 128)),
+                            jnp.bfloat16)
+            args = (q, *pools, pt, jnp.asarray(sid, jnp.int32),
+                    jnp.asarray(lens, jnp.int32))
+            key = f"paged_cell_h16x128_p16_bfloat16_{name}"
+
+            def run_cell(args=args, key=key):
+                got = jax.block_until_ready(jax.jit(
+                    lambda *a: ragged_paged_attention(*a))(*args))
+                f32 = tuple(a.astype(jnp.float32) for a in args[:3])
+                with jax.default_matmul_precision("highest"):
+                    want = jax.jit(lambda *a: paged_attention_jnp(*a))(
+                        *f32, *args[3:])
+                err = _maxdiff(got, want)
+                pad_zero = bool(np.all(
+                    np.asarray(got, np.float32)[np.asarray(args[5]) == 0]
+                    == 0))
+                sm.say(f"kernel {key}: max|Δ| {err:.2e} (tol "
+                       f"{TOL_PAGED_BF16:g}) padding rows zero={pad_zero}")
+                sm.check(err <= TOL_PAGED_BF16 and pad_zero,
+                         f"kernel {key}: err {err}, pad_zero {pad_zero}")
+                return {"max_abs_err": err, "pad_rows_zero": pad_zero}
+
+            sm.case(key, run_cell, out)
     return out
 
 

@@ -306,9 +306,12 @@ def _pallas_backend_ok():
 
 
 def _paged_pallas_eligible(q, k_pool):
-    """Pallas ragged-paged-attention gate: `_pallas_backend_ok` +
-    MXU-friendly head_dim + lane-tileable page size (the grid is
-    per-token so seq alignment is moot)."""
+    """Pallas ragged-paged-attention gate: `_pallas_backend_ok` + a
+    head_dim the kernel's `[H, D]` lane tiles take + a sublane-tileable
+    page size. Nothing about sequence lengths: a query block is one
+    token (or one slot's verify rows) and walks only the pages its row
+    has; whether the kernel copies those pages itself or Mosaic's
+    pipeline does is the kernel's own choice from the pool's shape."""
     return (
         _pallas_backend_ok()
         and len(q.shape) == 3

@@ -9,23 +9,40 @@ serving stack keeps a contiguous per-request cache instead — paging is
 what lets HBM scale with live tokens).
 
 Layout: q [T, heads, head_dim]; the pool [num_pages, page_size, heads,
-head_dim]. Grid (T / qb, pages_per_seq) with the page dimension
-innermost (qb = 1 per-token, or the verify step's k+1 rows per slot):
-each query block revisits its output block across page steps, so the
-f32 accumulator and the online-softmax (m, l) statistics live in VMEM
-scratch and are finalized on the last page step — the same
-FlashAttention-2 shape as flash_attention.py, but the kv blocks are
-GATHERED through the page table: the page id for grid step (b, j) is
-read from scalar-prefetch SMEM (page_tables[slot_ids[b·qb], j]) inside
-the BlockSpec index_map, so Mosaic DMAs exactly the pages the block
-needs and blocks past its kv length are skipped.
+head_dim]. A query block is `qb` rows of ONE slot (qb = 1 per-token, or
+the verify step's k+1 rows per slot); its f32 accumulator and
+online-softmax (m, l) statistics live in VMEM scratch while the slot's
+pages stream past in ascending order — the FlashAttention-2 shape of
+flash_attention.py, with the kv blocks GATHERED through the page table
+(page_tables[slot_ids[b·qb], j], read from scalar-prefetch SMEM).
+
+Who walks the pages (`_walks_in_kernel`, static shapes only):
+
+* THE WALK — grid (T / qb,), one grid step per query block. The pools
+  stay in HBM and the kernel copies pages itself (`make_async_copy`)
+  into two VMEM halves of `G` pages each: while group g is computed,
+  group g + 1 flies. The loop runs `cdiv(live pages, G)` times, so a
+  block costs what its row HAS — nothing in the launch scales with
+  `pages_per_seq` (= max_model_len / page_size), and a padding row is
+  one grid step and no loop iteration. A grid step costs ≈ 0.15 µs on a
+  v5e even when it does nothing (PERF.md §6, PR 26); with the page
+  dimension in the grid a 475-token row at max_model_len 2048 paid for
+  128 steps to use 30.
+* THE PAGE GRID — grid (T / qb, pages_per_seq), page dimension
+  innermost, pages DMA'd by Mosaic's own pipeline through a BlockSpec
+  index_map (clamped at the block's last live page, so dead steps
+  re-request the resident page and copy nothing). Kept ONLY for what
+  Mosaic (jax 0.9.0) cannot slice for a manual copy: a `.at[page]` on a
+  tiled HBM ref must leave whole tiles in the last two dims, which
+  head_dim 64, packed int4 (lane dim D/2), 12 heads of a 16-bit or 8-bit
+  pool, and every `[P, H]` scale plane (so every int8/int4 pool) do
+  not. One page body serves both.
 
 Why the body is VPU work over [H, ·] tiles and not an MXU batched
-matmul: the pool block is `[P, H, D]` — heads on the SUBLANE dim, and
-H = 12 is not a sublane multiple. A per-head `q·kᵀ` needs `[H, P, D]`
-(a major↔sublane transpose Mosaic does not lower at 12 rows), and its
-lhs `q[H, D]` has no free dimension anyway (one query row per head: an
-MXU pass at 1/128 occupancy). So the contraction is reordered: per page
+matmul: a page is `[P, H, D]` — heads on the SUBLANE dim. A per-head
+`q·kᵀ` needs `[H, P, D]` (a major↔sublane transpose), and its lhs
+`q[H, D]` has no free dimension anyway (one query row per head: an MXU
+pass at 1/128 occupancy). So the contraction is reordered: per page
 row p, `sum_d q[H, D]·k_p[H, D]` is an elementwise multiply and a lane
 reduce, the softmax runs over the P row-columns `[H, 1]`, and the
 weighted sum of `v_p[H, D]` is a lane-broadcast multiply-add. The pool
@@ -48,6 +65,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["ragged_paged_attention"]
 
 NEG_INF = -1e30
+# VMEM for the walk's double-buffered K and V page groups
+_GROUP_VMEM_BYTES = 2 * 1024 * 1024
 
 
 def _eye_column(row, heads):
@@ -63,33 +82,36 @@ def _eye_column(row, heads):
     return jnp.sum(jnp.where(r == c, full, 0.0), axis=-1, keepdims=True)
 
 
-def _rpa_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref, v_ref,
-                *rest, page_size, pages_per_seq, quantized, qb):
-    """One grid step = (query block b of `qb` rows owned by ONE slot,
-    logical page j of that slot). Every array the body touches is 2-D
-    `[H, ·]` with heads on the sublanes — the layout a `[P, H, D]` pool
-    block already has per page row — so nothing is transposed,
-    reshaped or concatenated in VMEM (see the module docstring)."""
-    if quantized:
-        # int8/int4 pools ride with per-row fp32 scale planes, gathered
-        # through the SAME page_map (quantization runtime, PT_KV_DTYPE);
-        # quantized == 4 marks packed nibbles (pool lane dim D/2)
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    # lazy: keeps the kernel module free of the package import cycle
-    from ...quantization.runtime import unpack_int4_halves
+def _walks_in_kernel(heads, kdim, dtype, quantized):
+    """True where the kernel can copy pages itself (module docstring):
+    a page slice `pool.at[page]` has to leave whole (sublane, 128-lane)
+    tiles in its last two dims `[heads, kdim]` — Mosaic refuses the
+    slice otherwise, for the HBM side of a manual copy as for the VMEM
+    side. A 32-bit pool tiles HBM by single rows; 16- and 8-bit pools
+    by 8. The `[P, H]` scale planes of a quantized pool never fill 128
+    lanes."""
+    rows = 1 if jnp.dtype(dtype).itemsize == 4 else 8
+    return not quantized and kdim % 128 == 0 and heads % rows == 0
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    halves, heads, kdim = q_ref.shape[1:]
-    scale = 1.0 / math.sqrt(halves * kdim)
 
-    # per-row kv lengths from scalar-prefetch SMEM: scalar reads,
-    # unrolled over the STATIC block height. The frontier offset
-    # advances every LIVE row; padding rows (base 0) stay padding — the
-    # fused decode window's per-iteration frontier (one scalar per
-    # iteration, the lens vector itself stays window-invariant)
+def _pages_per_group(page_size, heads, kdim, dtype, pages_per_seq):
+    """How many pages one DMA group of the walk holds: the two halves
+    of K and of V together stay within `_GROUP_VMEM_BYTES`, heads
+    counted as VMEM pads them (to the dtype's sublane tile)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * (4 // itemsize)
+    page_bytes = (page_size * -(-heads // sublanes) * sublanes * kdim
+                  * itemsize)
+    return max(1, min(pages_per_seq, _GROUP_VMEM_BYTES // (4 * page_bytes)))
+
+
+def _block_kv_lens(lens_ref, off_ref, b, qb):
+    """Per-row kv lengths of block `b` and their maximum, from
+    scalar-prefetch SMEM: scalar reads, unrolled over the STATIC block
+    height. The frontier offset advances every LIVE row; padding rows
+    (base 0) stay padding — the fused decode window's per-iteration
+    frontier (one scalar per iteration, the lens vector itself stays
+    window-invariant)."""
     kvlens = []
     for i in range(qb):
         base = lens_ref[b * qb + i]
@@ -97,98 +119,206 @@ def _rpa_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref, v_ref,
     kvmax = kvlens[0]
     for kl in kvlens[1:]:
         kvmax = jnp.maximum(kvmax, kl)
+    return kvlens, kvmax
+
+
+def _init_stats(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _finalize(o_ref, acc_ref, m_ref, l_ref):
+    qb, halves = acc_ref.shape[:2]
+    for i in range(qb):
+        l = l_ref[i][:, :1]
+        # padding rows (kv_len 0) never ran a page: l == 0 → zeros
+        safe_l = jnp.where(l == 0.0, 1.0, l)
+        for c in range(halves):
+            o_ref[i, c] = (acc_ref[i, c] / safe_l).astype(o_ref.dtype)
+
+
+def _attend_page(j, row, kvlens, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                 acc_ref, m_ref, l_ref, *, quantized):
+    """Fold logical page `j` of the block's slot — held in row `row` of
+    the page refs — into every row of the block. Every array touched is
+    2-D `[H, ·]` with heads on the sublanes — the layout a `[P, H, D]`
+    page already has per page row — so nothing is transposed, reshaped
+    or concatenated in VMEM (see the module docstring)."""
+    # lazy: keeps the kernel module free of the package import cycle
+    from ...quantization.runtime import unpack_int4_halves
+
+    qb, halves, heads, kdim = q_ref.shape
+    page_size = k_ref.shape[1]
+    scale = 1.0 / math.sqrt(halves * kdim)
+    # the page, once per BLOCK: per page row p and lane half c an
+    # [H, kdim] f32 tile; for a quantized pool the codes stay unscaled
+    # and the per-(row, head) scale is applied to the reduced score /
+    # the softmax weight instead ([H, 1] work instead of [H, D])
+    kt, vt, kcol, vcol = [], [], [], []
+    for p in range(page_size):
+        kp, vp = k_ref[row, p], v_ref[row, p]
+        if quantized == 4:
+            # the ONE nibble codec (quantization.runtime), as its two
+            # planes — a second copy here would have to stay
+            # bit-identical with `pack_int4` forever
+            kh, vh = unpack_int4_halves(kp), unpack_int4_halves(vp)
+        elif quantized:
+            kh, vh = (kp.astype(jnp.int32),), (vp.astype(jnp.int32),)
+        else:
+            kh, vh = (kp,), (vp,)
+        kt.append([t.astype(jnp.float32) for t in kh])
+        vt.append([t.astype(jnp.float32) for t in vh])
+        if quantized:
+            kcol.append(_eye_column(ks_ref[row, pl.ds(p, 1), :], heads))
+            vcol.append(_eye_column(vs_ref[row, pl.ds(p, 1), :], heads))
+    pos1 = jnp.zeros((heads, 1), jnp.int32) + j * page_size
+    posd = jnp.zeros((heads, kdim), jnp.int32) + j * page_size
+
+    for i in range(qb):
+        kvlen = kvlens[i]
+
+        # a row this page is entirely PAST (the block visits it because
+        # a longer sibling row needs it) must not touch its (m, l,
+        # acc): its scores would all be NEG_INF and exp(s - m) would
+        # read exp(0) = 1 across the page
+        @pl.when(j * page_size < kvlen)
+        def _row(i=i, kvlen=kvlen):
+            q = [q_ref[i, c].astype(jnp.float32) * scale
+                 for c in range(halves)]
+            s = []
+            for p in range(page_size):
+                sp = jnp.sum(q[0] * kt[p][0], axis=-1,
+                             keepdims=True)          # [H, 1]
+                for c in range(1, halves):
+                    sp = sp + jnp.sum(q[c] * kt[p][c], axis=-1,
+                                      keepdims=True)
+                if quantized:
+                    sp = sp * kcol[p]
+                s.append(jnp.where(pos1 + p < kvlen, sp, NEG_INF))
+            m_prev = m_ref[i][:, :1]     # [H, 1] (stats broadcast lanes)
+            l_prev = l_ref[i][:, :1]
+            m_new = m_prev
+            for sp in s:
+                m_new = jnp.maximum(m_new, sp)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev
+            acc = [acc_ref[i, c] * alpha for c in range(halves)]
+            for p in range(page_size):
+                w = jnp.exp(s[p] - m_new)            # [H, 1]
+                l_new = l_new + w
+                if quantized:
+                    w = w * vcol[p]
+                # freed/unwritten page rows hold stale-but-finite
+                # garbage (the pool is zero-initialized); their weight
+                # is exactly 0, but zero the v rows anyway so no
+                # accidental inf·0 can form
+                live = posd + p < kvlen
+                for c in range(halves):
+                    acc[c] = acc[c] + w * jnp.where(live, vt[p][c], 0.0)
+            for c in range(halves):
+                acc_ref[i, c] = acc[c]
+            m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[i] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def _walk_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_hbm, v_hbm,
+                 o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
+                 pages_per_seq, group):
+    """One grid step = one query block; the walk over its slot's live
+    pages is the loop in here. `k_buf`/`v_buf` are `[2·G, P, H, D]`:
+    two halves of `G` pages, one DMA semaphore a half."""
+    b = pl.program_id(0)
+    qb = q_ref.shape[0]
+    page_size = k_buf.shape[1]
+    kvlens, kvmax = _block_kv_lens(lens_ref, off_ref, b, qb)
+    # pages past the LONGEST row's prefix contribute to no row: the
+    # walk ends there (padding rows have kvlen 0, so an all-padding
+    # block walks nothing). A slot has `pages_per_seq` table entries
+    # and no more, whatever a length claims.
+    n_pages = jnp.minimum((kvmax + (page_size - 1)) // page_size,
+                          pages_per_seq)
+    n_groups = (n_pages + (group - 1)) // group
+    # one slot per block (the slot-major contract): the block's first
+    # row names it
+    table = sid_ref[b * qb] * pages_per_seq
+
+    def live_in(g):
+        # a page of the last group that lies past the row is neither
+        # copied nor computed
+        return jnp.minimum(group, n_pages - g * group)
+
+    def group_copies(g, half, start):
+        """Start (or wait for) the copies of group `g`'s live pages
+        into buffer half `half`. A wait only needs the copy's SHAPE, so
+        it names page 0 instead of reading the table again."""
+        def one(i, carry):
+            page = pt_ref[table + g * group + i] if start else 0
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                dma = pltpu.make_async_copy(
+                    hbm.at[page], buf.at[half * group + i], sems.at[half])
+                dma.start() if start else dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(g), one, None)
+
+    _init_stats(acc_ref, m_ref, l_ref)
+
+    @pl.when(n_groups > 0)
+    def _first():
+        group_copies(0, 0, start=True)
+
+    def one_group(g, carry):
+        half = g % 2
+
+        # the next group's pages fly while this group's are computed
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            group_copies(g + 1, 1 - half, start=True)
+
+        group_copies(g, half, start=False)
+
+        def one_page(i, c):
+            _attend_page(g * group + i, half * group + i, kvlens, q_ref,
+                         k_buf, v_buf, None, None, acc_ref, m_ref, l_ref,
+                         quantized=0)
+            return c
+
+        jax.lax.fori_loop(0, live_in(g), one_page, None)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, one_group, None)
+    _finalize(o_ref, acc_ref, m_ref, l_ref)
+
+
+def _page_grid_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref,
+                      v_ref, *rest, quantized):
+    """One grid step = (query block b, logical page j of its slot);
+    the page's block `[1, P, H, D]` was DMA'd by the pipeline."""
+    # int8/int4 pools ride with per-row fp32 scale planes, gathered
+    # through the SAME page_map (quantization runtime, PT_KV_DTYPE);
+    # quantized == 4 marks packed nibbles (pool lane dim D/2)
+    ks_ref, vs_ref = rest[:2] if quantized else (None, None)
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    kvlens, kvmax = _block_kv_lens(lens_ref, off_ref, b, q_ref.shape[0])
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_stats(acc_ref, m_ref, l_ref)
 
     # pages past the LONGEST row's prefix contribute to no row — skip
     # (padding rows have kvlen 0, so an all-padding block skips every
     # page)
-    @pl.when(j * page_size < kvmax)
+    @pl.when(j * k_ref.shape[1] < kvmax)
     def _page():
-        # the page, once per BLOCK: per page row p and lane half c an
-        # [H, kdim] f32 tile; for a quantized pool the codes stay
-        # unscaled and the per-(row, head) scale is applied to the
-        # reduced score / the softmax weight instead ([H, 1] work
-        # instead of [H, D])
-        kt, vt, kcol, vcol = [], [], [], []
-        for p in range(page_size):
-            kp, vp = k_ref[0, p], v_ref[0, p]
-            if quantized == 4:
-                # the ONE nibble codec (quantization.runtime), as its
-                # two planes — a second copy here would have to stay
-                # bit-identical with `pack_int4` forever
-                kh, vh = unpack_int4_halves(kp), unpack_int4_halves(vp)
-            elif quantized:
-                kh, vh = (kp.astype(jnp.int32),), (vp.astype(jnp.int32),)
-            else:
-                kh, vh = (kp,), (vp,)
-            kt.append([t.astype(jnp.float32) for t in kh])
-            vt.append([t.astype(jnp.float32) for t in vh])
-            if quantized:
-                kcol.append(_eye_column(ks_ref[0, pl.ds(p, 1), :], heads))
-                vcol.append(_eye_column(vs_ref[0, pl.ds(p, 1), :], heads))
-        pos1 = jnp.zeros((heads, 1), jnp.int32) + j * page_size
-        posd = jnp.zeros((heads, kdim), jnp.int32) + j * page_size
+        _attend_page(j, 0, kvlens, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                     acc_ref, m_ref, l_ref, quantized=quantized)
 
-        for i in range(qb):
-            kvlen = kvlens[i]
-
-            # a row this page is entirely PAST (the block ran because a
-            # longer sibling row needed it) must not touch its (m, l,
-            # acc): its scores would all be NEG_INF and exp(s - m)
-            # would read exp(0) = 1 across the page
-            @pl.when(j * page_size < kvlen)
-            def _row(i=i, kvlen=kvlen):
-                q = [q_ref[i, c].astype(jnp.float32) * scale
-                     for c in range(halves)]
-                s = []
-                for p in range(page_size):
-                    sp = jnp.sum(q[0] * kt[p][0], axis=-1,
-                                 keepdims=True)          # [H, 1]
-                    for c in range(1, halves):
-                        sp = sp + jnp.sum(q[c] * kt[p][c], axis=-1,
-                                          keepdims=True)
-                    if quantized:
-                        sp = sp * kcol[p]
-                    s.append(jnp.where(pos1 + p < kvlen, sp, NEG_INF))
-                m_prev = m_ref[i][:, :1]     # [H, 1] (stats broadcast lanes)
-                l_prev = l_ref[i][:, :1]
-                m_new = m_prev
-                for sp in s:
-                    m_new = jnp.maximum(m_new, sp)
-                alpha = jnp.exp(m_prev - m_new)
-                l_new = alpha * l_prev
-                acc = [acc_ref[i, c] * alpha for c in range(halves)]
-                for p in range(page_size):
-                    w = jnp.exp(s[p] - m_new)            # [H, 1]
-                    l_new = l_new + w
-                    if quantized:
-                        w = w * vcol[p]
-                    # freed/unwritten page rows hold stale-but-finite
-                    # garbage (the pool is zero-initialized); their
-                    # weight is exactly 0, but zero the v rows anyway
-                    # so no accidental inf·0 can form
-                    live = posd + p < kvlen
-                    for c in range(halves):
-                        acc[c] = acc[c] + w * jnp.where(
-                            live, vt[p][c], 0.0)
-                for c in range(halves):
-                    acc_ref[i, c] = acc[c]
-                m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-                l_ref[i] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-
-    @pl.when(j == pages_per_seq - 1)
-    def _finalize():
-        for i in range(qb):
-            l = l_ref[i][:, :1]
-            # padding rows (kv_len 0) never ran a page: l == 0 → zeros
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            for c in range(halves):
-                o_ref[i, c] = (acc_ref[i, c] / safe_l).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _last():
+        _finalize(o_ref, acc_ref, m_ref, l_ref)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
@@ -206,15 +336,16 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
 
     k_scales/v_scales [N, P, H] fp32: per-row dequant scales of INT8
     pools (quantization runtime). They are gathered through the same
-    page-table index_map as the pools and the dequant happens in VMEM
-    after the DMA, so HBM traffic for the cache stays int8 — the whole
-    point of the quantized pool (page bytes ≈ ×4 down vs fp32).
+    page-table index_map as the pools (the page grid: module docstring)
+    and the dequant happens in VMEM after the DMA, so HBM traffic for
+    the cache stays int8 — the whole point of the quantized pool (page
+    bytes ≈ ×4 down vs fp32).
 
     q_per_slot: optional STATIC int — the caller's guarantee that the
     T query rows are slot-major contiguous blocks of exactly this many
     rows, one slot per block (the speculative VERIFY layout: k+1 rows
-    per slot). The grid becomes (T/q_per_slot, pages_per_seq): each
-    slot's pages are DMA'd once per BLOCK instead of once per row,
+    per slot). A query block becomes q_per_slot rows: each slot's
+    pages are DMA'd once per BLOCK instead of once per row,
     while per-row kv_lens keep the in-window causal raggedness (row i
     masks its scores at its own kv_len, which is what lets draft token
     j attend to drafts 0..j-1 written in this same dispatch and never
@@ -235,85 +366,103 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     compiled on the chip by chip_smoke.py)."""
     tokens, heads, dim = q.shape
     _, page_size, _, kdim = k_pool.shape
-    _, pages_per_seq = page_tables.shape
     quantized = 0
     if k_scales is not None:
         quantized = 4 if kdim * 2 == dim else 8
+    qb = 1
+    if q_per_slot is not None and tokens % int(q_per_slot) == 0:
+        qb = int(q_per_slot)
+    if frontier_offset is None:
+        frontier_offset = 0
+    scales = (k_scales, v_scales) if quantized else ()
+    call = _paged_call(q.shape, q.dtype, k_pool.shape, k_pool.dtype,
+                       page_tables.shape[1], quantized, qb, interpret)
+    return call(jnp.asarray(slot_ids, jnp.int32),
+                jnp.asarray(page_tables, jnp.int32).reshape(-1),
+                jnp.asarray(kv_lens, jnp.int32),
+                jnp.asarray(frontier_offset, jnp.int32).reshape((1,)),
+                q, k_pool, v_pool, *scales)
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
+                quantized, qb, interpret):
+    """The launch for one set of static shapes, as ONE jitted function:
+    a model calls the kernel once a layer with the same shapes, and a
+    fresh `pallas_call` would trace the kernel body again at every
+    site (0.5–0.9 s each on the v5e's host, 48 sites in the decode
+    cell's two programs: PERF.md §6, PR 26). `inline=True` leaves the
+    caller's jaxpr and HLO as they were — one `pallas_call` a site."""
+    tokens, heads, dim = q_shape
+    _, page_size, _, kdim = pool_shape
     # packed int4 splits head_dim into its two nibble planes: q and out
     # ride as [T, 2, H, D/2] so each plane sits at lane offset 0 in the
     # kernel (no lane slice / concatenate in VMEM); float and int8
     # pools are the halves == 1 case of the same layout
     halves = dim // kdim
-    qb = 1
-    if q_per_slot is not None and tokens % int(q_per_slot) == 0:
-        qb = int(q_per_slot)
+    q_block = (qb, halves, heads, kdim)
+    stats = [
+        pltpu.VMEM(q_block, jnp.float32),            # accumulator
+        pltpu.VMEM((qb, heads, 128), jnp.float32),   # running max
+        pltpu.VMEM((qb, heads, 128), jnp.float32),   # running sum
+    ]
 
-    if frontier_offset is None:
-        frontier_offset = 0
-    off = jnp.asarray(frontier_offset, jnp.int32).reshape((1,))
-
-    kernel = functools.partial(
-        _rpa_kernel, page_size=page_size, pages_per_seq=pages_per_seq,
-        quantized=quantized, qb=qb)
-
-    def _blk_page(b, j, sid, pt, lens, offv):
-        # clamp j to the LAST live page any row of block b needs (index_
-        # map twin of the kernel's kvmax): grid steps past the valid
-        # prefix re-request the same block, so Mosaic elides their
-        # HBM→VMEM copy (the compute is already pl.when-gated) — without
-        # the clamp every dead page would still be DMA'd and kernel
-        # bandwidth would scale with max_model_len, not live tokens.
-        # The prefetched operands are SMEM refs here — scalar reads
-        # only, unrolled over the STATIC block height.
-        eff_max = jnp.asarray(0, jnp.int32)
-        for i in range(qb):
-            base = lens[b * qb + i]
-            eff = jnp.where(base > 0, base + offv[0], 0)
-            eff_max = jnp.maximum(eff_max, eff)
-        last = jnp.maximum(eff_max - 1, 0) // page_size
-        # one slot per block (the slot-major contract): the block's
-        # first row names it
-        return pt[sid[b * qb] * pages_per_seq + jnp.minimum(j, last)]
-
-    def page_map(b, j, *prefetch):
-        return (_blk_page(b, j, *prefetch), 0, 0, 0)
-
-    def scale_map(b, j, *prefetch):
-        return (_blk_page(b, j, *prefetch), 0, 0)
-
-    def q_map(b, j, *prefetch):
+    def q_map(b, *_):
         return (b, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((qb, halves, heads, kdim), q_map),
-        pl.BlockSpec((1, page_size, heads, kdim), page_map),
-        pl.BlockSpec((1, page_size, heads, kdim), page_map),
-    ]
-    q4 = jnp.swapaxes(q.reshape(tokens, heads, halves, kdim), 1, 2)
-    inputs = [q4, k_pool, v_pool]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, heads), scale_map),
-                     pl.BlockSpec((1, page_size, heads), scale_map)]
-        inputs += [k_scales, v_scales]
+    q_spec = pl.BlockSpec(q_block, q_map)
+    if _walks_in_kernel(heads, kdim, pool_dtype, quantized):
+        group = _pages_per_group(page_size, heads, kdim, pool_dtype,
+                                 pages_per_seq)
+        kernel = functools.partial(
+            _walk_kernel, pages_per_seq=pages_per_seq, group=group)
+        grid = (tokens // qb,)
+        hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+        in_specs = [q_spec, hbm, hbm]
+        scratch = [pltpu.VMEM((2 * group, page_size, heads, kdim),
+                              pool_dtype)] * 2
+        scratch += [pltpu.SemaphoreType.DMA((2,))] + stats
+    else:
+        kernel = functools.partial(_page_grid_kernel, quantized=quantized)
+        grid = (tokens // qb, pages_per_seq)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(tokens // qb, pages_per_seq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((qb, halves, heads, kdim), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((qb, halves, heads, kdim), jnp.float32),  # acc
-            pltpu.VMEM((qb, heads, 128), jnp.float32),   # running max
-            pltpu.VMEM((qb, heads, 128), jnp.float32),   # running sum
-        ],
-    )
-    out = pl.pallas_call(
+        def _blk_page(b, j, sid, pt, lens, offv):
+            # clamp j to the LAST live page any row of block b needs
+            # (index_map twin of the kernel's kvmax): grid steps past
+            # the valid prefix re-request the same block, so Mosaic
+            # elides their HBM→VMEM copy (the compute is already
+            # pl.when-gated)
+            _, eff_max = _block_kv_lens(lens, offv, b, qb)
+            last = jnp.maximum(eff_max - 1, 0) // page_size
+            # one slot per block (the slot-major contract): the block's
+            # first row names it
+            return pt[sid[b * qb] * pages_per_seq + jnp.minimum(j, last)]
+
+        def page_map(b, j, *prefetch):
+            return (_blk_page(b, j, *prefetch), 0, 0, 0)
+
+        def scale_map(b, j, *prefetch):
+            return (_blk_page(b, j, *prefetch), 0, 0)
+
+        page = pl.BlockSpec((1, page_size, heads, kdim), page_map)
+        in_specs = [q_spec, page, page]
+        if quantized:
+            in_specs += [pl.BlockSpec((1, page_size, heads), scale_map)] * 2
+        scratch = stats
+
+    launch = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=grid, in_specs=in_specs,
+            out_specs=q_spec, scratch_shapes=scratch),
+        out_shape=jax.ShapeDtypeStruct((tokens, halves, heads, kdim),
+                                       q_dtype),
         interpret=interpret,
-    )(jnp.asarray(slot_ids, jnp.int32),
-      jnp.asarray(page_tables, jnp.int32).reshape(-1),
-      jnp.asarray(kv_lens, jnp.int32), off,
-      *inputs)
-    return jnp.swapaxes(out, 1, 2).reshape(tokens, heads, dim)
+    )
+
+    def call(sid, table, lens, off, q, *pools):
+        q4 = jnp.swapaxes(q.reshape(tokens, heads, halves, kdim), 1, 2)
+        out = launch(sid, table, lens, off, q4, *pools)
+        return jnp.swapaxes(out, 1, 2).reshape(tokens, heads, dim)
+
+    return jax.jit(call, inline=True)

@@ -24,16 +24,24 @@ from paddle_tpu.text.models.gpt import gpt_tiny
 pytestmark = pytest.mark.serving
 
 
-@pytest.fixture(autouse=True)
-def _serial_mesh():
+def _reset_mesh():
     from paddle_tpu.distributed import mesh as mesh_mod
 
     mesh_mod.reset_mesh()
+
+
+@pytest.fixture(autouse=True)
+def _serial_mesh():
+    _reset_mesh()
     yield
 
 
 @pytest.fixture(scope="module")
 def tiny_model():
+    # a module-scoped fixture is built BEFORE the function-scoped
+    # autouse reset above: a mesh left by an earlier file of the same
+    # xdist worker (--dist loadfile) would shard the model's parameters
+    _reset_mesh()
     paddle.seed(30)
     cfg = gpt_tiny()
     model = GPTForCausalLM(cfg)
@@ -315,6 +323,14 @@ def test_host_sampler_compiles_once_across_frontier_counts(tiny_model):
     eng = LLMEngine(model, LLMEngineConfig(
         num_slots=3, page_size=16, token_budget=8, max_model_len=64,
         decode_k=1, seed=3))
+    # a jit of this engine's own: every `jax.jit(sample_tokens)` of a
+    # process shares ONE cache, so an engine of another test file on the
+    # same xdist worker (other slots, other vocabulary) would be counted
+    import jax
+
+    from paddle_tpu.text.models.gpt import sample_tokens
+
+    eng._host_sample = jax.jit(lambda *a: sample_tokens(*a))
     # staggered budgets: the live-frontier count sweeps 1..3 both ways
     for j, L in enumerate((4, 7, 5)):
         eng.add_request(rng.integers(0, cfg.vocab_size, (L,)),

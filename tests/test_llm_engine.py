@@ -122,6 +122,129 @@ def test_pallas_ragged_paged_attention_interpret_matches_jnp():
     np.testing.assert_allclose(pl_out, jnp_out, rtol=1e-5, atol=1e-6)
 
 
+# the page walk: pools × heads whose pages the kernel copies itself
+# (float32 at 12 heads, bfloat16 at 16) and quantized pools, which stay
+# on the page grid; head_dim 128 throughout (int4 packs it to 64 lanes)
+_WALK_POOLS = [("float32", 12), ("bfloat16", 16), ("int8", 12), ("int4", 16)]
+
+
+def _walk_case(pool, heads, scenario):
+    """(kernel kwargs, reference kwargs) of one scenario, sized from
+    the kernel's own group length G so that every boundary of the walk
+    is crossed: page, group, the last partial group, the table's end."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+    from paddle_tpu.quantization import runtime as qrt
+
+    P, D = 16, 128
+    kdim = D // 2 if pool == "int4" else D
+    store = {"int8": "int8", "int4": "int8"}.get(pool, pool)
+    G = pak._pages_per_group(P, heads, kdim, store, 10 ** 6)
+    MP = 2 * G + 3                     # two whole groups and a partial one
+    cap = MP * P
+    qps = off = None
+    if scenario == "ragged":
+        # padding among live rows; 1 token; exactly a page; exactly a
+        # group; one past it; a live-page count that is no multiple of
+        # G; the whole table
+        lens = [0, 1, P, 0, G * P, G * P + 1, (G + 3) * P - 5, cap, 0]
+        sid = [0, 1, 2, 3, 0, 1, 2, 3, 2]
+    elif scenario == "frontier":
+        # the offset carries a row into a new page, into a new group,
+        # up to the table's end; a padding row stays padding
+        off = 3
+        lens = [P - 2, G * P - 1, cap - 3, 0, 2 * G * P - 3, 1]
+        sid = [0, 1, 2, 3, 3, 0]
+    else:
+        # the verify layout: 3 rows a slot, ragged inside a block —
+        # across a group boundary, a dead tail row, an all-dead block,
+        # the table's end
+        qps = 3
+        lens = [G * P - 1, G * P, G * P + 1, 5, 6, 0, 0, 0, 0,
+                cap - 2, cap - 1, cap]
+        sid = [0, 0, 0, 1, 1, 1, 2, 2, 2, 3, 3, 3]
+    rng = np.random.default_rng(len(lens) * heads + G)
+    S = 4
+    N = S * MP + 1
+    pt = (rng.permutation(np.arange(1, N)).reshape(S, MP).astype(np.int32))
+    q = jnp.asarray(rng.standard_normal((len(sid), heads, D)), jnp.float32)
+    kv = [jnp.asarray(rng.standard_normal((N, P, heads, D)), jnp.float32)
+          for _ in range(2)]
+    scales = {}
+    if pool in ("int8", "int4"):
+        quant = (qrt.quantize_kv_rows if pool == "int8"
+                 else qrt.quantize_kv_rows_int4)
+        packed = [quant(x.reshape(N * P, heads, D)) for x in kv]
+        kv = [c.reshape(N, P, heads, -1) for c, _ in packed]
+        scales = {n: sc.reshape(N, P, heads)
+                  for n, (_, sc) in zip(("k_scales", "v_scales"), packed)}
+    else:
+        kv = [x.astype(pool) for x in kv]
+    args = (pt, np.asarray(sid, np.int32), np.asarray(lens, np.int32))
+    offv = None if off is None else jnp.asarray(off, jnp.int32)
+    kern = dict(args=(q, *kv, *args), kw=dict(
+        frontier_offset=offv, q_per_slot=qps, **scales))
+    # the reference reads the SAME stored values, widened (a bf16 pool
+    # is exact in f32), so both sides are f32 arithmetic
+    wide = kv if scales else [x.astype(jnp.float32) for x in kv]
+    ref = dict(args=(q, *wide, *map(jnp.asarray, args)), kw=dict(
+        frontier_offset=offv, max_tokens_per_slot=qps, **scales))
+    return kern, ref, np.asarray(lens) == 0
+
+
+@pytest.mark.parametrize("scenario", ["ragged", "frontier", "verify"])
+@pytest.mark.parametrize("pool,heads", _WALK_POOLS)
+def test_pallas_paged_walk_matches_jnp(pool, heads, scenario):
+    from paddle_tpu.nn.functional.attention import paged_attention_jnp
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+
+    kern, ref, dead = _walk_case(pool, heads, scenario)
+    got = np.asarray(pak.ragged_paged_attention(
+        *kern["args"], **kern["kw"], interpret=True))
+    want = np.asarray(paged_attention_jnp(*ref["args"], **ref["kw"]))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+    assert dead.any() and np.all(got[dead] == 0)
+
+
+def _pallas_grid(page_tables_width, heads, dim, dtype, tokens=6, qps=None):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+
+    z = jnp.zeros
+    jaxpr = jax.make_jaxpr(lambda *a: pak.ragged_paged_attention(
+        *a, q_per_slot=qps))(
+        z((tokens, heads, dim), dtype), z((9, 16, heads, dim), dtype),
+        z((9, 16, heads, dim), dtype), z((2, page_tables_width), jnp.int32),
+        z((tokens,), jnp.int32), z((tokens,), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+def test_pallas_paged_grid_does_not_scale_with_max_model_len():
+    """The decode cell's launch (16 heads × 128, bf16): one grid step
+    per query block, whatever `page_tables.shape[1]` (= max_model_len /
+    page_size) is. A pool Mosaic cannot slice for a manual copy keeps
+    the page dimension in its grid — pinned here so that the day it
+    can, this fails and the page grid is deleted."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+
+    assert _pallas_grid(128, 16, 128, jnp.bfloat16) == (6,)
+    assert _pallas_grid(256, 16, 128, jnp.bfloat16) == (6,)
+    assert _pallas_grid(256, 16, 128, jnp.bfloat16, qps=3) == (2,)
+    assert _pallas_grid(256, 12, 128, jnp.float32) == (6,)
+    assert pak._walks_in_kernel(16, 128, jnp.bfloat16, 0)
+    # head_dim 64, 12 heads of a 16-bit pool, any quantized pool
+    assert _pallas_grid(128, 12, 64, jnp.float32) == (6, 128)
+    assert not pak._walks_in_kernel(12, 128, jnp.bfloat16, 0)
+    assert not pak._walks_in_kernel(16, 128, jnp.int8, 8)
+
+
 # --------------------------------------------------------------------
 # engine == generate()
 # --------------------------------------------------------------------

@@ -1,0 +1,76 @@
+"""The paged attention kernel compiled for a DESCRIBED TPU v5e — no
+chip: the installed TPU compiler lowers `ragged_paged_attention` at the
+shapes the serving paths launch, so what Mosaic refuses (a slice that is
+not tile-aligned, too much VMEM) fails here and not on the chip.
+Interpret-mode parity (tests/test_llm_engine.py) cannot see either.
+
+Nothing runs and nothing is timed. All of these live in ONE file: the
+worker that describes the topology holds libtpu until it exits.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas_kernels.paged_attention import (
+    ragged_paged_attention)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any refusal means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (id, tokens, heads, head_dim, table [slots, pages], pool, q_per_slot)
+_LAUNCHES = [
+    # the decode cell (cerebras-gpt-1.3b, bf16 pool): the fused window's
+    # 24 rows and the single tick's 256 — the in-kernel page walk
+    ("cell_window", 24, 16, 128, (24, 128), "bfloat16", None),
+    ("cell_tick", 256, 16, 128, (24, 128), "bfloat16", None),
+    ("cell_verify", 120, 16, 128, (24, 128), "bfloat16", 5),
+    ("walk_f32_h12", 17, 12, 128, (4, 8), "float32", None),
+    # what the walk cannot slice stays on the page grid: head_dim 64,
+    # 12 heads of a 16-bit pool, quantized pools
+    ("grid_f32_h12x64", 17, 12, 64, (4, 8), "float32", None),
+    ("grid_bf16_h12", 17, 12, 128, (4, 8), "bfloat16", None),
+    ("grid_int8_h16", 17, 16, 128, (24, 128), "int8", None),
+    ("grid_int4_h16", 20, 16, 128, (24, 128), "int4", 5),
+]
+
+
+@pytest.mark.parametrize(
+    "tokens,heads,dim,table,pool,qps",
+    [pytest.param(*c[1:], id=c[0]) for c in _LAUNCHES])
+def test_paged_kernel_compiles_for_v5e(one_chip, tokens, heads, dim, table,
+                                       pool, qps):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    quant = pool in ("int8", "int4")
+    store = jnp.int8 if quant else jnp.dtype(pool)
+    page = (table[0] * 4 + 1, 16, heads, dim // 2 if pool == "int4" else dim)
+    args = [sds((tokens, heads, dim), jnp.float32 if quant else store),
+            sds(page, store), sds(page, store), sds(table, jnp.int32),
+            sds((tokens,), jnp.int32), sds((tokens,), jnp.int32),
+            sds((), jnp.int32)]
+    if quant:
+        args += [sds(page[:3], jnp.float32)] * 2
+
+    def call(q, k, v, pt, sid, lens, off, *scales):
+        ks, vs = scales or (None, None)
+        return ragged_paged_attention(
+            q, k, v, pt, sid, lens, k_scales=ks, v_scales=vs,
+            frontier_offset=off, q_per_slot=qps)
+
+    # conftest turns x64 on for numpy parity; the chip runs without it
+    # (and Mosaic has no 64-bit scalars to lower a Python int to)
+    with jax.enable_x64(False):
+        text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
