@@ -2,9 +2,11 @@
 through the entry points a user calls (`GPTConfig`, `GPTForCausalLM`,
 `inference.LLMServer`, `paddle.jit.TrainStep`). This and the drivers
 are the only benchmark files that import the program. The weights are
-the benchmark's (`harness.weights`, from the seed), handed over leaf by
+the benchmark's (`references.gpt2`, from the seed), handed over leaf by
 leaf under the program's names.
 """
+from harness.plain import seed_key
+from references import gpt2
 
 # tree name -> the program's state_dict suffix inside gpt.layers.<i>.
 _LAYER_NAMES = {
@@ -50,13 +52,11 @@ def flat_weights(cfg, seed, dtype):
     """{program name: array}: made and unstacked in ONE jitted call."""
     import jax
 
-    from harness import weights
-
-    items = weights.cfg_items(cfg)
+    items = gpt2.cfg_items(cfg)
     n_layer = int(cfg["n_layer"])
 
     def make(key):
-        tree = weights.tree_from_key(key, items, dtype)
+        tree = gpt2.tree_from_key(key, items, dtype)
         out = {program_name(k): v for k, v in tree.items()
                if k != "layers"}
         for k, v in tree["layers"].items():
@@ -64,7 +64,7 @@ def flat_weights(cfg, seed, dtype):
                 out[program_name(k, i)] = v[i]
         return out
 
-    return jax.jit(make)(weights.seed_key(seed))
+    return jax.jit(make)(seed_key(seed))
 
 
 def build_model(cfg, seed, dtype, recompute=False):
@@ -124,6 +124,13 @@ class Served:
             prefix_cache=bool(e["prefix_cache"]))
         self.server = inference.LLMServer(self.model, self.engine_config)
         self.engine = self.server.engine
+
+    def page_occupancy(self):
+        """Share of the page pool in use now (page 0 is never handed
+        out); a cache manager with several pools answers for its
+        fullest."""
+        pool = self.engine.pool
+        return pool.num_live / (pool.num_pages - 1)
 
     def custom_calls(self):
         """{step: {custom call target: count}} of the lowered step

@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from harness import arith, traffic
+from harness import traffic
 from harness.trace_reduce import WINDOW_SPAN
 
 
@@ -46,6 +46,7 @@ class Record:
 class Tracker:
     def __init__(self, served):
         self.engine, self.server = served.engine, served.server
+        self.page_occupancy = served.page_occupancy
         self.records = []
         self.inflight = {}
         self.lock = threading.Lock()
@@ -73,9 +74,8 @@ class Tracker:
         for rec in live:
             if rec.first is None and rec.generated() > 0:
                 rec.first = now
-        pool = self.engine.pool
         self.page_occ_peak = max(self.page_occ_peak,
-                                 pool.num_live / (pool.num_pages - 1))
+                                 self.page_occupancy())
         for name, _t, hook in wanted:
             if hook is not None:
                 hook()
@@ -84,21 +84,28 @@ class Tracker:
         return out
 
     def snapshot(self, now=None):
-        """Counts at a step boundary (or while the engine idles)."""
+        """Counts at a step boundary (or while the engine idles):
+        the benchmark's own (`generated`, `processed`, each record's
+        processed count under `per_record`), the counters today's
+        readers know by their plain names, and EVERY numeric entry of
+        `server.metrics()` and `engine.stats` under `metrics` and
+        `stats`, so that a counter a later PR adds reaches a reader a
+        later PR adds with no edit here."""
         st = self.engine.stats
         m = self.server.metrics()
+        per_record = {r.idx: r.processed() for r in self.records}
         return {
             "t": time.perf_counter() if now is None else now,
             "generated": sum(r.generated() for r in self.records),
-            "processed": sum(r.processed() for r in self.records),
-            "context_sum": sum(arith.context_sum(0, r.processed())
-                               for r in self.records),
+            "processed": sum(per_record.values()),
+            "per_record": per_record,
             "steps": st["steps"], "fused_steps": st["fused_steps"],
             "occupancy_sum": st["occupancy_sum"],
             "prefill_tokens": m["prefill_tokens"],
             "decode_tokens": m["decode_tokens"],
             "dispatches": m["dispatches"],
             "preemptions": m["preemptions"],
+            "metrics": numeric(m), "stats": numeric(st),
             "compile_stats": dict(self.engine.compile_stats()),
             "boundaries": self.boundaries,
         }
@@ -204,29 +211,50 @@ class TraceWindow:
             - self.tracker.snaps["trace_on"]["t"]))
 
 
-def delta(a, b):
-    """Counter deltas between two snapshots."""
-    return {k: b[k] - a[k] for k in a if isinstance(a[k], (int, float))}
+def numeric(d):
+    return {k: v for k, v in d.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def work_between(a, b, decode_k):
+    """The `work` between two snapshots (`harness/arith.py` describes
+    it): counter deltas, `segments` [(start, n)] of the positions each
+    request had through the model, and the model's `iterations` — a
+    fused dispatch scans `decode_k` of them, a single tick is one. A
+    request that was preempted inside restarts its prefill at 0 and
+    reads a negative `n` here; no cell preempts (`preemptions`)."""
+    out = {k: b[k] - v for k, v in numeric(a).items()}
+    for group in ("metrics", "stats"):
+        out[group] = {k: b[group][k] - v for k, v in a[group].items()
+                      if k in b[group]}
+    before = a["per_record"]
+    moved = ((before.get(i, 0), n) for i, n in b["per_record"].items())
+    out["segments"] = [(start, n - start) for start, n in moved
+                       if n != start]
+    out["iterations"] = out["fused_steps"] * int(decode_k) \
+        + (out["steps"] - out["fused_steps"])
+    return out
 
 
 def serve_observations(tracker, served, open_snap, close_snap, trace_dir):
     """What per-layer readers get from a serving run."""
     cfg = served.cfg
+    decode_k = int(cfg["engine"]["decode_k"])
     obs = {
         "cfg": cfg, "kind": "serve",
         "kv_dtype": cfg["engine"]["kv_dtype"],
         "weight_dtype": cfg["serve"]["weight_dtype"],
-        "decode_k": int(cfg["engine"]["decode_k"]),
+        "decode_k": decode_k,
         "num_slots": int(cfg["engine"]["num_slots"]),
-        "window": delta(open_snap, close_snap),
+        "window": work_between(open_snap, close_snap, decode_k),
         "page_occ_peak": tracker.page_occ_peak,
         "compiles_in_window": sum(
             close_snap["compile_stats"][k] - open_snap["compile_stats"]
             .get(k, 0) for k in close_snap["compile_stats"]),
     }
     if "trace_off" in tracker.snaps:
-        obs["traced"] = delta(tracker.snaps["trace_on"],
-                              tracker.snaps["trace_off"])
+        obs["traced"] = work_between(tracker.snaps["trace_on"],
+                                     tracker.snaps["trace_off"], decode_k)
         obs["trace_dir"] = trace_dir
     return obs
 

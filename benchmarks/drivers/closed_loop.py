@@ -11,6 +11,8 @@ from harness import traffic
 
 from . import _serving as sv
 
+COMPARES = "served"    # which comparison decides `correct`
+
 
 def run(ctx):
     served, mix, log = ctx["handle"], ctx["mix"], ctx["log"]
@@ -70,7 +72,7 @@ def run(ctx):
                        (c["generated"] - o["generated"]) / elapsed},
         "obs": sv.serve_observations(tracker, served, o, c,
                                      ctx["trace_dir"]),
-        "check": {"kind": "served", "sample": sample,
+        "check": {"kind": COMPARES, "sample": sample,
                   "malformed": malformed,
                   "rows_to": int(mix["output_len"]["max"])},
     }

@@ -10,6 +10,8 @@ from harness import traffic
 
 from . import _serving as sv
 
+COMPARES = "served"    # which comparison decides `correct`
+
 
 def percentile(values, q):
     """Linear interpolation between order statistics (numpy's default),
@@ -103,7 +105,7 @@ def run(ctx):
         "end_to_end": {"ttft_p90_s": percentile(ttft, 90),
                        "tpot_p90_s": percentile(tpot, 90)},
         "obs": obs,
-        "check": {"kind": "served", "sample": sample,
+        "check": {"kind": COMPARES, "sample": sample,
                   "malformed": malformed,
                   "rows_to": int(mix["output_len"]["max"])},
     }

@@ -12,6 +12,7 @@ from harness import traffic
 from harness.trace_reduce import WINDOW_SPAN
 
 CHECK_STEPS = 3
+COMPARES = "trained"   # which comparison decides `correct`
 
 
 def run(ctx):
@@ -95,7 +96,7 @@ def run(ctx):
         "end_to_end": {"train_tok_s":
                        steps * batch * seq / (t_close - t_open)},
         "obs": obs,
-        "check": {"kind": "trained", "losses": losses,
+        "check": {"kind": COMPARES, "losses": losses,
                   "moment1": moment1, "change": change,
                   "batches": [pool[i] for i in range(CHECK_STEPS)],
                   "custom_calls_batch": pool[0]},

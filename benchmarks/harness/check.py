@@ -9,12 +9,12 @@ prompt with its served tokens. Training: the first three steps' losses,
 the norm of the first gradient (from the optimizer's first moment) and
 the norm of the parameters' change after three steps, each by the worst
 leaf as a gap between norms.
+
+The weights and the plain forward pass are the reference's
+(`references/<name>.py`, the module `run.py` loaded by the
+configuration's `reference` key and hands in as `reference`).
 """
 import numpy as np
-
-from . import reference, weights
-
-BETA1 = reference.ADAMW["beta1"]
 
 
 def _round_up(n, m):
@@ -37,7 +37,7 @@ def expected_gap(margin, sd=NOISE_SD):
     return sd * (phi - u * (1.0 - cdf))
 
 
-def served_numbers(cfg, seed, sample, rows_to, quant=None):
+def served_numbers(reference, cfg, seed, sample, rows_to, quant=None):
     """Over every served token compared, the gap by which its logit
     lies below the reference's best (most are 0: the served token IS
     the reference's best): "served_gap_max" the widest, "served_gap_
@@ -49,12 +49,12 @@ def served_numbers(cfg, seed, sample, rows_to, quant=None):
     positions and its served rows to `rows_to` (the mix's longest
     output), so that every seed's sample compiles the same few
     shapes."""
-    w = weights.make_weights(cfg, seed, cfg["serve"]["weight_dtype"])
+    w = reference.make_weights(cfg, seed, cfg["serve"]["weight_dtype"])
+    longest = int(reference.positions(cfg))
     gaps, margins = [], []
     for toks, plen in sample:
         g, m = reference.served_token_gaps(
-            w, cfg["n_head"], toks, plen,
-            min(_round_up(len(toks), 256), int(cfg["n_positions"])),
+            cfg, w, toks, plen, min(_round_up(len(toks), 256), longest),
             _round_up(max(rows_to, len(toks) - plen), 128), quant=quant)
         gaps.append(g)
         margins.append(m)
@@ -74,8 +74,8 @@ def served_numbers(cfg, seed, sample, rows_to, quant=None):
 
 
 def tree_of(named, tree_position, like):
-    """{program name: value} → the reference's leaf layout
-    ({"wte": x, "layers/qkv_w": [L]...}), `like` giving the shapes."""
+    """{program name: value} → the reference's leaf layout ({key: a
+    scalar, or one value a layer}), `like` giving the shapes."""
     out = {k: np.zeros_like(np.asarray(v, np.float64))
            for k, v in like.items()}
     seen = 0
@@ -138,16 +138,18 @@ def trained_numbers(ref, prog):
     return out
 
 
-def reference_training(cfg, seed, batches, quant=None, keep_rows=None):
-    w0 = weights.make_weights(cfg, seed, "float32")
-    return reference.train_three_steps(
-        w0, batches, cfg["n_head"], quant=quant, keep_rows=keep_rows)
+def reference_training(reference, cfg, seed, batches, quant=None,
+                       keep_rows=None):
+    w0 = reference.make_weights(cfg, seed, "float32")
+    return reference.train_three_steps(cfg, w0, batches, quant=quant,
+                                       keep_rows=keep_rows)
 
 
-def program_training(check, tree_position, like):
+def program_training(reference, check, tree_position, like):
     """The program's readings in the reference's layout: the first
     gradient is moment1 / (1 - beta1) after one step."""
-    grad1 = {n: v / (1.0 - BETA1) for n, v in check["moment1"].items()}
+    beta1 = reference.ADAMW["beta1"]
+    grad1 = {n: v / (1.0 - beta1) for n, v in check["moment1"].items()}
     return {"losses": check["losses"],
             "grad1": tree_of(grad1, tree_position, like["grad1"]),
             "change": tree_of(check["change"], tree_position,

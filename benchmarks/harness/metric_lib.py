@@ -2,14 +2,17 @@
 each `read(ctx) -> number | None`). `ctx` holds: `obs` (the driver's
 counts: `window` and `traced` counter deltas, the configuration),
 `trace` (the reduction of the traced window, None without one), `cfg`,
-`chips`, `peaks` (the chip's row of the peaks table).
+`chips`, `peaks` (the chip's row of the peaks table), and `ref`: the
+configuration's reference (`references/<name>.py`), which owns the
+operations and bytes of every share of a peak. A serving driver's
+`obs["traced"]` is the traced window's `work` (`harness/arith.py`).
 
 A reader that finds nothing to read returns None and the harness leaves
 the metric out of the line. A share of a peak is never clamped.
 """
 import statistics
 
-from . import arith, trace_reduce
+from . import trace_reduce
 
 
 def pct(num, den):
@@ -83,7 +86,7 @@ def train_step_mfu(ctx):
     tr, obs = ctx.get("trace"), ctx["obs"]
     if not tr or "traced" not in obs:
         return None
-    flops = obs["traced"]["steps"] * arith.train_step_flops(
+    flops = obs["traced"]["steps"] * ctx["ref"].train_step_flops(
         ctx["cfg"], obs["batch"], obs["seq"])
     return pct(flops / tr["window_s"],
                ctx["chips"] * ctx["peaks"]["bf16_flops"])
@@ -96,7 +99,7 @@ def flash_attn_roofline(ctx, pattern, field="name"):
     secs = kernel_seconds(ctx, pattern, field)
     if secs is None or "traced" not in obs:
         return None
-    flops = obs["traced"]["steps"] * arith.flash_attn_flops(
+    flops = obs["traced"]["steps"] * ctx["ref"].flash_attn_flops(
         ctx["cfg"], obs["batch"], obs["seq"]) / ctx["chips"]
     return pct(flops / secs, ctx["peaks"]["bf16_flops"])
 
@@ -105,18 +108,9 @@ def serve_step_mfu(ctx):
     tr, obs = ctx.get("trace"), ctx["obs"]
     if not tr or "traced" not in obs:
         return None
-    t = obs["traced"]
-    flops = arith.serve_flops(ctx["cfg"], t["processed"], t["context_sum"])
+    flops = ctx["ref"].serve_flops(ctx["cfg"], obs["traced"])
     return pct(flops / tr["window_s"],
                ctx["chips"] * ctx["peaks"]["bf16_flops"])
-
-
-def model_iterations(ctx):
-    """Forward passes in the traced window: a fused dispatch scans
-    decode_k iterations, a single tick is one."""
-    t = ctx["obs"]["traced"]
-    return t["fused_steps"] * ctx["obs"]["decode_k"] \
-        + (t["steps"] - t["fused_steps"])
 
 
 def serve_step_hbm_share(ctx):
@@ -127,10 +121,9 @@ def serve_step_hbm_share(ctx):
     tr, obs = ctx.get("trace"), ctx["obs"]
     if not tr or "traced" not in obs or not tr["busy_s"]:
         return None
-    cfg = ctx["cfg"]
-    need = model_iterations(ctx) * arith.weight_bytes(
-        cfg, obs["weight_dtype"]) + arith.paged_attn_bytes(
-        cfg, obs["traced"]["context_sum"], obs["kv_dtype"])
+    cfg, ref, work = ctx["cfg"], ctx["ref"], obs["traced"]
+    need = ref.weight_bytes(cfg, obs["weight_dtype"], work) \
+        + ref.kv_bytes_attended(cfg, work, obs["kv_dtype"])
     return pct(need / tr["busy_s"], ctx["peaks"]["hbm_bytes_per_s"])
 
 
@@ -141,6 +134,6 @@ def paged_attn_roofline(ctx, pattern, field="name"):
     secs = kernel_seconds(ctx, pattern, field)
     if secs is None or "traced" not in obs:
         return None
-    need = arith.paged_attn_bytes(ctx["cfg"], obs["traced"]["context_sum"],
-                                  obs["kv_dtype"])
+    need = ctx["ref"].kv_bytes_attended(ctx["cfg"], obs["traced"],
+                                        obs["kv_dtype"])
     return pct(need / secs, ctx["peaks"]["hbm_bytes_per_s"])

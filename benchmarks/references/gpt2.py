@@ -1,27 +1,120 @@
-"""The plain reference: GPT-2 (pre-LN block, learned positions, tied
-head, exact GELU, LayerNorm eps 1e-5) in straightforward `jax.numpy`
-float32 under `precision="highest"`, with no kernel, no cache and no
-batching tricks. It imports nothing from the program and is given
-nothing the program made: weights come from `harness.weights` and the
-seed, tokens from the traffic generator and the served output.
+"""Reference `gpt2`: GPT-2 (pre-LN block, learned positions, tied
+head, exact GELU, LayerNorm eps 1e-5) behind the contract of
+`references/__init__.py`: its weights from the seed, its plain forward
+pass (serving gaps, three training steps) and its arithmetic. Key
+names are GPT-2's own (n_embd, n_layer, n_head, n_inner, n_positions,
+vocab_size): nothing outside this file and its builder reads them.
 
-It is computed in blocks so that it fits beside nothing else: layers
-through `lax.scan` (one compile for any depth), training rows a few at
-a time with the gradient accumulated.
+The forward pass is straightforward `jax.numpy` float32 under
+`precision="highest"`, with no kernel, no cache and no batching
+tricks. It imports nothing from the program and is given nothing the
+program made: weights come from `make_weights` and the seed, tokens
+from the traffic generator and the served output. It is computed in
+blocks so that it fits beside nothing else: layers through `lax.scan`
+(one compile for any depth), training rows a few at a time with the
+gradient accumulated. `quant` selects the CONTROL (`harness.plain.mm`).
 
-`quant` selects the CONTROL, the reference in the nearest precision
-below the one the configuration states (bf16 → 8 bits): "int8" rounds
-every matmul's activations (per row) and weights (per output channel)
-to int8 and multiplies in integers; "fp8" rounds both to float8_e4m3
-(per-tensor scale) in the forward pass, straight-through backward.
+The weights' tree is the plain GPT-2 one, layers stacked on a leading
+axis:
+
+    wte [V,d]  wpe [P,d]  lnf_w lnf_b [d]
+    layers: ln1_w ln1_b ln2_w ln2_b [L,d]  qkv_w [L,d,3d] qkv_b [L,3d]
+            proj_w [L,d,d] proj_b [L,d]  fc1_w [L,d,f] fc1_b [L,f]
+            fc2_w [L,f,d] fc2_b [L,d]
+
+Matrices are [in, out] (y = x @ W + b); qkv's output is [q | k | v],
+each [n_head, head] inside. The initialisation is GPT-2's (normal 0.02,
+residual projections scaled by 1/sqrt(2L)) with small random biases and
+LayerNorm offsets so that no leaf is exactly zero or one. The program
+gets these through its builder; the comparison makes them again from
+the seed with the same function and never sees the program's copies.
+
+The arithmetic counts from SHAPES (the rule of `harness/arith.py`).
+Copies of the program's (the originals are listed in PERF.md for a
+later PR to delete): `observability.steptrace.model_flops`,
+`LLMEngineConfig.kv_bytes_per_page`.
 """
 import functools
 import math
+
+from harness.arith import ITEMSIZE, context_sum
+from harness.plain import mm as _mm, seed_key
 
 LN_EPS = 1e-5
 ADAMW = {"lr": 1e-4, "beta1": 0.9, "beta2": 0.999, "eps": 1e-8,
          "weight_decay": 0.01}
 
+
+# --------------------------------------------------------------- sizes
+
+def dims(cfg):
+    d = int(cfg["n_embd"])
+    ffn = int(cfg.get("n_inner") or 4 * d)
+    return d, int(cfg["n_layer"]), int(cfg["n_head"]), ffn, \
+        int(cfg["vocab_size"]), int(cfg["n_positions"])
+
+
+def positions(cfg):
+    """The longest sequence the reference takes: its learned positions."""
+    return int(cfg["n_positions"])
+
+
+# ------------------------------------------------------------- weights
+
+def shapes(cfg):
+    d, L, _, f, v, p = dims(cfg)
+    top = {"wte": (v, d), "wpe": (p, d), "lnf_w": (d,), "lnf_b": (d,)}
+    layers = {"ln1_w": (L, d), "ln1_b": (L, d), "ln2_w": (L, d),
+              "ln2_b": (L, d), "qkv_w": (L, d, 3 * d), "qkv_b": (L, 3 * d),
+              "proj_w": (L, d, d), "proj_b": (L, d), "fc1_w": (L, d, f),
+              "fc1_b": (L, f), "fc2_w": (L, f, d), "fc2_b": (L, d)}
+    return top, layers
+
+
+def tree_from_key(key, cfg_items, dtype):
+    import jax
+    import jax.numpy as jnp
+
+    cfg = dict(cfg_items)
+    top, layers = shapes(cfg)
+    L = int(cfg["n_layer"])
+    names = sorted(top) + ["layers/" + n for n in sorted(layers)]
+    keys = dict(zip(names, jax.random.split(key, len(names))))
+
+    def draw(name, shape):
+        base = name.rsplit("/", 1)[-1]
+        std = 0.02
+        if base in ("proj_w", "fc2_w"):
+            std = 0.02 / math.sqrt(2 * L)
+        x = std * jax.random.normal(keys[name], shape, jnp.float32)
+        if base.endswith("_w") and base.startswith("ln"):
+            x = 1.0 + x
+        return x.astype(dtype)
+
+    out = {n: draw(n, s) for n, s in top.items()}
+    out["layers"] = {n: draw("layers/" + n, s) for n, s in layers.items()}
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted():
+    import jax
+
+    return jax.jit(tree_from_key, static_argnums=(1, 2))
+
+
+def cfg_items(cfg):
+    return tuple(sorted((k, int(cfg[k])) for k in (
+        "n_embd", "n_layer", "n_head", "n_inner", "n_positions",
+        "vocab_size") if cfg.get(k) is not None))
+
+
+def make_weights(cfg, seed, dtype):
+    """The whole tree, made on the device from `seed` in one call."""
+    return _jitted()(seed_key(seed), cfg_items(cfg), str(dtype))
+
+
+# ------------------------------------------------------------- forward
 
 def _ln(x, w, b):
     import jax
@@ -29,29 +122,6 @@ def _ln(x, w, b):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + LN_EPS) * w + b
-
-
-def _mm(x, w, quant):
-    """x [..., k] @ w [k, n] in float32/highest, or the control."""
-    import jax
-    import jax.numpy as jnp
-
-    if quant is None:
-        return jnp.matmul(x, w, precision="highest")
-    if quant == "int8":
-        sx = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0 + 1e-30
-        sw = jnp.max(jnp.abs(w), axis=0, keepdims=True) / 127.0 + 1e-30
-        xq = jnp.round(x / sx).astype(jnp.int8)
-        wq = jnp.round(w / sw).astype(jnp.int8)
-        acc = jnp.matmul(xq, wq, preferred_element_type=jnp.int32)
-        return acc.astype(jnp.float32) * sx * sw
-    if quant == "fp8":
-        def q(t):
-            s = jnp.max(jnp.abs(t)) / 448.0 + 1e-30
-            r = (t / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-            return t + jax.lax.stop_gradient(r - t)
-        return jnp.matmul(q(x), q(w), precision="highest")
-    raise ValueError(f"unknown control precision {quant!r}")
 
 
 def _block(x, lw, n_head, quant):
@@ -148,7 +218,7 @@ def _gap_fn(n_head, quant):
     return jax.jit(fn)
 
 
-def served_token_gaps(w, n_head, toks, plen, pad_to, rows_to, quant=None):
+def served_token_gaps(cfg, w, toks, plen, pad_to, rows_to, quant=None):
     """(gaps, reference margins) of the served tokens `toks[plen:]` of
     one sequence, one forward over the whole of it. Shapes are padded
     to (`pad_to`, `rows_to`) so that every seed compiles the same few
@@ -163,7 +233,8 @@ def served_token_gaps(w, n_head, toks, plen, pad_to, rows_to, quant=None):
     rows[:n] = np.arange(plen - 1, len(toks) - 1)
     served = np.full((rows_to,), toks[plen], np.int32)
     served[:n] = toks[plen:]
-    gap, margin = _gap_fn(int(n_head), quant)(w, ids, rows, served)
+    gap, margin = _gap_fn(int(cfg["n_head"]), quant)(w, ids, rows,
+                                                     served)
     return np.asarray(gap)[:n], np.asarray(margin)[:n]
 
 
@@ -227,8 +298,8 @@ def _adamw_fn():
     return jax.jit(fn, donate_argnums=(0, 2, 3))
 
 
-def train_three_steps(w0, batches, n_head, quant=None, rows_per_block=4,
-                      keep_rows=None):
+def train_three_steps(cfg, w0, batches, quant=None, keep_rows=None,
+                      rows_per_block=4):
     """AdamW (the program's hyper-parameters, decoupled decay on every
     leaf) from float32 weights `w0` over `batches` (a list of int32
     [b, s] arrays). `keep_rows` plants the half-batch fault: only those
@@ -240,7 +311,7 @@ def train_three_steps(w0, batches, n_head, quant=None, rows_per_block=4,
     import jax.numpy as jnp
     import numpy as np
 
-    grad = _grad_fn(int(n_head), quant)
+    grad = _grad_fn(int(cfg["n_head"]), quant)
     adamw = _adamw_fn()
     norms = jax.jit(leaf_norms)
     diff_norms = jax.jit(lambda a, b: leaf_norms(
@@ -271,3 +342,79 @@ def train_three_steps(w0, batches, n_head, quant=None, rows_per_block=4,
         w, m, v = adamw(w, g, m, v, np.float32(t))
     change = {k: np.asarray(x) for k, x in diff_norms(w, w0).items()}
     return {"losses": losses, "grad1": grad1, "change": change}
+
+
+# ---------------------------------------------------------- arithmetic
+
+def param_count(cfg):
+    """Every parameter of the tied-head GPT-2 (embeddings, positions,
+    biases and LayerNorms included; the head is the embedding)."""
+    d, L, _, ffn, v, npos = dims(cfg)
+    per_layer = (d * 3 * d + 3 * d) + (d * d + d) \
+        + (d * ffn + ffn) + (ffn * d + d) + 4 * d
+    return v * d + npos * d + L * per_layer + 2 * d
+
+
+def matmul_params(cfg):
+    """Weights a token is multiplied by: the four matrices of each
+    block and the tied vocabulary head (6·P / 2·P counts these)."""
+    d, L, _, ffn, v, _ = dims(cfg)
+    return L * (4 * d * d + 2 * d * ffn) + v * d
+
+
+def train_step_flops(cfg, batch, seq):
+    """Forward + backward of one training step, no recomputation:
+    6·P per token plus causal attention (scores and context, forward
+    once and backward twice, half the square)."""
+    d, L, *_ = dims(cfg)
+    tokens = int(batch) * int(seq)
+    return 6 * matmul_params(cfg) * tokens + flash_attn_flops(
+        cfg, batch, seq)
+
+
+def flash_attn_flops(cfg, batch, seq):
+    """Causal attention of one training step: q·kᵀ and p·v are 2·s²·d
+    each per layer and row, forward once and backward twice (dq, and
+    dk with dv), over the causal half."""
+    d, L, *_ = dims(cfg)
+    return L * int(batch) * (4 * int(seq) ** 2 * d) * 3 * 0.5
+
+
+def attended(work):
+    """Σ over the work's tokens of the context length each attended:
+    every layer attends every earlier position, so a segment of `n`
+    positions from `start` reads `context_sum(start, n)`."""
+    return sum(context_sum(int(s), int(n)) for s, n in work["segments"])
+
+
+def serve_flops(cfg, work):
+    """Forward only. `work["processed"]` tokens went through the model
+    (prefill rows and decode rows alike: 2·P each), and `attended` is
+    the sum over those tokens of the context length each attended
+    (scores and context: 4·d per attended position per layer)."""
+    d, L, *_ = dims(cfg)
+    return 2 * matmul_params(cfg) * int(work["processed"]) \
+        + 4 * d * L * attended(work)
+
+
+def kv_bytes_per_token(cfg, kv_dtype):
+    """K and V rows of one token over all layers."""
+    d, L, *_ = dims(cfg)
+    return 2 * L * d * ITEMSIZE[kv_dtype]
+
+
+def kv_bytes_per_page(cfg, page_size, kv_dtype):
+    return int(page_size) * kv_bytes_per_token(cfg, kv_dtype)
+
+
+def weight_bytes(cfg, dtype, work=None):
+    """Bytes of the whole tree; given `work`, the least its iterations
+    must read of it: every weight once an iteration."""
+    held = param_count(cfg) * ITEMSIZE[dtype]
+    return held if work is None else int(work["iterations"]) * held
+
+
+def kv_bytes_attended(cfg, work, kv_dtype):
+    """Least bytes attention must read: the K and V rows of every
+    attended position, once per attending token."""
+    return attended(work) * kv_bytes_per_token(cfg, kv_dtype)

@@ -6,11 +6,23 @@
 A new process: finds the cell in BENCHMARK.json and everything that
 belongs to it by name (configs/<config>.json, traffic/<mix>.json, the
 driver by the mix's `kind`, the builder by the configuration's
-`builder`, limits/<workload>.json, each per-layer metric's reader in
-layer_metrics/<name>.py), builds the system from --seed, warms only
-that cell's shapes, measures for --seconds, compares what the timed
-path produced with the plain reference, and prints ONE JSON object as
-its last line. Earlier lines (stderr) carry everything else.
+`builder`, the reference by its `reference`, limits/<workload>.json,
+each per-layer metric's reader in layer_metrics/<name>.py), builds the
+system from --seed, warms only that cell's shapes, measures for
+--seconds, compares what the timed path produced with the plain
+reference, and prints ONE JSON object as its last line. Earlier lines
+(stderr) carry everything else.
+
+What knows a model lives in three files chosen by name from the
+configuration's file: the file itself, `builders/<builder>.py` and
+`references/<reference>.py`. Of a configuration file the harness
+reads: `builder`, `reference`; `vocab_size` (the rows held here: the
+traffic draws its ids from it); `serve` (`weight_dtype`) with `engine`
+(`num_slots`, `token_budget`, `decode_k`, `kv_dtype` ... the
+deployment's choices) for a served cell, or `train` for one that
+trains; `rehearse` (the overlay of `--rehearse-cpu`); and, for the
+reader, `source`, `reduced`, `assumed`. Every other key is the
+architecture's own, read by its builder and its reference alone.
 
 It fails, with no result line, where JAX reports no TPU or fewer chips
 than the cell asks for. `--rehearse-cpu` is a debugging aid: the same
@@ -123,6 +135,7 @@ def main(argv=None):
     cell, cfg_entry = find_cell(manifest, args.workload)
     with open(os.path.join(ROOT, cfg_entry["file"])) as f:
         cfg = json.load(f)
+    import references
     from harness import check, peaks, trace_reduce, traffic
 
     mix = traffic.load_mix(os.path.join(HERE, "traffic"), cell["traffic"])
@@ -138,6 +151,11 @@ def main(argv=None):
     if args.cfg_set or args.mix_set:
         log(f"OVERRIDDEN (not the committed cell): {args.cfg_set} "
             f"{args.mix_set}")
+    driver = load_module(
+        os.path.join(HERE, "drivers", mix["kind"] + ".py"),
+        "drivers." + mix["kind"])
+    ref = references.load(cfg["reference"],
+                          training=driver.COMPARES == "trained")
 
     # the compile cache: where the environment says, else a fixed path
     # inside the checkout (the program's own default is the same path)
@@ -176,9 +194,6 @@ def main(argv=None):
     builder = load_module(
         os.path.join(HERE, "builders", cfg["builder"] + ".py"),
         "bench_builder_" + cfg["builder"])
-    driver = load_module(
-        os.path.join(HERE, "drivers", mix["kind"] + ".py"),
-        "drivers." + mix["kind"])
     handle = builder.build(cfg, args.seed, mix["kind"])
     log("system built")
     timing = {}
@@ -213,27 +228,29 @@ def main(argv=None):
     chk = out["check"]
     extra = {}
     if chk["kind"] == "served":
-        numbers = check.served_numbers(cfg, args.seed, chk["sample"],
-                                       chk["rows_to"])
+        numbers = check.served_numbers(ref, cfg, args.seed,
+                                       chk["sample"], chk["rows_to"])
         numbers["malformed_answers"] = chk["malformed"]
         limits = dict(limits, malformed_answers=0)
         if args.control:
             extra["control_" + args.control] = check.served_numbers(
-                cfg, args.seed, chk["sample"], chk["rows_to"],
+                ref, cfg, args.seed, chk["sample"], chk["rows_to"],
                 quant=args.control)
     else:
-        ref = check.reference_training(cfg, args.seed, chk["batches"])
+        want = check.reference_training(ref, cfg, args.seed,
+                                        chk["batches"])
         numbers = check.trained_numbers(
-            ref, check.program_training(chk, tree_position, ref))
+            want, check.program_training(ref, chk, tree_position, want))
         if args.control:
             extra["control_" + args.control] = check.trained_numbers(
-                ref, check.reference_training(
-                    cfg, args.seed, chk["batches"], quant=args.control))
+                want, check.reference_training(
+                    ref, cfg, args.seed, chk["batches"],
+                    quant=args.control))
         if args.fault == "half_batch":
             half = list(range(len(chk["batches"][0]) // 2))
             extra["fault_half_batch"] = check.trained_numbers(
-                ref, check.reference_training(
-                    cfg, args.seed, chk["batches"], keep_rows=half))
+                want, check.reference_training(
+                    ref, cfg, args.seed, chk["batches"], keep_rows=half))
     for name, nums in extra.items():
         log(f"{name}: {json.dumps(nums)}")
     worst = numbers.pop("_worst", None)
@@ -266,7 +283,7 @@ def main(argv=None):
                 "idle_gaps": [[n, s] for n, s in reduction["idle_gaps"]]}
         mctx = {"obs": out["obs"], "trace": reduction, "cfg": cfg,
                 "chips": int(cell["chips"]), "peaks": chip_peaks,
-                "cell": cell}
+                "cell": cell, "ref": ref}
         for m in metrics_of(manifest, "per_layer", cell["name"]):
             if args.rehearse_cpu and m["source"] != "program_counter":
                 continue

@@ -99,14 +99,19 @@ def test_readers_on_the_recorded_window(reduction):
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            "cerebras-gpt-1.3b.json")) as f:
         cfg = json.load(f)
-    # counts as a driver would hand them over: 24 rows through one
+    # the work as a driver would hand it over: 24 rows through one
     # fused window of 8 and 128 rows through one single tick, at
-    # contexts of about 400 positions
-    traced = {"processed": 24 * 8 + 128, "context_sum": (24 * 8 + 128)
-              * 400, "steps": 2, "fused_steps": 1}
+    # contexts of about 400 positions (the segments attend 320 * 400
+    # positions in all)
+    from references import gpt2
+
+    segments = [(419, 8)] * 23 + [(423, 8), (300, 128)]
+    traced = {"processed": 24 * 8 + 128, "segments": segments,
+              "iterations": 9, "steps": 2, "fused_steps": 1}
+    assert gpt2.attended(traced) == (24 * 8 + 128) * 400
     ctx = {"obs": {"traced": traced, "decode_k": 8, "kv_dtype": "bfloat16",
                    "weight_dtype": "bfloat16", "window": {}},
-           "trace": reduction, "cfg": cfg, "chips": 1,
+           "trace": reduction, "cfg": cfg, "chips": 1, "ref": gpt2,
            "peaks": peaks.peaks_for("TPU v5 lite")}
     share = _reader("paged_attn_time_share.decode")(ctx)
     assert share == pytest.approx(100 * 0.222876118 / 0.254093043)

@@ -6,6 +6,7 @@ import os
 import numpy as np
 import pytest
 
+import references
 from harness import traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -123,8 +124,9 @@ def test_the_serving_traffic_fits_the_engine():
         pairs = (traffic.closed_grid(mix) if mix["kind"] == "closed_loop"
                  else traffic.open_pairs(mix, m["run_seconds"]))
         longest = max(p + o for p, o in pairs)
-        assert longest <= e["max_model_len"] <= cfg["n_positions"]
-        per_token = 2 * cfg["n_layer"] * cfg["n_embd"] * 2   # bf16 K+V
+        ref = references.load(cfg["reference"])
+        assert longest <= e["max_model_len"] <= ref.positions(cfg)
+        per_token = ref.kv_bytes_per_token(cfg, e["kv_dtype"])
         pool_tokens = e["pool_budget_bytes"] // per_token
         if mix["kind"] == "closed_loop":
             assert e["num_slots"] * (longest + e["page_size"]) \
