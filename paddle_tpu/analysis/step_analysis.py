@@ -408,7 +408,9 @@ def _paged_step_args(engine):
         [p._value for p in sf._params],
         np.zeros((T,), i32), np.zeros((T,), i32),
         jax.device_put(np.zeros((T,), i32), sharding),
-        np.zeros((T,), i32), engine._page_tables, np.zeros((T,), i32),
+        # one write index a cache kind (the engine's `_step_tables`)
+        np.zeros((len(engine._kinds), T) if engine._extra else (T,), i32),
+        engine._step_tables(), np.zeros((T,), i32),
         jax.device_put(np.zeros((engine.num_slots,), i32), sharding),
         (engine._kv, engine._kv_scales, engine._key),
     )
@@ -435,7 +437,7 @@ def _fused_step_args(engine):
         np.zeros((S,), bool), np.full((S,), -1, i32),
         np.zeros((S,), np.float32), np.ones((S,), np.float32),
         np.zeros((S,), i32), gst, gtrans, gmask,
-        engine._page_tables,
+        engine._step_tables(),
         (engine._kv, engine._kv_scales, engine._key),
     )
 

@@ -257,6 +257,66 @@ class PagePool:  # ptlint: thread-shared (scraped by /metrics)
                 f"{len(self._ref)} live != {self.num_pages - 1}")
 
 
+class _CacheKindState:
+    """One FURTHER kind of K/V cache beside the engine's first (a model
+    with several: `text/models/serving_protocol.py`): its own page pool
+    and its own page table a slot, indexed by LOGICAL page like the
+    first kind's. A kind with a window keeps, for a sequence whose next
+    position is p, only the pages that hold p - window + 1 … p: pages
+    wholly behind are freed at step boundaries and their table entries
+    return to 0 (the trash page, which the kernel's lower bound never
+    reads). Requests hold their pages of kind n in `req.kind_pages[n]`,
+    {logical page: physical page}."""
+
+    def __init__(self, index, kind, num_pages, page_size, num_slots,
+                 pages_per_seq):
+        self.index = index
+        self.kind = kind
+        self.pool = PagePool(num_pages, page_size)
+        self.tables = np.zeros((num_slots, pages_per_seq), np.int32)
+
+    def first_live_page(self, position):
+        """The first logical page a query at `position` still reads."""
+        if self.kind.window is None:
+            return 0
+        return max(0, position - self.kind.window + 1) \
+            // self.pool.page_size
+
+    def missing(self, req, first_pos, last_pos):
+        """Logical pages this kind lacks for queries first_pos …
+        last_pos."""
+        held = req.kind_pages[self.index]
+        return [j for j in range(self.first_live_page(first_pos),
+                                 last_pos // self.pool.page_size + 1)
+                if j not in held]
+
+    def grow(self, slot, req, first_pos, last_pos):
+        """Allocate what is `missing` (raises PoolExhausted)."""
+        held = req.kind_pages[self.index]
+        for j in self.missing(req, first_pos, last_pos):
+            held[j] = self.pool.alloc()
+            self.tables[slot, j] = held[j]
+
+    def trim(self, slot, req):
+        """Free the pages wholly behind the window of the request's
+        next position; returns how many."""
+        if self.kind.window is None:
+            return 0
+        held = req.kind_pages[self.index]
+        first = self.first_live_page(req.n_prefilled)
+        dead = [j for j in held if j < first]
+        for j in dead:
+            self.pool.free([held.pop(j)])
+            self.tables[slot, j] = 0
+        return len(dead)
+
+    def release(self, slot, req):
+        held = req.kind_pages[self.index]
+        self.pool.free(held.values())
+        held.clear()
+        self.tables[slot, :] = 0
+
+
 class LLMEngineConfig:
     """Engine sizing. Defaults are safe (worst-case pool: no
     preemption); shrink `num_pages` to trade HBM for occasional
@@ -266,7 +326,10 @@ class LLMEngineConfig:
                   step's batch geometry)
     page_size     tokens per KV page
     num_pages     pool size incl. the trash page; default
-                  num_slots * ceil(max_model_len / page_size) + 1
+                  num_slots * ceil(max_model_len / page_size) + 1. A
+                  model with several cache kinds (docs/SERVING.md "Two
+                  kinds of cache") takes `{kind name: pages}`; a kind
+                  left out gets its worst case
     max_model_len per-sequence token cap; default model max_seq_len
     token_budget  flat tokens per step (>= num_slots); the surplus over
                   the decode tokens is the chunked-prefill bandwidth.
@@ -442,35 +505,48 @@ class LLMEngineConfig:
                 "mapping would alias a partially-matching page")
 
     @staticmethod
-    def kv_bytes_per_page(model_config, page_size, kv_dtype=None):
-        """Bytes ONE page costs across every layer's k+v pool, scale
-        planes included — the unit of the capacity math below. int8
-        rows cost hd + 4 bytes per head; packed int4 rows cost hd/2 +
-        4 (two nibbles per byte — the scale plane is shared machinery,
-        so its 4 bytes/head weigh relatively more: equal-bytes
-        capacity lands ≈ ×1.9 over int8, ≈ ×7 over fp32 at hd 32)."""
+    def kv_bytes_per_page(model_config, page_size, kv_dtype=None,
+                          kind=None):
+        """Bytes ONE page costs across the k+v pools of every layer of
+        one cache kind (`model_config.cache_kinds()`; `kind` its name,
+        default the first), scale planes included — the unit of the
+        capacity math below. int8 rows cost hd + 4 bytes per head;
+        packed int4 rows cost hd/2 + 4 (two nibbles per byte — the
+        scale plane is shared machinery, so its 4 bytes/head weigh
+        relatively more: equal-bytes capacity lands ≈ ×1.9 over int8,
+        ≈ ×7 over fp32 at hd 32)."""
         from ..quantization import runtime as _qrt
 
         dt, quantized = _qrt.resolve_kv_dtype(kv_dtype, jnp.float32)
-        nh = model_config.num_heads
-        hd = model_config.hidden_size // nh
+        kinds = model_config.cache_kinds()
+        ck = kinds[0] if kind is None else next(
+            k for k in kinds if k.name == kind)
+        nh, hd = ck.kv_heads, ck.head_dim
         if quantized == 4:
             per_row = nh * (hd // 2)      # packed nibbles
         else:
             per_row = nh * hd * jnp.dtype(dt).itemsize
         if quantized:
             per_row += nh * 4  # fp32 scale per (row, head)
-        return 2 * model_config.num_layers * page_size * per_row
+        return 2 * len(ck.layers) * page_size * per_row
 
     @classmethod
     def for_pool_budget(cls, model_config, budget_bytes, page_size=16,
                         kv_dtype=None, **kw):
         """Size `num_pages` to a page-pool BYTE budget — the equal-bytes
         capacity comparison the quantized-KV acceptance pins (int8 pools
-        admit ~4× the pages of fp32 at the same budget)."""
-        per_page = cls.kv_bytes_per_page(model_config, page_size,
-                                         kv_dtype)
-        num_pages = max(2, int(budget_bytes) // per_page + 1)  # + trash
+        admit ~4× the pages of fp32 at the same budget). A model with
+        several cache kinds takes a budget a kind: `{kind name:
+        bytes}`."""
+        def pages(budget, kind=None):
+            per_page = cls.kv_bytes_per_page(model_config, page_size,
+                                             kv_dtype, kind)
+            return max(2, int(budget) // per_page + 1)  # + trash
+
+        if isinstance(budget_bytes, dict):
+            num_pages = {k: pages(b, k) for k, b in budget_bytes.items()}
+        else:
+            num_pages = pages(budget_bytes)
         return cls(page_size=page_size, num_pages=num_pages,
                    kv_dtype=kv_dtype, **kw)
 
@@ -534,9 +610,16 @@ class _CompiledPagedStep(_CompiledStepBase):
                     p._value = v
             logits, *new_kv = out
             n = len(kv_vals)
-            return logits._value, ([x._value for x in new_kv[:n]],
-                                   [x._value for x in new_kv[n:]], key)
+            state = ([x._value for x in new_kv[:n]],
+                     [x._value for x in new_kv[n:n + len(kv_scales)]],
+                     key)
+            if counted:
+                # the model's own counters (serving_protocol.py): one
+                # more small result, read when the host next syncs
+                return (logits._value, new_kv[-1]._value), state
+            return logits._value, state
 
+        counted = bool(getattr(model, "step_counters", ()))
         self._jit = jax.jit(pure, donate_argnums=(8,))
 
     def __call__(self, tok, pos, sid, widx, pt, klen, smp, kv_state):
@@ -569,14 +652,20 @@ class _CompiledFusedStep(_CompiledStepBase):
                 p._value = v
             try:
                 with eng.no_grad_guard():
-                    emits, new_kv, new_scales = model._paged_decode_fused(
-                        self.k, ps, tok0, pos0, rem, fin0, eos, temps,
-                        top_ps, streams, pt, list(kv_vals),
-                        list(kv_scales) if kv_scales else None, key,
-                        gstate0=gstate0, gtrans=gtrans, gmask=gmask)
+                    emits, new_kv, new_scales, *counters = \
+                        model._paged_decode_fused(
+                            self.k, ps, tok0, pos0, rem, fin0, eos, temps,
+                            top_ps, streams, pt, list(kv_vals),
+                            list(kv_scales) if kv_scales else None, key,
+                            gstate0=gstate0, gtrans=gtrans, gmask=gmask)
             finally:
                 for p, v in zip(self._params, originals):
                     p._value = v
+            if counters:
+                # the model's own counters [k, C] ride the ONE array the
+                # host reads a window: [k, S + C]
+                emits = jnp.concatenate(
+                    [emits, counters[0].astype(emits.dtype)], axis=1)
             return emits, (new_kv, new_scales, key)
 
         self._jit = jax.jit(pure, donate_argnums=(13,))
@@ -603,6 +692,7 @@ class _Request:
         self.target = None        # total-token cap, set at add_request
         self.slot = None
         self.pages = []           # physical page ids, logical order
+        self.kind_pages = {}      # further cache kinds: {n: {logical: id}}
         self.n_prefilled = 0      # kv-written tokens (reset on preempt)
         self.draft_prefilled = 0  # draft-pool valid prefix (speculative)
         self.admit_seq = None     # admission order (preemption picks max)
@@ -714,12 +804,49 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 f"token_budget {self.token_budget} < num_slots "
                 f"{self.num_slots}: every running sequence needs one "
                 "decode token per step")
-        num_pages = int(cfg.num_pages
-                        or self.num_slots * self.pages_per_seq + 1)
+        # the model's cache kinds (text/models/serving_protocol.py). The
+        # FIRST keeps every page and is what `pool`, `req.pages` and
+        # `_page_tables` have always been; further kinds (a window
+        # kind's pages are freed behind the window) live in `_extra`
+        kinds = list(mcfg.cache_kinds())
+        if kinds[0].window is not None:
+            raise ValueError(
+                f"cache kind {kinds[0].name!r} comes first and has a "
+                "window: the first kind keeps every page (list a full "
+                "kind first)")
+        worst = self.num_slots * self.pages_per_seq + 1
+        if isinstance(cfg.num_pages, dict):
+            unknown = set(cfg.num_pages) - {k.name for k in kinds}
+            if unknown:
+                raise ValueError(
+                    f"num_pages names {sorted(unknown)}; the model's "
+                    f"cache kinds are {[k.name for k in kinds]}")
+            pages_of = [int(cfg.num_pages.get(k.name) or worst)
+                        for k in kinds]
+        else:
+            pages_of = [int(cfg.num_pages or worst)] + [worst] * (
+                len(kinds) - 1)
+        num_pages = pages_of[0]
         self.pool = PagePool(num_pages, self.page_size)
-
-        nh = mcfg.num_heads
-        hd = mcfg.hidden_size // nh
+        self._kinds = kinds
+        self._extra = [
+            _CacheKindState(n, kinds[n], pages_of[n], self.page_size,
+                            self.num_slots, self.pages_per_seq)
+            for n in range(1, len(kinds))]
+        if self._extra:
+            on = [name for name, v in (
+                ("prefix_cache=True", cfg.prefix_cache),
+                ("kv_tier", cfg.kv_tier),
+                ("speculative decoding", cfg.draft_model is not None
+                 or cfg.spec_mode)) if v]
+            if on:
+                raise ValueError(
+                    f"{', '.join(on)}: not with a model of "
+                    f"{len(kinds)} cache kinds "
+                    f"({[k.name for k in kinds]}). The prefix trie, "
+                    "the tier store, the KV wire and the speculative "
+                    "draft pool assume ONE page geometry and one page "
+                    "table a slot (ROADMAP.md B-I)")
         # pool in the configured kv_dtype (default: the model's compute
         # dtype — decode is HBM-bound, same reasoning as generate()'s
         # cache dtype; "int8" quantizes each written row per (token,
@@ -734,15 +861,21 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         from ..distributed import mesh as mesh_mod
         from ..quantization import runtime as _qrt
 
-        compute_dt = model.gpt.wte.weight._value.dtype
+        compute_dt = model.compute_dtype()
         cache_dt, self.kv_quantized = _qrt.resolve_kv_dtype(
             cfg.kv_dtype, compute_dt)
+        if self.kv_quantized and (
+                self._extra or any(k.head_major for k in kinds)):
+            raise ValueError(
+                f"kv_dtype={cfg.kv_dtype!r}: head-major pools and "
+                "models with several cache kinds keep float pools")
+        hd = kinds[0].head_dim
         # kv_quantized is the code width (0 float / 8 / 4 — truthy when
         # quantized); int4 packs two nibbles per byte along head_dim,
         # so the pool's last dim is hd/2 and attention unpacks on
         # gather (the shape IS the codec discriminator — gpt.py
         # _paged_cache_write_quant / F.paged_attention)
-        hd_store = hd
+        hd_store = None       # None: every kind stores its own head_dim
         if self.kv_quantized == 4:
             if hd % 2:
                 raise ValueError(
@@ -754,20 +887,29 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             self.kv_dtype = str(jnp.dtype(cache_dt))
         sharding = mesh_mod.named_sharding()  # replicated on the mesh
 
+        # k0, v0, k1, v1 … in LAYER order, each pool shaped by its
+        # layer's kind
+        kind_of = {i: n for n, k in enumerate(kinds) for i in k.layers}
+        n_layers = len(kind_of)
+        if sorted(kind_of) != list(range(n_layers)):
+            raise ValueError("the cache kinds must cover every layer "
+                             "once")
+
         def _fresh_pools():
             pools = [
                 jax.device_put(
-                    jnp.zeros((num_pages, self.page_size, nh, hd_store),
-                              cache_dt), sharding)
-                for _ in range(2 * mcfg.num_layers)]
+                    jnp.zeros(kinds[kind_of[i // 2]].pool_shape(
+                        pages_of[kind_of[i // 2]], self.page_size,
+                        hd_store), cache_dt), sharding)
+                for i in range(2 * n_layers)]
             scales = []
             if self.kv_quantized:
                 sshape = _qrt.kv_scale_shape(num_pages, self.page_size,
-                                             nh)
+                                             kinds[0].kv_heads)
                 scales = [
                     jax.device_put(jnp.zeros(sshape, jnp.float32),
                                    sharding)
-                    for _ in range(2 * mcfg.num_layers)]
+                    for _ in range(2 * n_layers)]
             return pools, scales
 
         self._fresh_pools = _fresh_pools
@@ -831,6 +973,20 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       "finished": 0, "preemptions": 0,
                       "occupancy_sum": 0.0, "fused_steps": 0,
                       "stage_hits": 0}
+        # the model's own step counters (e.g. an expert layer's), summed
+        # into `stats` under their names; a tick's arrive with the next
+        # read the ENGINE thread makes anyway (`_note_counters`: never a
+        # scraper's, which would race the step and wait on the device),
+        # so they are complete whenever no request is in flight
+        self._counter_names = tuple(getattr(model, "step_counters", ()))
+        self._pending_counters = []
+        for name in self._counter_names:
+            self.stats[name] = 0
+        if self._extra:
+            self.stats["window_pages_freed"] = 0
+            for k in kinds:
+                self.stats[f"{k.name}_pages_live"] = 0
+                self.stats[f"kv_positions_least_{k.name}"] = 0
         # recent per-request phase timelines (reqtrace), appended at
         # first token / prefill export — the `metrics()` drill-down
         self._timelines = collections.deque(maxlen=64)
@@ -1053,6 +1209,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       model)."""
         grammar_obj = self._resolve_constraint(grammar, json_schema,
                                                eos_token_id, spec_mode)
+        if self._extra and (prefill_only or kv_import is not None):
+            raise ValueError(
+                "prefill_only / kv_import: the KV wire carries one page "
+                "geometry; this model has "
+                f"{[k.name for k in self._kinds]} (ROADMAP.md B-I)")
         toks = np.asarray(prompt).reshape(-1)
         if toks.size == 0:
             raise ValueError("empty prompt")
@@ -1941,6 +2102,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
     def _release(self, slot, req):
         self.pool.free(req.pages)  # shared pages decref; trie keeps its
         req.pages = []             # own reference, private pages free
+        for ks in self._extra:
+            ks.release(slot, req)
         req.n_prefilled = 0
         req.draft_prefilled = 0   # preemption replay re-prefills BOTH pools
         req.cached_prefix = 0
@@ -2074,6 +2237,15 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # most resident_pages more, so free + victims + 2·resident <
         # prompt pages is infeasible regardless of what match() finds —
         # O(slots) with no trie walk
+        # (a') a further cache kind cannot hold the first chunk's pages:
+        # wait for runners to finish or to move their windows on (their
+        # pages are not this request's to take)
+        for ks in self._extra:
+            req.kind_pages.setdefault(ks.index, {})
+            span = len(req.tokens) if ks.kind.window is None else min(
+                len(req.tokens), ks.kind.window + self.token_budget)
+            if ks.pool.num_free < -(-span // self.page_size) + 1:
+                return False
         need_all = -(-len(req.tokens) // self.page_size)
         if self.pool.num_free - headroom < need_all:
             now = _time.perf_counter()
@@ -2257,6 +2429,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                         page = self._alloc_page()
                         self._page_tables[slot, len(req.pages)] = page
                         req.pages.append(page)
+                    for ks in self._extra:
+                        ks.grow(slot, req, req.n_prefilled, last)
                 except PoolExhausted:
                     # the victim may be no MORE urgent than the growing
                     # sequence: a BATCH job's page growth must never
@@ -2302,6 +2476,27 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         The straggler joins windows at the boundary after its prefill
         completes, and per-request greedy/sampled outputs are
         schedule-invariant, so nothing observable changes per request."""
+        out = self._step()
+        if self._extra:
+            self._trim_windows()
+        return out
+
+    def _trim_windows(self):
+        """The step boundary of a model with several cache kinds: every
+        running sequence frees the pages now wholly behind its windows,
+        and the page gauges of `stats` are brought up to date."""
+        freed = 0
+        for slot, req in enumerate(self._slots):
+            if req is not None:
+                for ks in self._extra:
+                    freed += ks.trim(slot, req)
+        self.stats["window_pages_freed"] += freed
+        self.stats[f"{self._kinds[0].name}_pages_live"] = \
+            self.pool.num_live
+        for ks in self._extra:
+            self.stats[f"{ks.kind.name}_pages_live"] = ks.pool.num_live
+
+    def _step(self):
         with _trace_span("llm_engine.admit",
                          waiting=len(self.waiting)) as span:
             self._sync_brownout()
@@ -2350,8 +2545,15 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         spill never recompiles."""
         if not active:
             return None
-        with _trace_span("llm_engine.reserve", rows=len(active)):
+        with _trace_span("llm_engine.reserve",
+                         rows=len(active)) as span:
             window = self._reserve_window(active)
+            if self._extra:
+                span.set(full_pages=self.pool.num_live,
+                         window_pages=sum(ks.pool.num_live
+                                          for ks in self._extra),
+                         window_pages_freed=self.stats[
+                             "window_pages_freed"])
         if window is None:
             return None
         (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst, gtrans,
@@ -2364,10 +2566,15 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                              decode_tokens=int(rem.sum())):
                 emits, (self._kv, self._kv_scales, self._key) = fused(
                     tok0, pos0, rem, fin0, eos, temps, tops, streams,
-                    gst, gtrans, gmask, self._page_tables,
+                    gst, gtrans, gmask, self._step_tables(),
                     (self._kv, self._kv_scales, self._key))
                 with _trace_span("llm_engine.sync"):
                     emits = np.asarray(emits)  # once-per-k host sync
+                if self._counter_names:
+                    # the model's counters rode the same array
+                    self._note_counters(
+                        emits[:, self.num_slots:].sum(axis=0))
+                    emits = emits[:, :self.num_slots]
         except Exception as e:
             # same contract as the single tick: the donated pytree may
             # already be consumed — fail in-flight work and re-zero
@@ -2395,6 +2602,18 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 tot += max(0, last // ps + 1 - len(req.pages))
             return tot
 
+        def extra_fits(w):
+            """Every further cache kind covers a window of w."""
+            for ks in self._extra:
+                need = 0
+                for _, req in active:
+                    writes = min(w, req.target - len(req.tokens))
+                    need += len(ks.missing(req, req.n_prefilled,
+                                           req.n_prefilled + writes - 1))
+                if need > ks.pool.num_free:
+                    return False
+            return True
+
         avail = self.pool.num_free
         if self.prefix_cache is not None:
             avail += self.prefix_cache.reclaimable_pages()
@@ -2403,9 +2622,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # never recompiles (overload.BrownoutController L3)
         cap = self._brownout.get("decode_k_cap")
         w = k if cap is None else max(1, min(k, int(cap)))
-        while w > 1 and pages_needed(w) > avail:
-            w -= 1        # spill: the largest window the pool covers
-        if pages_needed(w) > avail:
+        while w > 1 and (pages_needed(w) > avail or not extra_fits(w)):
+            w -= 1        # spill: the largest window the pools cover
+        if pages_needed(w) > avail or not extra_fits(w):
             return None   # not even 1 token/row: single tick preempts
 
         # reserve the window's pages up front (_alloc_page evicts LRU
@@ -2420,6 +2639,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                     page = self._alloc_page()
                     self._page_tables[slot, len(req.pages)] = page
                     req.pages.append(page)
+                for ks in self._extra:    # `extra_fits(w)` held above
+                    ks.grow(slot, req, req.n_prefilled, last)
                 writes = want
             except PoolExhausted:
                 writes = min(want,
@@ -2481,6 +2702,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                         or len(req.tokens) >= req.target):
                     done = True   # in-executable masking already
                     break         # padded the rest of the window
+            if self._extra and emitted:
+                self._note_attended(req.n_prefilled, 1, steps=emitted)
             req.n_prefilled += emitted
             total += emitted
             self.stats["generated"] += emitted
@@ -2507,6 +2730,47 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         _PAGE_OCC.set(self.pool.num_live / (self.pool.num_pages - 1))
         _PAGE_FRAG.set(self.kv_fragmentation())
         return finished
+
+    def _step_tables(self):
+        """The page tables a step is dispatched with: the one table
+        [S, MP], or with several cache kinds [kinds, S, MP] in the
+        model's order (serving_protocol.py)."""
+        if not self._extra:
+            return self._page_tables
+        return np.stack([self._page_tables]
+                        + [ks.tables for ks in self._extra])
+
+    def _note_attended(self, first_pos, rows, steps=1):
+        """The least K/V a step must read, as positions a cache kind
+        (`stats["kv_positions_least_<kind>"]`, several kinds only): one
+        slot had `rows` consecutive positions from `first_pos` through
+        the model in ONE step, and a layer must read the span they
+        attend once, however the kernel blocks its rows: every earlier
+        position, or from the first row's window on. `steps` > 1: that
+        many steps of one row each (a fused window's iterations)."""
+        for kind in self._kinds:
+            if steps == 1:
+                lo = 0 if kind.window is None else max(
+                    0, first_pos - kind.window + 1)
+                n = first_pos + rows - lo
+            else:
+                ctx = range(first_pos + 1, first_pos + steps + 1)
+                n = sum(ctx) if kind.window is None else sum(
+                    min(c, kind.window) for c in ctx)
+            self.stats[f"kv_positions_least_{kind.name}"] += n
+
+    def _note_counters(self, totals=None):
+        """Add the model's step counters to `stats`: `totals` [C] now,
+        and every tick's result still pending (device arrays that the
+        program order has completed by the time the host has read a
+        LATER result — no sync of their own)."""
+        pending, self._pending_counters = self._pending_counters, []
+        for c in pending:
+            c = np.asarray(c)
+            totals = c if totals is None else totals + c
+        if totals is not None:
+            for name, v in zip(self._counter_names, totals):
+                self.stats[name] += int(v)
 
     # ---- single-tick step (prefill / mixed / k=1) ----
 
@@ -2556,9 +2820,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             try:
                 logits, (self._kv, self._kv_scales, self._key) = \
                     self._step_fn(
-                        tok, pos, sid, widx, self._page_tables, klen,
+                        tok, pos, sid, widx, self._step_tables(), klen,
                         sample_idx,
                         (self._kv, self._kv_scales, self._key))
+                if self._counter_names:
+                    logits, counters = logits
+                    self._pending_counters.append(counters)
             except Exception as e:
                 # the donated pools may already be consumed by the
                 # failed dispatch — fail the in-flight work and re-zero
@@ -2571,6 +2838,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             # program it launched runs inside it on a profile
             with _trace_span("llm_engine.sync"):
                 nxt = self._read_frontier(logits, sample_slots)
+                if sample_slots and self._pending_counters:
+                    self._note_counters()   # the device is past them
         with _trace_span("llm_engine.emit"):
             return self._emit_tick(plan, i, sample_slots, nxt,
                                    only_slots)
@@ -2617,12 +2886,14 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         pos = np.zeros((T,), np.int32)
         widx = np.zeros((T,), np.int32)   # 0 → trash page, row 0
         klen = np.zeros((T,), np.int32)   # 0 → padding token
+        sid_np = np.zeros((T,), np.int32)
         if staged is not None:
             sid = staged["sid"]
             sample_idx = staged["sample_idx"]
             sample_slots = staged["slots"]
             for row, (slot, req, _) in enumerate(plan):
                 p = req.n_prefilled
+                sid_np[row] = slot
                 tok[row] = req.tokens[p]
                 pos[row] = p
                 widx[row] = (req.pages[p // self.page_size]
@@ -2634,7 +2905,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         else:
             from ..distributed import mesh as mesh_mod
 
-            sid = np.zeros((T,), np.int32)
+            sid = sid_np
             # per-SLOT sampling frontier: the vocab head only runs on
             # these gathered rows (stale slots point at row 0; logits
             # ignored)
@@ -2666,6 +2937,18 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             sharding = mesh_mod.named_sharding()
             sid = jax.device_put(sid, sharding)
             sample_idx = jax.device_put(sample_idx, sharding)
+        if self._extra:
+            # a write index a cache kind: the same position through
+            # each kind's own page table
+            rows = np.flatnonzero(klen)
+            slots = sid_np[rows]
+            page, off = pos[rows] // self.page_size, \
+                pos[rows] % self.page_size
+            widx = np.stack([widx] + [np.zeros_like(widx)
+                                      for _ in self._extra])
+            for ks in self._extra:
+                widx[ks.index, rows] = (
+                    ks.tables[slots, page] * self.page_size + off)
         return (plan, i, tok, pos, sid, widx, klen, sample_idx,
                 sample_slots)
 
@@ -2723,6 +3006,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
         finished = []
         for slot, req, take in plan:
+            if self._extra:
+                self._note_attended(req.n_prefilled, take)
             req.n_prefilled += take
             if req.n_prefilled >= len(req.tokens) - 1:
                 # the sampling frontier is reached: prefill is over

@@ -221,7 +221,7 @@ class SpeculativeDecoder:
         # draft pool mirrors the engine pool's geometry — SAME page ids
         # and page tables, its own buffers in the engine's kv dtype
         draft_dt, self._quantized = _qrt.resolve_kv_dtype(
-            engine.kv_dtype, draft_model.gpt.wte.weight._value.dtype)
+            engine.kv_dtype, draft_model.compute_dtype())
         # packed int4 pools halve the stored head_dim (same shape
         # discriminator the engine pool uses)
         hd_store = hd // 2 if self._quantized == 4 else hd

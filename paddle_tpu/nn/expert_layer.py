@@ -1,0 +1,117 @@
+"""A serving expert layer that is told which experts it holds.
+
+`distributed/moe.py` is training's layer: capacity buckets, dropped
+tokens, every expert on every chip. Serving under expert parallelism
+asks something else of one chip (model-configs guide §4): the router
+keeps its published width and experts per token, this chip holds
+`experts_held` of the experts (ids `first_expert` … `first_expert +
+experts_held - 1`), computes ITS experts' part of the result for the
+tokens routed to them, and drops nothing. What the absent experts would
+add arrives from the other chips in a deployment and is simply absent
+on one chip: no code here stands in for them.
+
+Raw `jax.numpy` in and out (the callers are step bodies already under
+`jax.jit`). Shapes are static whatever the routing: the `T·k`
+assignments are sorted by expert with the ones this chip does not hold
+(and those of padding rows) behind the last group, and ONE grouped
+matrix product per projection runs over the sorted rows, its group
+sizes a traced vector. So a step program holds one executable, and the
+bytes the product reads are the experts that were touched.
+"""
+import jax
+import jax.numpy as jnp
+
+from .functional.attention import _pallas_backend_ok
+
+__all__ = ["route_top_k", "held_experts_ffn", "grouped_matmul"]
+
+_scope = jax.named_scope
+
+
+def route_top_k(x, router_w, top_k, renormalise=True):
+    """Softmax routing in float32 over ALL `router_w.shape[1]` experts:
+    x [T, d], router_w [d, E_all] → (weights [T, top_k] float32, expert
+    ids [T, top_k] int32). `renormalise` divides the chosen weights by
+    their sum (norm_topk_prob)."""
+    with _scope("moe_router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision="highest")
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, ids = jax.lax.top_k(probs, int(top_k))
+        if renormalise:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return w, ids.astype(jnp.int32)
+
+
+# the Pallas grouped matmul's (k, n) tile: the whole contraction of the
+# first projection and 1 024 columns read best at 48 and at 512 rows on a
+# v5e (PERF.md §6, PR 28); rows a tile are its 128
+_GMM_TILE_M = 128
+_GMM_TILE_K = 3072
+_GMM_TILE_N = 1024
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs [M, K] sorted by group, rhs [G, K, N], group_sizes [G] int32
+    (Σ ≤ M) → [M, N]; rows past the last group are unspecified. Where
+    Pallas kernels run (a TPU: `_pallas_backend_ok`), the grouped matmul
+    shipped with JAX, M a multiple of its row tile (0.762 against
+    `ragged_dot`'s 0.757 ms at 48 rows, 1.779 against 2.860 ms at 512:
+    PERF.md §6, PR 28); `jax.lax.ragged_dot` elsewhere."""
+    if _pallas_backend_ok():
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+        m, k = lhs.shape
+        n = rhs.shape[2]
+        tiling = (min(_GMM_TILE_M, m), min(_GMM_TILE_K, k),
+                  min(_GMM_TILE_N, n))
+        return gmm(lhs, rhs, group_sizes,
+                   preferred_element_type=lhs.dtype, tiling=tiling)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def held_experts_ffn(x, weights, ids, valid, w_gate_up, w_down,
+                     first_expert=0):
+    """The held experts' part of a sparse feed-forward layer, dropless.
+
+    x [T, d] the normed input; weights / ids [T, k] from `route_top_k`;
+    valid [T] bool (False: a padding row, routed nowhere); w_gate_up
+    [E, d, 2m] (gate | up) and w_down [E, m, d] the E experts held here,
+    expert j of them being router output `first_expert + j`.
+
+    Returns (out [T, d] float32 = Σ over the held experts a row chose of
+    weight · E_e(x), counters int32 [3] = assignments of valid rows,
+    those that fell on a held expert, held experts with at least one
+    row)."""
+    T, d = x.shape
+    k = ids.shape[1]
+    E, _, m2 = w_gate_up.shape
+    m = m2 // 2
+    with _scope("moe_experts"):
+        flat_e = ids.reshape(-1) - int(first_expert)
+        flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        flat_w = weights.reshape(-1)
+        live = jnp.repeat(valid, k)
+        held = live & (flat_e >= 0) & (flat_e < E)
+        key = jnp.where(held, flat_e, E)            # not held: last
+        order = jnp.argsort(key, stable=True)
+        key_s = key[order]
+        tok_s = flat_t[order]
+        sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+        rows = T * k
+        pad = (-rows) % _GMM_TILE_M if _pallas_backend_ok() else 0
+        xs = x[tok_s]
+        if pad:
+            xs = jnp.pad(xs, ((0, pad), (0, 0)))
+        h = grouped_matmul(xs, w_gate_up, sizes)
+        act = (jax.nn.silu(h[:, :m].astype(jnp.float32))
+               * h[:, m:].astype(jnp.float32)).astype(x.dtype)
+        y = grouped_matmul(act, w_down, sizes)[:rows]
+        y = jnp.where((key_s < E)[:, None],
+                      y.astype(jnp.float32) * flat_w[order][:, None], 0.0)
+        out = jax.ops.segment_sum(y, tok_s, num_segments=T)
+        counters = jnp.stack([
+            jnp.sum(live), jnp.sum(held), jnp.sum(sizes > 0)]).astype(
+                jnp.int32)
+    return out, counters

@@ -8,6 +8,7 @@ when shapes warrant, the Pallas flash-attention kernel
 """
 import math
 
+import jax
 import jax.numpy as jnp
 
 from ...ops._helpers import apply_jfn, ensure_tensor
@@ -290,6 +291,117 @@ def paged_attention_jnp(qv, kpool, vpool, tables, sids, ls, k_scales=None,
     # uniform garbage — zero it explicitly
     return jnp.where((ls > 0)[:, None, None], out,
                      jnp.zeros_like(out))
+
+
+def paged_attention_gqa(qv, kpool, vpool, tables, sids, ls, starts=None,
+                        frontier_offset=None, block_layout=None):
+    """Ragged paged attention with GROUPED queries over HEAD-MAJOR pools,
+    on raw arrays: q [T, H, D], pools [N, KV, P, D] (query head j reads
+    KV head j // (H / KV)), tables [S, MP], sids / ls [T] as
+    `paged_attention`, `starts` [T] the first position each row sees
+    (a sliding window's lower bound; may be negative; None: 0). The
+    frontier offset advances both bounds of every live row. The Pallas
+    head-major walk on a TPU, `paged_attention_gqa_jnp` elsewhere.
+
+    block_layout: a `SlotBlockLayout` of these rows (made once a step,
+    used by every layer). The kernel then runs over the padded layout in
+    query blocks of `block_layout.rows` rows of one slot, so a slot's
+    pages are copied once a block and not once a row (a prefill chunk's
+    rows share their slot's context)."""
+    if not _pallas_backend_ok():
+        return paged_attention_gqa_jnp(qv, kpool, vpool, tables, sids, ls,
+                                       starts, frontier_offset)
+    from ...ops.pallas_kernels import paged_attention as pa_kernel
+
+    if block_layout is None:
+        return pa_kernel.ragged_paged_attention(
+            qv, kpool, vpool, tables, sids, ls, kv_starts=starts,
+            frontier_offset=frontier_offset, head_major=True)
+    lay = block_layout
+    out = pa_kernel.ragged_paged_attention(
+        lay.spread(qv), kpool, vpool, tables, lay.sids, lay.lens,
+        kv_starts=None if starts is None else lay.spread(starts),
+        frontier_offset=frontier_offset, head_major=True,
+        q_per_slot=lay.rows)
+    return out[lay.dest]
+
+
+class SlotBlockLayout:
+    """The rows of a flat step, laid out again so that every slot's run
+    of rows STARTS a block of `rows` rows (padding rows, length 0, fill
+    each run's last block). sids / lens [T] with the live rows first and
+    each slot's rows side by side (the engine's tick); at most
+    `max_runs` runs. `dest` [T] is where each row went (dead rows to the
+    last, dead, row), `sids` / `lens` the padded layout's, `spread(x)`
+    any per-row array in it."""
+
+    def __init__(self, sids, lens, rows, max_runs):
+        T = sids.shape[0]
+        self.rows = int(rows)
+        self.total = -(-(T + max_runs * (self.rows - 1)) // self.rows) \
+            * self.rows
+        r = jnp.arange(T, dtype=jnp.int32)
+        sids = sids.astype(jnp.int32)
+        live = lens > 0
+        starts_run = live & ((r == 0) | (sids != jnp.roll(sids, 1))
+                             | ~jnp.roll(live, 1))
+        run_start = jax.lax.cummax(jnp.where(starts_run, r, 0))
+        # the padding behind the run that ends where this one starts
+        pad = jnp.where(starts_run & (r > 0),
+                        (-(r - jnp.roll(run_start, 1))) % self.rows, 0)
+        self.dest = jnp.where(live, r + jnp.cumsum(pad), self.total - 1)
+        self.sids = self.spread(sids)
+        self.lens = self.spread(jnp.where(live, lens, 0))
+
+    def spread(self, x):
+        return jnp.zeros((self.total,) + x.shape[1:], x.dtype).at[
+            self.dest].set(x)
+
+
+def paged_attention_gqa_jnp(qv, kpool, vpool, tables, sids, ls, starts=None,
+                            frontier_offset=None):
+    """`paged_attention_gqa` in plain jnp: what every non-TPU backend
+    runs and what the head-major kernel is compared with. Same slot-grid
+    shape as `paged_attention_jnp`; a position is attended where
+    start <= position < kv_len."""
+    import jax
+
+    n_pages, kvh, page_size, d = kpool.shape
+    tokens, heads, _ = qv.shape
+    g = heads // kvh
+    n_slots, pages_per_seq = tables.shape
+    L = pages_per_seq * page_size
+    ls = ls.astype(jnp.int32)
+    st = (jnp.full_like(ls, -(2 ** 30)) if starts is None
+          else starts.astype(jnp.int32))
+    if frontier_offset is not None:
+        off = jnp.asarray(frontier_offset, jnp.int32)
+        st = jnp.where(ls > 0, st + off, 0)
+        ls = jnp.where(ls > 0, ls + off, 0)
+    st = jnp.maximum(st, 0)
+    sids = sids.astype(jnp.int32)
+    l_idx = jnp.arange(L, dtype=jnp.int32)
+    phys = (tables.astype(jnp.int32)[:, l_idx // page_size]
+            * page_size + (l_idx % page_size)[None, :])   # [S, L]
+    k_all = jnp.swapaxes(kpool, 1, 2).reshape(n_pages * page_size, kvh, d)
+    v_all = jnp.swapaxes(vpool, 1, 2).reshape(n_pages * page_size, kvh, d)
+    ks, vs = k_all[phys], v_all[phys]                     # [S, L, KV, d]
+    eq = sids[:, None] == sids[None, :]
+    cpos = jnp.sum(jnp.tril(eq, -1), axis=1)              # [T]
+    qs = jnp.zeros((n_slots, tokens, kvh, g, d), qv.dtype).at[
+        (sids, cpos)].set(qv.reshape(tokens, kvh, g, d))
+    hi = jnp.zeros((n_slots, tokens), jnp.int32).at[(sids, cpos)].set(ls)
+    lo = jnp.zeros((n_slots, tokens), jnp.int32).at[(sids, cpos)].set(st)
+    sc = jnp.einsum("schgd,slhd->shgcl", qs, ks,
+                    preferred_element_type=jnp.float32) / math.sqrt(d)
+    seen = ((l_idx[None, None, :] < hi[:, :, None])
+            & (l_idx[None, None, :] >= lo[:, :, None]))   # [S, C, L]
+    sc = jnp.where(seen[:, None, None], sc, jnp.float32(-1e30))
+    w = jax.nn.softmax(sc, axis=-1).astype(vs.dtype)
+    o = jnp.einsum("shgcl,slhd->schgd", w, vs,
+                   preferred_element_type=jnp.float32).astype(qv.dtype)
+    out = o[(sids, cpos)].reshape(tokens, heads, d)
+    return jnp.where((ls > 0)[:, None, None], out, jnp.zeros_like(out))
 
 
 def _pallas_backend_ok():

@@ -50,6 +50,20 @@ layout is shared with PagePool, the KV wire, the tier store and the
 trie; a head-major pool that would feed the MXU is a layout change,
 not a kernel change.
 
+THE HEAD-MAJOR WALK (`head_major=True`) is that layout change, for a
+model whose pools are its own: the pool is [num_pages, kv_heads,
+page_size, head_dim], a page's `[page_size, head_dim]` per KV head
+fills whole tiles at any head count (8 KV heads of a bf16 pool
+included, which the `[H, D]` page above cannot), and the query heads
+that share a KV head (GROUPED queries: q heads = kv_heads · G) are the
+free dimension the MXU lacked: per KV head and group of pages,
+`q[G, D] · Kᵀ[D, pages·P]` and `p[G, pages·P] · V[pages·P, D]` are two
+matrix products. Rows carry a LOWER bound beside the upper one
+(`kv_starts`: the first position a row sees, a sliding window's p -
+W + 1): the walk starts at that position's page, so pages behind the
+window are neither copied nor computed, and the table entries of pages
+the cache manager freed there are never read.
+
 Decode-only (no VJP): serving runs under no_grad. Numerics follow the
 flash kernel: f32 accumulation, masked positions get -1e30,
 fully-masked rows (padding tokens, kv_len 0) finalize to exact zeros.
@@ -321,12 +335,222 @@ def _page_grid_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref,
         _finalize(o_ref, acc_ref, m_ref, l_ref)
 
 
+# VMEM for the head-major walk's double-buffered K and V page groups.
+# Larger is faster up to what compiles: a group costs a fixed few
+# microseconds beside its pages (at 0.5 / 1 / 2 / 4 / 8 MiB a decode row
+# over a full context read 20 / 33 / 38 / 53-58 / 61 % of 819 GB/s on a
+# v5e, PERF.md §6, PR 28); 16 MiB no longer fits the scoped VMEM.
+_GQA_GROUP_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _gqa_walks(page_size, kdim, dtype):
+    """True where the head-major walk can copy a page `[KV, P, D]` into
+    its place in a group buffer: `[P, D]` must be whole (sublane,
+    128-lane) tiles of the pool's dtype."""
+    rows = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return kdim % 128 == 0 and page_size % rows == 0
+
+
+def _gqa_walk_kernel(sid_ref, pt_ref, lens_ref, starts_ref, off_ref, q_ref,
+                     k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref,
+                     m_ref, l_ref, *, pages_per_seq, group):
+    """One grid step = one query block: `q_ref` [qb, KV, G, D] (qb rows
+    of ONE slot; the G query heads of each KV head padded to whole
+    sublane tiles), the walk over the block's live pages [the earliest
+    row's first position's page, the longest row's last page] the loop
+    in here; each row masks at its own bounds. `k_buf`/`v_buf` are
+    `[2, KV, group·P, D]`: two halves, in each the group's pages side
+    by side per KV head, so that a head's keys are ONE `[group·P, D]`
+    operand and its queries one `[qb·G, D]`."""
+    b = pl.program_id(0)
+    qb, kv_heads, gp, dim = q_ref.shape
+    rows = k_buf.shape[2]
+    page_size = rows // group
+    scale = 1.0 / math.sqrt(dim)
+    # stated, not inherited: a process-wide "highest" (the test suite's)
+    # is no precision Mosaic has for 16-bit operands
+    precision = (jax.lax.Precision.HIGHEST
+                 if k_buf.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    # per-row bounds from SMEM (static unroll); a padding row (length
+    # 0) bounds nothing
+    kvlens, starts = [], []
+    for i in range(qb):
+        base = lens_ref[b * qb + i]
+        kvlens.append(jnp.where(base > 0, base + off_ref[0], 0))
+        starts.append(jnp.maximum(jnp.where(
+            base > 0, starts_ref[b * qb + i] + off_ref[0], 0), 0))
+    kvmax = kvlens[0]
+    first = jnp.where(kvlens[0] > 0, starts[0], 2 ** 30)
+    for i in range(1, qb):
+        kvmax = jnp.maximum(kvmax, kvlens[i])
+        first = jnp.minimum(first, jnp.where(kvlens[i] > 0, starts[i],
+                                             2 ** 30))
+    first_page = jnp.where(kvmax > 0, first, 0) // page_size
+    n_pages = jnp.maximum(
+        jnp.minimum((kvmax + (page_size - 1)) // page_size, pages_per_seq)
+        - first_page, 0)
+    n_groups = (n_pages + (group - 1)) // group
+    # one slot per block (the slot-major contract): its first row names it
+    table = sid_ref[b * qb] * pages_per_seq + first_page
+
+    # a group's dead tail (pages past the block) is never copied: what
+    # it multiplies (weight exactly 0) must be finite, so the buffers
+    # start from zeros; later they only ever hold pool pages
+    @pl.when(b == 0)
+    def _zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    def live_in(g):
+        return jnp.minimum(group, n_pages - g * group)
+
+    def group_copies(g, half, start_copy):
+        def one(i, carry):
+            page = pt_ref[table + g * group + i] if start_copy else 0
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                dma = pltpu.make_async_copy(
+                    hbm.at[page],
+                    buf.at[half, :, pl.ds(i * page_size, page_size), :],
+                    sems.at[half])
+                dma.start() if start_copy else dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(g), one, None)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # each matmul row's own bounds: row r belongs to block row r // gp
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (qb * gp, rows), 0) // gp
+    lo = jnp.zeros((qb * gp, rows), jnp.int32)
+    hi = jnp.zeros((qb * gp, rows), jnp.int32)
+    for i in range(qb):
+        lo = jnp.where(row_of == i, starts[i], lo)
+        hi = jnp.where(row_of == i, kvlens[i], hi)
+
+    @pl.when(n_groups > 0)
+    def _first():
+        group_copies(0, 0, True)
+
+    def one_group(g, carry):
+        half = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            group_copies(g + 1, 1 - half, True)
+
+        group_copies(g, half, False)
+        pos = (first_page + g * group) * page_size \
+            + jax.lax.broadcasted_iota(jnp.int32, (qb * gp, rows), 1)
+        valid = (pos >= lo) & (pos < hi)
+        for h in range(kv_heads):
+            k = k_buf[half, h]                       # [group·P, D]
+            v = v_buf[half, h]
+            q = q_ref[:, h].reshape(qb * gp, dim)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h][:, :1]
+            l_prev = l_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, one_group, None)
+    for h in range(kv_heads):
+        l = l_ref[h][:, :1]
+        # padding rows (kv_len 0) attended nothing: l == 0 -> zeros
+        o_ref[:, h] = (acc_ref[h] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype).reshape(qb, gp, dim)
+
+
+@functools.lru_cache(maxsize=None)
+def _gqa_paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
+                    qb, interpret):
+    """The head-major launch for one set of static shapes, ONE jitted
+    function a set (as `_paged_call`)."""
+    tokens, heads, dim = q_shape
+    _, kv_heads, page_size, kdim = pool_shape
+    if heads % kv_heads or kdim != dim:
+        raise ValueError(
+            f"grouped queries need q heads ({heads}) a multiple of the "
+            f"pool's KV heads ({kv_heads}) and one head_dim ({dim} / "
+            f"{kdim})")
+    if not _gqa_walks(page_size, kdim, pool_dtype):
+        raise ValueError(
+            f"a head-major {jnp.dtype(pool_dtype).name} pool needs "
+            f"head_dim % 128 == 0 and pages of whole sublane tiles; got "
+            f"page_size {page_size}, head_dim {kdim}")
+    if tokens % qb:
+        raise ValueError(f"{tokens} rows are not whole blocks of {qb}")
+    g = heads // kv_heads
+    tile = 8 * (4 // jnp.dtype(q_dtype).itemsize)
+    gp = -(-g // tile) * tile          # the group, in whole sublane tiles
+    page_bytes = kv_heads * page_size * kdim * jnp.dtype(pool_dtype).itemsize
+    group = max(1, min(pages_per_seq,
+                       _GQA_GROUP_VMEM_BYTES // (4 * page_bytes)))
+    q_block = (qb, kv_heads, gp, kdim)
+
+    def q_map(b, *_):
+        return (b, 0, 0, 0)
+
+    q_spec = pl.BlockSpec(q_block, q_map)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    buf = pltpu.VMEM((2, kv_heads, group * page_size, kdim), pool_dtype)
+    launch = pl.pallas_call(
+        functools.partial(_gqa_walk_kernel, pages_per_seq=pages_per_seq,
+                          group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(tokens // qb,),
+            in_specs=[q_spec, hbm, hbm], out_specs=q_spec,
+            scratch_shapes=[
+                buf, buf, pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((kv_heads, qb * gp, kdim), jnp.float32),
+                pltpu.VMEM((kv_heads, qb * gp, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, qb * gp, 128), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, gp, kdim),
+                                       q_dtype),
+        interpret=interpret,
+    )
+
+    def call(sid, table, lens, starts, off, q, k_pool, v_pool):
+        q4 = q.reshape(tokens, kv_heads, g, dim)
+        if gp != g:
+            q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+        out = launch(sid, table, lens, starts, off, q4, k_pool, v_pool)
+        return out[:, :, :g].reshape(tokens, heads, dim)
+
+    return jax.jit(call, inline=True)
+
+
 def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
                            kv_lens, k_scales=None, v_scales=None,
                            frontier_offset=None, q_per_slot=None,
-                           interpret=False):
+                           interpret=False, kv_starts=None,
+                           head_major=False):
     """q [T, H, D], pools [N, P, H, D], page_tables [S, MP] int,
     slot_ids [T] int, kv_lens [T] int → out [T, H, D].
+
+    head_major (STATIC): the pools are [N, KV, P, D] and the H query
+    heads share the KV heads in groups of H / KV (query head j reads KV
+    head j // (H / KV)): the head-major walk of the module docstring.
+    Float pools only. kv_starts [T] int (with head_major): the first
+    position each row sees (may be negative: clamped at 0); the frontier
+    offset advances it with kv_lens. None: every row sees from position
+    0. `q_per_slot` there is a CONTRACT and no hint: T is whole blocks
+    of that many rows, every block's rows are one slot's (padding rows,
+    length 0, only behind its live rows), and the block's pages are
+    copied once and multiplied as `[q_per_slot · G, D]` queries.
 
     frontier_offset: optional scalar int32 added to every NONZERO
     kv_lens row (rides scalar-prefetch SMEM like the page table). The
@@ -365,6 +589,26 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     tests/test_quant_runtime.py and tests/test_speculative.py, and
     compiled on the chip by chip_smoke.py)."""
     tokens, heads, dim = q.shape
+    if frontier_offset is None:
+        frontier_offset = 0
+    if head_major:
+        if k_scales is not None:
+            raise ValueError("the head-major walk takes float pools")
+        if kv_starts is None:
+            # no lower bound: far enough below 0 that the frontier
+            # offset never lifts it above
+            kv_starts = jnp.full((tokens,), -(2 ** 30), jnp.int32)
+        call = _gqa_paged_call(q.shape, q.dtype, k_pool.shape,
+                               k_pool.dtype, page_tables.shape[1],
+                               int(q_per_slot or 1), interpret)
+        return call(jnp.asarray(slot_ids, jnp.int32),
+                    jnp.asarray(page_tables, jnp.int32).reshape(-1),
+                    jnp.asarray(kv_lens, jnp.int32),
+                    jnp.asarray(kv_starts, jnp.int32),
+                    jnp.asarray(frontier_offset, jnp.int32).reshape((1,)),
+                    q, k_pool, v_pool)
+    if kv_starts is not None:
+        raise ValueError("kv_starts needs head_major pools")
     _, page_size, _, kdim = k_pool.shape
     quantized = 0
     if k_scales is not None:
@@ -372,8 +616,6 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     qb = 1
     if q_per_slot is not None and tokens % int(q_per_slot) == 0:
         qb = int(q_per_slot)
-    if frontier_offset is None:
-        frontier_offset = 0
     scales = (k_scales, v_scales) if quantized else ()
     call = _paged_call(q.shape, q.dtype, k_pool.shape, k_pool.dtype,
                        page_tables.shape[1], quantized, qb, interpret)

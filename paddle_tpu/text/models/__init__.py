@@ -25,7 +25,11 @@ from .bert import (  # noqa: F401
     ernie_3_base,
 )
 
+from .laguna import LagunaConfig, LagunaForCausalLM, laguna_tiny  # noqa: F401,E402
+from .serving_protocol import CacheKind  # noqa: F401,E402
+
 __all__ = [
+    "LagunaConfig", "LagunaForCausalLM", "laguna_tiny", "CacheKind",
     "GPTConfig", "GPTDecoderLayer", "GPTModel", "GPTForCausalLM",
     "GPTPretrainingCriterion", "gpt_tiny", "gpt_small", "gpt_medium",
     "gpt_1p3b",

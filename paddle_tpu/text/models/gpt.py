@@ -66,6 +66,16 @@ class GPTConfig:
         # False | True (keep nothing) | policy name ('dots_saveable', ...)
         self.recompute = recompute
 
+    def cache_kinds(self):
+        """What the serving engine asks (serving_protocol.py): ONE kind
+        of K/V cache, every layer's, page-major pools."""
+        from .serving_protocol import CacheKind
+
+        return [CacheKind("kv", tuple(range(self.num_layers)),
+                          self.num_heads,
+                          self.hidden_size // self.num_heads, None,
+                          False)]
+
 
 def gpt_tiny(**kw):
     return GPTConfig(vocab_size=2048, hidden_size=128, num_layers=2,
@@ -955,6 +965,13 @@ class GPTGenerationMixin:
 
 class GPTForCausalLM(GPTGenerationMixin, nn.Layer):
     """LM head tied to the (vocab-sharded) embedding by default."""
+
+    # the serving engine's protocol (serving_protocol.py): no counters
+    # of its own, one cache kind (`GPTConfig.cache_kinds`)
+    step_counters = ()
+
+    def compute_dtype(self):
+        return self.gpt.wte.weight._value.dtype
 
     def __init__(self, config):
         super().__init__()

@@ -1,0 +1,55 @@
+"""What `inference.LLMEngine` asks of a model it serves.
+
+The engine owns scheduling, pages and the compiled steps; the model owns
+its arithmetic. Between them:
+
+    model.config.vocab_size, .max_seq_len
+    model.config.cache_kinds() -> [CacheKind, ...]
+        the kinds of K/V cache its layers keep. One kind, one page pool
+        and one page table a slot: layers of a kind share page ids, so a
+        page of that kind is one row of every one of their pools.
+    model.compute_dtype()      -> the dtype activations and float pools
+                                  default to
+    model.step_counters        -> names of the int32 counters its step
+                                  bodies return (may be empty)
+    model._paged_decode_core(tok, pos, slot_ids, write_idx, page_tables,
+        kv_lens, sample_idx, kv, kv_scales=None, ...)
+        one ragged step over flat tokens (Tensors in and out): returns
+        (logits [1, S, vocab], *new pools, *new scale planes[, counters
+        [len(step_counters)]]). `kv` is the flat list k0, v0, k1, v1 …
+        in LAYER order, each pool shaped by its layer's kind.
+    model._paged_decode_fused(k, page_size, tok0, pos0, rem, fin0, eos,
+        temps, top_ps, streams, page_tables, kv, kv_scales, key, ...)
+        `k` such steps in one scan with sampling inside (raw arrays):
+        returns (emits [k, S], new kv, new scales[, counters [k, C]]).
+
+With ONE kind, `write_idx` is [T] and `page_tables` [S, MP]. With
+several, both carry a leading axis in the order of `cache_kinds()`:
+`write_idx` [kinds, T], `page_tables` [kinds, S, MP].
+"""
+import collections
+
+__all__ = ["CacheKind"]
+
+
+class CacheKind(collections.namedtuple(
+        "CacheKind", "name layers kv_heads head_dim window head_major")):
+    """One kind of K/V cache.
+
+    name        what the engine's counters and spans call it
+    layers      indices of the model's layers that keep this kind
+    kv_heads    K/V heads a layer of this kind caches
+    head_dim    their size
+    window      None: a layer attends every earlier position and keeps
+                every page. An int W: position p attends p - W + 1 … p,
+                and the cache manager frees pages wholly behind that.
+    head_major  pool layout: False [pages, page, kv_heads, head_dim],
+                True [pages, kv_heads, page, head_dim]
+    """
+    __slots__ = ()
+
+    def pool_shape(self, num_pages, page_size, head_dim_store=None):
+        hd = self.head_dim if head_dim_store is None else head_dim_store
+        if self.head_major:
+            return (num_pages, self.kv_heads, page_size, hd)
+        return (num_pages, page_size, self.kv_heads, hd)

@@ -74,3 +74,44 @@ def test_paged_kernel_compiles_for_v5e(one_chip, tokens, heads, dim, table,
     with jax.enable_x64(False):
         text = jax.jit(call).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# The head-major walk at the published shapes of the window / full cell
+# (laguna-s-2.1: 48 and 72 query heads over 8 KV heads of 128, bf16
+# pools [pages, 8, 16, 128], a lower bound a row): the fused window's
+# 48 rows and the single tick's 512, both cache kinds' page counts.
+_GQA_LAUNCHES = [
+    ("full_window_rows", 48, 48, 13313, None),
+    ("full_tick_rows", 512, 48, 13313, None),
+    ("sliding_window_rows", 48, 72, 1821, None),
+    ("sliding_tick_rows", 512, 72, 1821, None),
+    # the tick as the model launches it: 512 rows laid out in blocks of
+    # 8 rows of one slot (`SlotBlockLayout`: 512 + 48 · 7 rows)
+    ("full_tick_blocks", 848, 48, 13313, 8),
+    ("sliding_tick_blocks", 848, 72, 1821, 8),
+]
+
+
+@pytest.mark.parametrize(
+    "tokens,heads,pages,qps",
+    [pytest.param(*c[1:], id=c[0]) for c in _GQA_LAUNCHES])
+def test_head_major_gqa_kernel_compiles_for_v5e(one_chip, tokens, heads,
+                                                pages, qps):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (pages, 8, 16, 128)
+    args = [sds((tokens, heads, 128), jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds((48, 320), jnp.int32), sds((tokens,), jnp.int32),
+            sds((tokens,), jnp.int32), sds((tokens,), jnp.int32),
+            sds((), jnp.int32)]
+
+    def call(q, k, v, pt, sid, lens, starts, off):
+        return ragged_paged_attention(
+            q, k, v, pt, sid, lens, kv_starts=starts,
+            frontier_offset=off, head_major=True, q_per_slot=qps)
+
+    with jax.enable_x64(False):
+        text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
