@@ -66,6 +66,7 @@ tests/test_llm_engine.py); eos semantics follow the shared contract
 (the emitted eos is kept, nothing after it).
 """
 import collections
+import contextlib
 import itertools
 import os
 import queue
@@ -81,6 +82,7 @@ import jax.numpy as jnp
 from ..observability import metrics as _obs
 from ..observability import reqtrace as _reqtrace
 from ..observability.tracing import trace_span as _trace_span
+from ..ops.pallas_kernels import paged_attention as _paged_kernel
 from .structured.compiler import _STRUCT_CACHE_HITS, _STRUCT_REQS
 from .fleet_serving import (Priority, RadixPrefixCache, RequestCancelled,
                             RequestShed, SLAScheduler, note_cancelled,
@@ -561,10 +563,23 @@ class _CompiledStepBase:
     docs/RESILIENCE.md)."""
 
     _jit = None
+    # page-major paged-attention launches ONE dispatch makes, by the
+    # body the kernel built for their shapes ({"mxu": n, "vpu": n});
+    # known once the program is traced, i.e. from its first dispatch on
+    launches = {}
 
     def cache_size(self):
         n = getattr(self._jit, "_cache_size", None)
         return int(n()) if callable(n) else -1
+
+    @contextlib.contextmanager
+    def _counting_launches(self, repeats=1):
+        """Around the model's step body inside `pure`: the kernel call
+        sites traced in the block, times `repeats` (the length of the
+        scan they sit in), are what a dispatch launches."""
+        with _paged_kernel.launch_sites() as sites:
+            yield
+        self.launches = {b: n * repeats for b, n in sites.items()}
 
 
 class _CompiledPagedStep(_CompiledStepBase):
@@ -598,7 +613,7 @@ class _CompiledPagedStep(_CompiledStepBase):
             for p, v in zip(self._params, param_vals):
                 p._value = v
             try:
-                with eng.no_grad_guard():
+                with eng.no_grad_guard(), self._counting_launches():
                     out = model._paged_decode_core(
                         t(tok), t(pos), t(sid), t(widx), t(pt), t(klen),
                         t(smp), [t(v) for v in kv_vals],
@@ -651,7 +666,7 @@ class _CompiledFusedStep(_CompiledStepBase):
             for p, v in zip(self._params, param_vals):
                 p._value = v
             try:
-                with eng.no_grad_guard():
+                with eng.no_grad_guard(), self._counting_launches(self.k):
                     emits, new_kv, new_scales, *counters = \
                         model._paged_decode_fused(
                             self.k, ps, tok0, pos0, rem, fin0, eos, temps,
@@ -972,7 +987,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self.stats = {"steps": 0, "tokens_in": 0, "generated": 0,
                       "finished": 0, "preemptions": 0,
                       "occupancy_sum": 0.0, "fused_steps": 0,
-                      "stage_hits": 0}
+                      "stage_hits": 0,
+                      # which body the paged kernel's launches ran
+                      # (`_note_launches`); both 0 on the jnp path
+                      "paged_attn_mxu_launches": 0,
+                      "paged_attn_vpu_launches": 0}
         # the model's own step counters (e.g. an expert layer's), summed
         # into `stats` under their names; a tick's arrive with the next
         # read the ENGINE thread makes anyway (`_note_counters`: never a
@@ -1811,6 +1830,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             "structured": self._structured_metrics(),
             "fused_steps": int(_FUSED_STEPS.value),
             "dispatches": int(_DISPATCHES.value),
+            "paged_attn_mxu_launches":
+                self.stats["paged_attn_mxu_launches"],
+            "paged_attn_vpu_launches":
+                self.stats["paged_attn_vpu_launches"],
             "tokens_per_dispatch": _TOK_PER_DISPATCH.value,
             "admission_p50_s": _ADMIT_SECONDS.quantile(0.5),
             "admission_p99_s": _ADMIT_SECONDS.quantile(0.99),
@@ -2681,6 +2704,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self.stats["steps"] += 1
         self.stats["fused_steps"] += 1
         self.stats["occupancy_sum"] += len(active) / self.num_slots
+        self._note_launches(self._fused_fn)
         _STEPS_TOTAL.inc()
         _FUSED_STEPS.inc()
         _DISPATCHES.inc()
@@ -2758,6 +2782,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 n = sum(ctx) if kind.window is None else sum(
                     min(c, kind.window) for c in ctx)
             self.stats[f"kv_positions_least_{kind.name}"] += n
+
+    def _note_launches(self, step_fn):
+        """A dispatch of `step_fn` was made: add the paged-attention
+        launches it holds to `stats`, by the body they run (a tick adds
+        a launch a layer, a window of k that k times)."""
+        for body, n in step_fn.launches.items():
+            self.stats[f"paged_attn_{body}_launches"] += n
 
     def _note_counters(self, totals=None):
         """Add the model's step counters to `stats`: `totals` [C] now,
@@ -2983,6 +3014,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self.stats["steps"] += 1
         self.stats["tokens_in"] += i
         self.stats["occupancy_sum"] += len(plan) / self.num_slots
+        self._note_launches(self._step_fn)
         _STEPS_TOTAL.inc()
         _DISPATCHES.inc()
         # a ragged-window straggler tick covers only the PREFILL rows —

@@ -116,7 +116,8 @@ class _CompiledProposeStep(_CompiledStepBase):
             for p, v in zip(self._params, param_vals):
                 p._value = v
             try:
-                with eng.no_grad_guard():
+                with eng.no_grad_guard(), \
+                        self._counting_launches(self.k + 1):
                     emits, new_kv, new_scales = model._paged_decode_fused(
                         self.k + 1, ps, tok0, pos0, rem, fin0, eos,
                         temps, top_ps, streams, pt, list(kv_vals),
@@ -163,7 +164,7 @@ class _CompiledVerifyStep(_CompiledStepBase):
             for p, v in zip(self._params, param_vals):
                 p._value = v
             try:
-                with eng.no_grad_guard():
+                with eng.no_grad_guard(), self._counting_launches():
                     emits, new_kv, new_scales = model._paged_verify_fused(
                         self.k, ps, tok0, pos0, drafts, width, rem,
                         fin0, eos, temps, top_ps, streams, pt,
@@ -342,6 +343,7 @@ class SpeculativeDecoder:
                 eng._page_tables, klen,
                 jax.device_put(np.zeros((1,), np.int32), sharding),
                 (self._kv, self._kv_scales, eng._key))
+            eng._note_launches(self._prefill_fn)
             for slot, req in todo:
                 req.draft_prefilled += took.get(slot, 0)
 
@@ -478,6 +480,8 @@ class SpeculativeDecoder:
 
         self._stats["steps"] += 1
         self._stats["spec_windows"] += 1
+        eng._note_launches(self._propose_fn)
+        eng._note_launches(self._verify_fn)
         self._stats["occupancy_sum"] += len(frontier) / S
         _STEPS_TOTAL.inc()
         _FUSED_STEPS.inc()

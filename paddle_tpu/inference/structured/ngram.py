@@ -209,6 +209,7 @@ class NgramSpeculator:
 
         self._stats["steps"] += 1
         self._stats["ngram_windows"] += 1
+        eng._note_launches(self._verify_fn)
         self._stats["occupancy_sum"] += len(frontier) / S
         _STEPS_TOTAL.inc()
         _FUSED_STEPS.inc()

@@ -36,21 +36,22 @@ Who walks the pages (`_walks_in_kernel`, static shapes only):
   tiled HBM ref must leave whole tiles in the last two dims, which
   head_dim 64, packed int4 (lane dim D/2), 12 heads of a 16-bit or 8-bit
   pool, and every `[P, H]` scale plane (so every int8/int4 pool) do
-  not. One page body serves both.
+  not. It shares its page body with the walk's VPU body.
 
-Why the body is VPU work over [H, ·] tiles and not an MXU batched
-matmul: a page is `[P, H, D]` — heads on the SUBLANE dim. A per-head
-`q·kᵀ` needs `[H, P, D]` (a major↔sublane transpose), and its lhs
-`q[H, D]` has no free dimension anyway (one query row per head: an MXU
-pass at 1/128 occupancy). So the contraction is reordered: per page
-row p, `sum_d q[H, D]·k_p[H, D]` is an elementwise multiply and a lane
-reduce, the softmax runs over the P row-columns `[H, 1]`, and the
-weighted sum of `v_p[H, D]` is a lane-broadcast multiply-add. The pool
-layout is shared with PagePool, the KV wire, the tier store and the
-trie; a head-major pool that would feed the MXU is a layout change,
-not a kernel change.
+Which unit multiplies (`_multiplies_on_mxu`, static shapes only). A
+page is `[P, H, D]`, heads on the SUBLANE dim, and a head has ONE query
+row: a per-head `q·kᵀ` wants `[H, P, D]` (a major↔sublane transpose)
+and leaves the MXU no free dimension. Where the heads are whole sublane
+tiles of the pool's dtype (16 of a 16-bit pool, 8 of a 32-bit one) a
+page IS `[P·H, D]` with no data movement, and ALL heads go through at
+once (`_mxu_body`): `q[H, D] · Kᵀ[D, G·P·H]` scores every head's query
+against every head's keys, a mask keeps the columns of the row's own
+head, and `p · V[G·P·H, D]` is exact because a masked weight is 0:
+H − 1 of H products thrown away on a unit that had nothing to do, for
+a tenth of the vector work. Everything else keeps the contraction on
+the VPU (`_attend_page`): a multiply and a lane reduce a page row.
 
-THE HEAD-MAJOR WALK (`head_major=True`) is that layout change, for a
+THE HEAD-MAJOR WALK (`head_major=True`) changes the layout instead, for a
 model whose pools are its own: the pool is [num_pages, kv_heads,
 page_size, head_dim], a page's `[page_size, head_dim]` per KV head
 fills whole tiles at any head count (8 KV heads of a bf16 pool
@@ -68,8 +69,10 @@ Decode-only (no VJP): serving runs under no_grad. Numerics follow the
 flash kernel: f32 accumulation, masked positions get -1e30,
 fully-masked rows (padding tokens, kv_len 0) finalize to exact zeros.
 """
+import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -238,13 +241,15 @@ def _attend_page(j, row, kvlens, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
 def _walk_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_hbm, v_hbm,
                  o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
-                 pages_per_seq, group):
+                 pages_per_seq, group, body):
     """One grid step = one query block; the walk over its slot's live
-    pages is the loop in here. `k_buf`/`v_buf` are `[2·G, P, H, D]`:
-    two halves of `G` pages, one DMA semaphore a half."""
+    pages is the loop in here. `k_buf`/`v_buf` are `[2·G, P, H, D]`
+    (`_mxu_body`: `[2·G, P·H, D]`): two halves of `G` pages, one DMA
+    semaphore a half. `body` (`_vpu_body` / `_mxu_body`, static) folds
+    a copied group into the block's statistics."""
     b = pl.program_id(0)
-    qb = q_ref.shape[0]
-    page_size = k_buf.shape[1]
+    qb, _, heads, _ = q_ref.shape
+    page_size = math.prod(k_buf.shape[1:-1]) // heads
     kvlens, kvmax = _block_kv_lens(lens_ref, off_ref, b, qb)
     # pages past the LONGEST row's prefix contribute to no row: the
     # walk ends there (padding rows have kvlen 0, so an all-padding
@@ -277,6 +282,8 @@ def _walk_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_hbm, v_hbm,
         jax.lax.fori_loop(0, live_in(g), one, None)
 
     _init_stats(acc_ref, m_ref, l_ref)
+    attend = body(b, n_pages, kvlens, q_ref, k_buf, v_buf, acc_ref, m_ref,
+                  l_ref, group=group, page_size=page_size)
 
     @pl.when(n_groups > 0)
     def _first():
@@ -291,14 +298,7 @@ def _walk_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_hbm, v_hbm,
             group_copies(g + 1, 1 - half, start=True)
 
         group_copies(g, half, start=False)
-
-        def one_page(i, c):
-            _attend_page(g * group + i, half * group + i, kvlens, q_ref,
-                         k_buf, v_buf, None, None, acc_ref, m_ref, l_ref,
-                         quantized=0)
-            return c
-
-        jax.lax.fori_loop(0, live_in(g), one_page, None)
+        attend(g, half, live_in(g))
         return carry
 
     jax.lax.fori_loop(0, n_groups, one_group, None)
@@ -619,11 +619,39 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
     scales = (k_scales, v_scales) if quantized else ()
     call = _paged_call(q.shape, q.dtype, k_pool.shape, k_pool.dtype,
                        page_tables.shape[1], quantized, qb, interpret)
+    for sites in _open_site_counts.stack:
+        sites[call.body] += 1
     return call(jnp.asarray(slot_ids, jnp.int32),
                 jnp.asarray(page_tables, jnp.int32).reshape(-1),
                 jnp.asarray(kv_lens, jnp.int32),
                 jnp.asarray(frontier_offset, jnp.int32).reshape((1,)),
                 q, k_pool, v_pool, *scales)
+
+
+class _OpenSiteCounts(threading.local):
+    """The `launch_sites()` blocks open on this thread."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_open_site_counts = _OpenSiteCounts()
+
+
+@contextlib.contextmanager
+def launch_sites():
+    """Which body the page-major launches traced inside the block run:
+    yields `{"mxu": n, "vpu": n}`, one count a CALL SITE (a launch
+    inside a scan body counts once, however long the scan). The body is
+    static a launch (`_multiplies_on_mxu`), so a step program traced
+    inside the block knows how many launches of each one dispatch
+    makes; host integers, nothing on the device."""
+    sites = {"mxu": 0, "vpu": 0}
+    _open_site_counts.stack.append(sites)
+    try:
+        yield sites
+    finally:
+        _open_site_counts.stack.remove(sites)
 
 
 @functools.lru_cache(maxsize=None)
@@ -653,16 +681,20 @@ def _paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
         return (b, 0, 0, 0)
 
     q_spec = pl.BlockSpec(q_block, q_map)
+    mxu = _multiplies_on_mxu(heads, kdim, pool_dtype, quantized)
     if _walks_in_kernel(heads, kdim, pool_dtype, quantized):
         group = _pages_per_group(page_size, heads, kdim, pool_dtype,
                                  pages_per_seq)
         kernel = functools.partial(
-            _walk_kernel, pages_per_seq=pages_per_seq, group=group)
+            _walk_kernel, pages_per_seq=pages_per_seq, group=group,
+            body=_mxu_body if mxu else _vpu_body)
         grid = (tokens // qb,)
         hbm = pl.BlockSpec(memory_space=pltpu.HBM)
         in_specs = [q_spec, hbm, hbm]
-        scratch = [pltpu.VMEM((2 * group, page_size, heads, kdim),
-                              pool_dtype)] * 2
+        # the MXU body's operands are a page's `[P·H, D]` rows
+        page = ((page_size * heads, kdim) if mxu
+                else (page_size, heads, kdim))
+        scratch = [pltpu.VMEM((2 * group, *page), pool_dtype)] * 2
         scratch += [pltpu.SemaphoreType.DMA((2,))] + stats
     else:
         kernel = functools.partial(_page_grid_kernel, quantized=quantized)
@@ -704,7 +736,110 @@ def _paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
 
     def call(sid, table, lens, off, q, *pools):
         q4 = jnp.swapaxes(q.reshape(tokens, heads, halves, kdim), 1, 2)
+        if mxu:
+            # `[N, P, H, D]` as `[N, P·H, D]`: whole sublane tiles of
+            # heads, so the same bytes in the same order (a bitcast)
+            pools = [x.reshape(-1, page_size * heads, kdim) for x in pools]
         out = launch(sid, table, lens, off, q4, *pools)
         return jnp.swapaxes(out, 1, 2).reshape(tokens, heads, dim)
 
-    return jax.jit(call, inline=True)
+    jitted = jax.jit(call, inline=True)
+    jitted.body = "mxu" if mxu else "vpu"
+    return jitted
+
+
+# ---- the page-major walk's two bodies ---------------------------------
+# Below the head-major section on purpose: a Pallas call's serialized
+# body keeps its source lines (PERF.md §6, PRs 25 and 28), and lines
+# that move above `_gqa_walk_kernel` compile its step programs again.
+
+def _multiplies_on_mxu(heads, kdim, dtype, quantized):
+    """True where the walk's body is `_mxu_body`: a pool the walk takes
+    whose heads are whole sublane tiles of its dtype (16 of a 16-bit
+    pool, 8 of a 32-bit one), so that a page `[P, H, D]` IS `[P·H, D]`
+    (module docstring)."""
+    tile = 8 * (4 // jnp.dtype(dtype).itemsize)
+    return (_walks_in_kernel(heads, kdim, dtype, quantized)
+            and heads % tile == 0)
+
+
+def _vpu_body(b, n_pages, kvlens, q_ref, k_buf, v_buf, acc_ref, m_ref,
+              l_ref, *, group, page_size):
+    """The walk's body a page at a time on the VPU (`_attend_page`, the
+    page grid's too), over the group's live pages only."""
+    def attend(g, half, n_live):
+        def one_page(i, c):
+            _attend_page(g * group + i, half * group + i, kvlens, q_ref,
+                         k_buf, v_buf, None, None, acc_ref, m_ref, l_ref,
+                         quantized=0)
+            return c
+
+        jax.lax.fori_loop(0, n_live, one_page, None)
+
+    return attend
+
+
+def _mxu_body(b, n_pages, kvlens, q_ref, k_buf, v_buf, acc_ref, m_ref,
+              l_ref, *, group, page_size):
+    """The walk's body a GROUP at a time on the MXU, all heads at once
+    (module docstring). Matmul row r is head r % H of block row r // H;
+    column c of a group is head c % H of the group's token c // H."""
+    qb, _, heads, dim = q_ref.shape
+    rows, cols = qb * heads, group * page_size * heads
+    scale = 1.0 / math.sqrt(dim)
+    # operands as stored, f32 accumulation; stated, not inherited: a
+    # process-wide "highest" (the test suite's) is no precision Mosaic
+    # has for 16-bit operands
+    dt = jnp.promote_types(q_ref.dtype, k_buf.dtype)
+    precision = (jax.lax.Precision.HIGHEST if dt == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+
+    # a last group's dead pages are never copied: what their columns
+    # multiply (weight exactly 0) must be finite, so the buffers start
+    # from zeros; later they only ever hold pool pages
+    @pl.when(b == 0)
+    def _zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    # once a block, outside the group loop: the column's token offset in
+    # its group where the heads match, a position no length reaches
+    # where they do not, so ONE compare a group decides both; and each
+    # matmul row's own length (the table holds `n_pages` and no more)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    head_pos = jnp.where(col % heads == row % heads, col // heads, 2 ** 30)
+    block_row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads
+    kvlen = jnp.zeros((rows, 1), jnp.int32)
+    for i in range(qb):
+        kvlen = jnp.where(block_row == i, kvlens[i], kvlen)
+    kvlen = jnp.minimum(kvlen, n_pages * page_size)
+
+    def attend(g, half, n_live):
+        q = q_ref[:, 0].reshape(rows, dim).astype(dt)
+        k = k_buf[pl.ds(half * group, group)].reshape(cols, dim).astype(dt)
+        v = v_buf[pl.ds(half * group, group)].reshape(cols, dim).astype(dt)
+        valid = head_pos < kvlen - g * (group * page_size)
+        # scaled in f32 AFTER the product: q is not rounded again
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...].reshape(rows, 128)[:, :1]
+        l_prev = l_ref[...].reshape(rows, 128)[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row that has attended nothing yet (a padding row beside
+        # live ones) still has m_new == NEG_INF, and exp(s - m_new)
+        # would read 1 across it: the mask again
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        # the one rounding the VPU body has not: p to the operands' dtype
+        acc = alpha * acc_ref[:, 0].reshape(rows, dim) + jax.lax.dot_general(
+            p.astype(dt), v, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+        acc_ref[:, 0] = acc.reshape(qb, heads, dim)
+        m_ref[...] = jnp.broadcast_to(m_new, (rows, 128)).reshape(m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, (rows, 128)).reshape(l_ref.shape)
+
+    return attend

@@ -123,12 +123,16 @@ def test_pallas_ragged_paged_attention_interpret_matches_jnp():
 
 
 # the page walk: pools × heads whose pages the kernel copies itself
-# (float32 at 12 heads, bfloat16 at 16) and quantized pools, which stay
-# on the page grid; head_dim 128 throughout (int4 packs it to 64 lanes)
-_WALK_POOLS = [("float32", 12), ("bfloat16", 16), ("int8", 12), ("int4", 16)]
+# (float32 at 12 heads: the VPU body; float32 and bfloat16 at 16: the MXU
+# body, with float32 queries, which widen a bf16 pool's operands, and
+# with the pool's own bf16) and quantized pools, which stay on the page
+# grid; head_dim 128 throughout (int4 packs it to 64 lanes)
+_WALK_POOLS = [("float32", 12, None), ("bfloat16", 16, None),
+               ("int8", 12, None), ("int4", 16, None),
+               ("float32", 16, None), ("bfloat16", 16, "bfloat16")]
 
 
-def _walk_case(pool, heads, scenario):
+def _walk_case(pool, heads, scenario, q_dtype=None):
     """(kernel kwargs, reference kwargs) of one scenario, sized from
     the kernel's own group length G so that every boundary of the walk
     is crossed: page, group, the last partial group, the table's end."""
@@ -144,7 +148,7 @@ def _walk_case(pool, heads, scenario):
     MP = 2 * G + 3                     # two whole groups and a partial one
     cap = MP * P
     qps = off = None
-    if scenario == "ragged":
+    if scenario in ("ragged", "garbage"):
         # padding among live rows; 1 token; exactly a page; exactly a
         # group; one past it; a live-page count that is no multiple of
         # G; the whole table
@@ -156,7 +160,7 @@ def _walk_case(pool, heads, scenario):
         off = 3
         lens = [P - 2, G * P - 1, cap - 3, 0, 2 * G * P - 3, 1]
         sid = [0, 1, 2, 3, 3, 0]
-    else:
+    elif scenario == "verify":
         # the verify layout: 3 rows a slot, ragged inside a block —
         # across a group boundary, a dead tail row, an all-dead block,
         # the table's end
@@ -164,11 +168,28 @@ def _walk_case(pool, heads, scenario):
         lens = [G * P - 1, G * P, G * P + 1, 5, 6, 0, 0, 0, 0,
                 cap - 2, cap - 1, cap]
         sid = [0, 0, 0, 1, 1, 1, 2, 2, 2, 3, 3, 3]
+    else:
+        # blocks of 5 with the frontier offset on top: rows a whole
+        # GROUP shorter than their block's longest (groups they must
+        # not touch), a one-page block with a dead tail, an all-dead
+        # block, the table's end reached by the offset
+        qps, off = 5, 2
+        lens = [3, G * P - 3, G * P - 2, 2 * G * P - 1, 2 * G * P + 6,
+                1, 2, 3, 0, 0, 0, 0, 0, 0, 0,
+                cap - 6, cap - 5, cap - 4, cap - 3, cap - 2]
+        sid = [0] * 5 + [1] * 5 + [2] * 5 + [3] * 5
     rng = np.random.default_rng(len(lens) * heads + G)
     S = 4
     N = S * MP + 1
     pt = (rng.permutation(np.arange(1, N)).reshape(S, MP).astype(np.int32))
-    q = jnp.asarray(rng.standard_normal((len(sid), heads, D)), jnp.float32)
+    if scenario == "garbage":
+        # table entries behind a slot's last live page hold ids of no
+        # page: the walk reads the table only as far as a row is long
+        live = np.zeros(S, np.int64)
+        np.maximum.at(live, sid, -(-np.asarray(lens) // P))
+        pt[np.arange(MP)[None, :] >= live[:, None]] = 7 * N
+    q = jnp.asarray(rng.standard_normal((len(sid), heads, D)),
+                    q_dtype or jnp.float32)
     kv = [jnp.asarray(rng.standard_normal((N, P, heads, D)), jnp.float32)
           for _ in range(2)]
     scales = {}
@@ -188,23 +209,71 @@ def _walk_case(pool, heads, scenario):
     # the reference reads the SAME stored values, widened (a bf16 pool
     # is exact in f32), so both sides are f32 arithmetic
     wide = kv if scales else [x.astype(jnp.float32) for x in kv]
-    ref = dict(args=(q, *wide, *map(jnp.asarray, args)), kw=dict(
-        frontier_offset=offv, max_tokens_per_slot=qps, **scales))
+    ref = dict(args=(q.astype(jnp.float32), *wide, *map(jnp.asarray, args)),
+               kw=dict(frontier_offset=offv, max_tokens_per_slot=qps,
+                       **scales))
     return kern, ref, np.asarray(lens) == 0
 
 
-@pytest.mark.parametrize("scenario", ["ragged", "frontier", "verify"])
-@pytest.mark.parametrize("pool,heads", _WALK_POOLS)
-def test_pallas_paged_walk_matches_jnp(pool, heads, scenario):
+@pytest.mark.parametrize("scenario", ["ragged", "frontier", "verify",
+                                      "verify5_frontier", "garbage"])
+@pytest.mark.parametrize("pool,heads,q_dtype", _WALK_POOLS)
+def test_pallas_paged_walk_matches_jnp(pool, heads, q_dtype, scenario):
     from paddle_tpu.nn.functional.attention import paged_attention_jnp
     from paddle_tpu.ops.pallas_kernels import paged_attention as pak
 
-    kern, ref, dead = _walk_case(pool, heads, scenario)
+    kern, ref, dead = _walk_case(pool, heads, scenario, q_dtype)
     got = np.asarray(pak.ragged_paged_attention(
-        *kern["args"], **kern["kw"], interpret=True))
+        *kern["args"], **kern["kw"], interpret=True).astype("float32"))
     want = np.asarray(paged_attention_jnp(*ref["args"], **ref["kw"]))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+    # bf16 queries: the MXU body's operands are bf16 as stored, its
+    # weights p are rounded to bf16 for the second product, and so is
+    # the result (chip_smoke.py holds the same launch to 2e-2)
+    tol = (dict(rtol=2e-2, atol=2e-2) if q_dtype
+           else dict(rtol=1e-5, atol=2e-6))
+    np.testing.assert_allclose(got, want, **tol)
     assert dead.any() and np.all(got[dead] == 0)
+
+
+# which body a launch gets (`paged_attention._multiplies_on_mxu`), and
+# that `launch_sites()` sees it: (pool dtype, heads, head_dim) → body
+_BODIES = [("bfloat16", 16, 128, "mxu"), ("float32", 16, 128, "mxu"),
+           ("float32", 8, 128, "mxu"), ("bfloat16", 32, 128, "mxu"),
+           ("float32", 12, 128, "vpu"), ("bfloat16", 12, 128, "vpu"),
+           ("bfloat16", 8, 128, "vpu"), ("float32", 16, 64, "vpu"),
+           ("int8", 16, 128, "vpu"), ("int4", 16, 128, "vpu")]
+
+
+@pytest.mark.parametrize("pool,heads,dim,body", _BODIES)
+def test_which_body_a_paged_launch_runs(pool, heads, dim, body):
+    """16 heads × 128 of a bf16 pool (the decode cell) multiply on the
+    MXU, as every float pool the walk takes whose heads are whole
+    sublane tiles; 12 heads, head_dim 64 and the quantized pools keep
+    the VPU page body (and the grid they had)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+
+    z = jnp.zeros
+    quant = pool in ("int8", "int4")
+    store = jnp.int8 if quant else jnp.dtype(pool)
+    page = (9, 16, heads, dim // 2 if pool == "int4" else dim)
+    scales = {n: z(page[:3], jnp.float32)
+              for n in (("k_scales", "v_scales") if quant else ())}
+    with pak.launch_sites() as sites:
+        jaxpr = jax.make_jaxpr(lambda q, k, v: pak.ragged_paged_attention(
+            q, k, v, z((2, 4), jnp.int32), z((6,), jnp.int32),
+            z((6,), jnp.int32), **scales))(
+            z((6, heads, dim), jnp.float32 if quant else store),
+            z(page, store), z(page, store))
+    assert sites == {"mxu": int(body == "mxu"), "vpu": int(body == "vpu")}
+    assert pak._multiplies_on_mxu(heads, page[3], store,
+                                  bool(quant)) == (body == "mxu")
+    call, = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    walks = pak._walks_in_kernel(heads, page[3], store, bool(quant))
+    assert len(call.params["grid_mapping"].grid) == (1 if walks else 2)
+    assert pak._open_site_counts.stack == []     # the block is closed
 
 
 def _pallas_grid(page_tables_width, heads, dim, dtype, tokens=6, qps=None):
@@ -243,6 +312,70 @@ def test_pallas_paged_grid_does_not_scale_with_max_model_len():
     assert _pallas_grid(128, 12, 64, jnp.float32) == (6, 128)
     assert not pak._walks_in_kernel(12, 128, jnp.bfloat16, 0)
     assert not pak._walks_in_kernel(16, 128, jnp.int8, 8)
+
+
+def _count_launches_of(model):
+    """Serve two prompts through a fused-window engine; the engine and
+    what it served."""
+    eng = LLMEngine(model, LLMEngineConfig(
+        num_slots=2, page_size=16, max_model_len=64, token_budget=8,
+        decode_k=4))
+    reqs = [eng.add_request(np.arange(n) % 50, max_new_tokens=9)
+            for n in (5, 11)]
+    while eng.has_work():
+        eng.step()
+    served = [r.future.result(timeout=0) for r in reqs]
+    assert [len(t) for t in served] == [5 + 9, 11 + 9]
+    return eng, served
+
+
+def test_paged_launch_counters_read_zero_on_the_jnp_path():
+    """The CPU tier's engine attends through `paged_attention_jnp`: no
+    kernel launch of either body, and both counters are there to say
+    so, in `stats` and in `metrics()`."""
+    eng, _ = _count_launches_of(_tiny_model()[1])
+    assert eng.stats["fused_steps"] > 0
+    for name in ("paged_attn_mxu_launches", "paged_attn_vpu_launches"):
+        assert eng.stats[name] == 0 and eng.metrics()[name] == 0
+    assert eng._step_fn.launches == {"mxu": 0, "vpu": 0}
+    assert eng._fused_fn.launches == {"mxu": 0, "vpu": 0}
+
+
+@pytest.mark.parametrize("heads,body", [(8, "mxu"), (4, "vpu")])
+def test_paged_launch_counters_count_every_dispatch(monkeypatch, heads,
+                                                    body):
+    """With the kernel forced on (interpreted): a tick adds a launch a
+    layer, a window of k adds k a layer, all of the body the shapes get
+    (float32 pool, head_dim 128: 8 heads are a whole sublane tile, 4
+    are not), and the served tokens are the jnp path's."""
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+    from paddle_tpu.text.models.gpt import GPTConfig
+
+    paddle.seed(3)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=128 * heads, num_layers=2,
+        num_heads=heads, max_seq_len=64))
+    model.eval()
+    _, want = _count_launches_of(model)
+    kernel = pak.ragged_paged_attention
+    monkeypatch.setattr(attention, "_paged_pallas_eligible",
+                        lambda q, k_pool: True)
+    monkeypatch.setattr(
+        pak, "ragged_paged_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
+    eng, served = _count_launches_of(model)
+    windows = eng.stats["fused_steps"]
+    ticks = eng.stats["steps"] - windows
+    assert windows > 0 and ticks > 0
+    assert eng._step_fn.launches[body] == 2
+    assert eng._fused_fn.launches[body] == 2 * 4
+    other = "vpu" if body == "mxu" else "mxu"
+    assert eng.stats[f"paged_attn_{body}_launches"] == 2 * (
+        ticks + 4 * windows)
+    assert eng.stats[f"paged_attn_{other}_launches"] == 0
+    for got, ref in zip(served, want):
+        np.testing.assert_array_equal(got, ref)
 
 
 # --------------------------------------------------------------------

@@ -35,6 +35,11 @@ _LAUNCHES = [
     ("cell_window", 24, 16, 128, (24, 128), "bfloat16", None),
     ("cell_tick", 256, 16, 128, (24, 128), "bfloat16", None),
     ("cell_verify", 120, 16, 128, (24, 128), "bfloat16", 5),
+    # those three multiply on the MXU (bf16 operands as stored); a
+    # float32 pool at 16 heads does too, at `Precision.HIGHEST`; 12
+    # heads keep the VPU body inside the same walk
+    ("walk_f32_h16", 24, 16, 128, (24, 128), "float32", None),
+    ("walk_f32_h16_verify", 120, 16, 128, (24, 128), "float32", 5),
     ("walk_f32_h12", 17, 12, 128, (4, 8), "float32", None),
     # what the walk cannot slice stays on the page grid: head_dim 64,
     # 12 heads of a 16-bit pool, quantized pools

@@ -488,7 +488,11 @@ def test_spec_metrics_surface(pair, prompts):
 # kernels: blocked-verify Pallas parity + jnp grid hint
 # --------------------------------------------------------------------
 
-def test_qblock_pallas_parity_interpret():
+# the page grid's geometry (head_dim 64), and the decode cell's: 16
+# heads × 128, where the float pool walks its pages in the kernel and
+# multiplies on the MXU, the int8 pool keeps the page grid
+@pytest.mark.parametrize("P,H,D", [(8, 4, 64), (16, 16, 128)])
+def test_qblock_pallas_parity_interpret(P, H, D):
     """The query-blocked Pallas kernel (one DMA of each page per slot
     BLOCK instead of per row) must match the per-token kernel on
     verify-shaped ragged inputs — float and int8, with and without the
@@ -498,7 +502,7 @@ def test_qblock_pallas_parity_interpret():
         ragged_paged_attention)
 
     rng = np.random.default_rng(0)
-    S, MP, N, P, H, D = 3, 4, 13, 8, 4, 64
+    S, MP, N = 3, 4, 13
     k = 3
     Q = k + 1
     T = S * Q
