@@ -401,6 +401,7 @@ def _paged_step_args(engine):
     i32 = np.int32
     sf = engine._step_fn
     sharding = mesh_mod.named_sharding()
+    flat = np.zeros((T,), i32)
     # sid / sample_idx are device-COMMITTED at runtime (the engine's
     # staging cache) — match, or the probe itself would trace a second
     # signature
@@ -408,8 +409,7 @@ def _paged_step_args(engine):
         [p._value for p in sf._params],
         np.zeros((T,), i32), np.zeros((T,), i32),
         jax.device_put(np.zeros((T,), i32), sharding),
-        # one write index a cache kind (the engine's `_step_tables`)
-        np.zeros((len(engine._kinds), T) if engine._extra else (T,), i32),
+        engine._write_index(flat, flat, flat),  # one a cache kind
         engine._step_tables(), np.zeros((T,), i32),
         jax.device_put(np.zeros((engine.num_slots,), i32), sharding),
         (engine._kv, engine._kv_scales, engine._key),
@@ -468,7 +468,7 @@ def _verify_step_args(engine):
         np.full((S,), -1, i32), np.zeros((S,), np.float32),
         np.ones((S,), np.float32), np.zeros((S,), i32),
         gst, gtrans, gmask,
-        engine._page_tables,
+        engine._step_tables(),
         (engine._kv, engine._kv_scales, engine._key),
     )
 
@@ -497,7 +497,7 @@ def _propose_step_args(engine):
         np.full((S,), -1, i32), np.zeros((S,), np.float32),
         np.ones((S,), np.float32), np.zeros((S,), i32),
         np.zeros((S,), i32), np.zeros((S,), i32),
-        engine._page_tables,
+        engine._step_tables(),
         (spec._kv, spec._kv_scales, engine._key),
     )
 

@@ -259,16 +259,33 @@ class PagePool:  # ptlint: thread-shared (scraped by /metrics)
                 f"{len(self._ref)} live != {self.num_pages - 1}")
 
 
+class _HeldPages:
+    """What one request holds of one cache kind: the physical `pages`
+    of its logical pages `first` …, a CONTIGUOUS run (growth appends, a
+    window frees from the front). `len()` and iteration: logical."""
+
+    def __init__(self):
+        self.first = 0
+        self.pages = []
+
+    def __len__(self):
+        return len(self.pages)
+
+    def __iter__(self):
+        return iter(range(self.first, self.first + len(self.pages)))
+
+
 class _CacheKindState:
-    """One FURTHER kind of K/V cache beside the engine's first (a model
-    with several: `text/models/serving_protocol.py`): its own page pool
-    and its own page table a slot, indexed by LOGICAL page like the
-    first kind's. A kind with a window keeps, for a sequence whose next
-    position is p, only the pages that hold p - window + 1 … p: pages
-    wholly behind are freed at step boundaries and their table entries
-    return to 0 (the trash page, which the kernel's lower bound never
-    reads). Requests hold their pages of kind n in `req.kind_pages[n]`,
-    {logical page: physical page}."""
+    """One kind of K/V cache of the model (`text/models/
+    serving_protocol.py`; GPT has one, the engine's first is no
+    exception): its page pool, its page table a slot indexed by LOGICAL
+    page, and what each request holds of it (`req.kind_pages[index]`,
+    a `_HeldPages`). A kind with a window keeps, for a sequence whose
+    next position is p, only the pages that hold p - window + 1 … p:
+    pages wholly behind are freed at step boundaries and their table
+    entries return to 0 (the trash page, which the kernel's lower bound
+    never reads). The kind whose pool carries the prefix trie has it in
+    `trie`: a dry pool first reclaims the trie's LRU pages."""
 
     def __init__(self, index, kind, num_pages, page_size, num_slots,
                  pages_per_seq):
@@ -276,6 +293,7 @@ class _CacheKindState:
         self.kind = kind
         self.pool = PagePool(num_pages, page_size)
         self.tables = np.zeros((num_slots, pages_per_seq), np.int32)
+        self.trie = None
 
     def first_live_page(self, position):
         """The first logical page a query at `position` still reads."""
@@ -284,20 +302,74 @@ class _CacheKindState:
         return max(0, position - self.kind.window + 1) \
             // self.pool.page_size
 
-    def missing(self, req, first_pos, last_pos):
-        """Logical pages this kind lacks for queries first_pos …
-        last_pos."""
+    def available(self):
+        """Pages `alloc` can still hand out: the free ones and what the
+        trie would give back."""
+        if self.trie is None:
+            return self.pool.num_free
+        return self.pool.num_free + self.trie.reclaimable_pages()
+
+    def pages_to_admit(self, n_tokens, token_budget):
+        """Free pages a prompt of `n_tokens` asks for at admission: all
+        of a full kind's; of a window kind's those of the first chunk
+        and the window behind it, and one more (the page ahead is taken
+        before the page behind is freed)."""
+        if self.kind.window is None:
+            return -(-n_tokens // self.pool.page_size)
+        span = min(n_tokens, self.kind.window + token_budget)
+        return -(-span // self.pool.page_size) + 1
+
+    def alloc(self):
+        try:
+            return self.pool.alloc()
+        except PoolExhausted:
+            if self.trie is not None and self.trie.evict(1) > 0:
+                return self.pool.alloc()
+            raise
+
+    def covered(self, req):
+        """The position the request's pages reach: every position below
+        it has its page, or lies behind the window."""
         held = req.kind_pages[self.index]
-        return [j for j in range(self.first_live_page(first_pos),
-                                 last_pos // self.pool.page_size + 1)
-                if j not in held]
+        return (held.first + len(held.pages)) * self.pool.page_size
+
+    def _next_page(self, held, first_pos):
+        """The next logical page to take for a request whose next
+        position is `first_pos`: nothing held starts at the window."""
+        if held.pages:
+            return held.first + len(held.pages)
+        return max(held.first, self.first_live_page(first_pos))
+
+    def missing(self, req, first_pos, last_pos):
+        """How many pages this kind lacks for queries first_pos …
+        last_pos, `first_pos` being the request's next position."""
+        return max(0, last_pos // self.pool.page_size + 1
+                   - self._next_page(req.kind_pages[self.index],
+                                     first_pos))
 
     def grow(self, slot, req, first_pos, last_pos):
-        """Allocate what is `missing` (raises PoolExhausted)."""
+        """Allocate what is `missing`. PoolExhausted leaves what was
+        taken before it with the request."""
         held = req.kind_pages[self.index]
-        for j in self.missing(req, first_pos, last_pos):
-            held[j] = self.pool.alloc()
-            self.tables[slot, j] = held[j]
+        if not held.pages:
+            held.first = self._next_page(held, first_pos)
+        for _ in range(self.missing(req, first_pos, last_pos)):
+            page = self.alloc()
+            self.tables[slot, held.first + len(held.pages)] = page
+            held.pages.append(page)
+
+    def adopt(self, slot, req, pages):
+        """`pages`, whose references are already the request's (a mapped
+        prefix, imported pages), become its logical pages 0 …"""
+        held = req.kind_pages[self.index]
+        held.first, held.pages = 0, list(pages)
+        self.tables[slot, :] = 0
+        self.tables[slot, :len(pages)] = pages
+
+    def rows(self, slots, positions):
+        """The pool row of each (slot, position), through the table."""
+        ps = self.pool.page_size
+        return self.tables[slots, positions // ps] * ps + positions % ps
 
     def trim(self, slot, req):
         """Free the pages wholly behind the window of the request's
@@ -305,17 +377,22 @@ class _CacheKindState:
         if self.kind.window is None:
             return 0
         held = req.kind_pages[self.index]
-        first = self.first_live_page(req.n_prefilled)
-        dead = [j for j in held if j < first]
-        for j in dead:
-            self.pool.free([held.pop(j)])
-            self.tables[slot, j] = 0
-        return len(dead)
+        dead = min(len(held.pages),
+                   self.first_live_page(req.n_prefilled) - held.first)
+        if dead <= 0:
+            return 0
+        self.pool.free(held.pages[:dead])
+        del held.pages[:dead]
+        self.tables[slot, held.first:held.first + dead] = 0
+        held.first += dead
+        return dead
 
     def release(self, slot, req):
+        """The request's references go: a page shared with the trie or
+        another request stays live with theirs."""
         held = req.kind_pages[self.index]
-        self.pool.free(held.values())
-        held.clear()
+        self.pool.free(held.pages)
+        held.first, held.pages = 0, []
         self.tables[slot, :] = 0
 
 
@@ -706,8 +783,8 @@ class _Request:
         self.future = future if future is not None else Future()
         self.target = None        # total-token cap, set at add_request
         self.slot = None
-        self.pages = []           # physical page ids, logical order
-        self.kind_pages = {}      # further cache kinds: {n: {logical: id}}
+        # what it holds of cache kind n (`_CacheKindState` keeps it)
+        self.kind_pages = collections.defaultdict(_HeldPages)
         self.n_prefilled = 0      # kv-written tokens (reset on preempt)
         self.draft_prefilled = 0  # draft-pool valid prefix (speculative)
         self.admit_seq = None     # admission order (preemption picks max)
@@ -775,6 +852,12 @@ class _Request:
         self.trace = None
 
     @property
+    def pages(self):
+        """The first cache kind's physical pages in logical order: that
+        kind's own list, for readers (trie, KV wire, tier)."""
+        return self.kind_pages[0].pages
+
+    @property
     def do_sample(self):
         return self.temperature > 0.0
 
@@ -819,10 +902,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 f"token_budget {self.token_budget} < num_slots "
                 f"{self.num_slots}: every running sequence needs one "
                 "decode token per step")
-        # the model's cache kinds (text/models/serving_protocol.py). The
-        # FIRST keeps every page and is what `pool`, `req.pages` and
-        # `_page_tables` have always been; further kinds (a window
-        # kind's pages are freed behind the window) live in `_extra`
+        # the model's cache kinds (text/models/serving_protocol.py), a
+        # `_CacheKindState` each. The FIRST keeps every page: the trie,
+        # the tier, the KV wire and the draft pool hang on it
         kinds = list(mcfg.cache_kinds())
         if kinds[0].window is not None:
             raise ValueError(
@@ -842,13 +924,18 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             pages_of = [int(cfg.num_pages or worst)] + [worst] * (
                 len(kinds) - 1)
         num_pages = pages_of[0]
-        self.pool = PagePool(num_pages, self.page_size)
-        self._kinds = kinds
-        self._extra = [
-            _CacheKindState(n, kinds[n], pages_of[n], self.page_size,
+        self._caches = [
+            _CacheKindState(n, kind, pages_of[n], self.page_size,
                             self.num_slots, self.pages_per_seq)
-            for n in range(1, len(kinds))]
-        if self._extra:
+            for n, kind in enumerate(kinds)]
+        # plain aliases, no state of their own: what benchmarks/builders/
+        # and tests/benchmarks/ still reach for (ROADMAP C11)
+        self.pool = self._caches[0].pool
+        self._extra = self._caches[1:]
+        # several kinds: what assumes one geometry refuses (below), and
+        # the per-kind counters of `stats` exist
+        self._several = len(kinds) > 1
+        if self._several:
             on = [name for name, v in (
                 ("prefix_cache=True", cfg.prefix_cache),
                 ("kv_tier", cfg.kv_tier),
@@ -880,7 +967,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         cache_dt, self.kv_quantized = _qrt.resolve_kv_dtype(
             cfg.kv_dtype, compute_dt)
         if self.kv_quantized and (
-                self._extra or any(k.head_major for k in kinds)):
+                self._several or any(k.head_major for k in kinds)):
             raise ValueError(
                 f"kv_dtype={cfg.kv_dtype!r}: head-major pools and "
                 "models with several cache kinds keep float pools")
@@ -931,8 +1018,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._kv, self._kv_scales = _fresh_pools()
         self._spec = None  # set below; pool_bytes() reads it
         _KV_POOL_BYTES.labels(dtype=self.kv_dtype).set(self.pool_bytes())
-        self._page_tables = np.zeros(
-            (self.num_slots, self.pages_per_seq), np.int32)
         self._slots = [None] * self.num_slots
         # fused multi-token decode (decode_k > 1): pure-decode ticks go
         # through ONE k-step scan executable; the engine-owned PRNG key
@@ -955,8 +1040,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # FIFO) + optional shared-prefix radix cache over the pool
         self.sched = SLAScheduler(cfg.sla_policy)
         self.hash_block_tokens = int(cfg.hash_block_tokens)
-        self.prefix_cache = (
-            RadixPrefixCache(self.pool, self.page_size,
+        self.prefix_cache = self._caches[0].trie = (
+            RadixPrefixCache(self._caches[0].pool, self.page_size,
                              self.hash_block_tokens)
             if cfg.prefix_cache else None)
         self._admit_counter = itertools.count()
@@ -1001,7 +1086,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._pending_counters = []
         for name in self._counter_names:
             self.stats[name] = 0
-        if self._extra:
+        if self._several:
             self.stats["window_pages_freed"] = 0
             for k in kinds:
                 self.stats[f"{k.name}_pages_live"] = 0
@@ -1228,11 +1313,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       model)."""
         grammar_obj = self._resolve_constraint(grammar, json_schema,
                                                eos_token_id, spec_mode)
-        if self._extra and (prefill_only or kv_import is not None):
+        if self._several and (prefill_only or kv_import is not None):
             raise ValueError(
                 "prefill_only / kv_import: the KV wire carries one page "
                 "geometry; this model has "
-                f"{[k.name for k in self._kinds]} (ROADMAP.md B-I)")
+                f"{[c.kind.name for c in self._caches]} (ROADMAP.md B-I)")
         toks = np.asarray(prompt).reshape(-1)
         if toks.size == 0:
             raise ValueError("empty prompt")
@@ -1240,10 +1325,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             raise ValueError(
                 f"prompt length {toks.size} exceeds max_model_len "
                 f"{self.max_model_len}")
-        if -(-int(toks.size) // self.page_size) > self.pool.num_pages - 1:
+        short = self._pool_too_small(int(toks.size))
+        if short is not None:
             raise ValueError(
-                f"prompt needs more KV pages than the pool holds "
-                f"({self.pool.num_pages - 1})")
+                f"prompt needs more KV pages than the "
+                f"{short.kind.name!r} pool holds "
+                f"({short.pool.num_pages - 1})")
         req = _Request(toks, max_new_tokens, eos_token_id, future,
                        tenant=tenant, priority=priority,
                        ttft_slo_s=ttft_slo_s, temperature=temperature,
@@ -1649,11 +1736,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             new_pages = []
             try:
                 for _ in range(ppb):
-                    new_pages.append(self._alloc_page())
+                    new_pages.append(self._caches[0].alloc())
             except PoolExhausted:
                 # prefetch must never starve the request's own prompt
                 # pages — give back and serve what we have
-                self.pool.free(new_pages)
+                self.prefix_cache.pool.free(new_pages)
                 break
             self._write_imported_pages(new_pages, payload)
             self.prefix_cache.insert(toks[:cached + bt],
@@ -1692,7 +1779,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         if not pages:
             return None
         kv, scales = self._gather_pages(pages)
-        self.pool.free(pages)   # match()'s share refs, returned
+        self.prefix_cache.pool.free(pages)   # match()'s share refs
         self.stats["kv_pages_migrated_out"] = (
             self.stats.get("kv_pages_migrated_out", 0) + cached // bt
             * self.prefix_cache.pages_per_block)
@@ -1788,12 +1875,29 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         prefix cache is busiest. Unwritten slots live only in a
         request's PRIVATE tail pages (shared and trie pages are full by
         construction), so the sum never double-counts."""
-        cap = self.pool.num_live * self.page_size
+        first = self._caches[0]
+        cap = first.pool.num_live * self.page_size
         if not cap:
             return 0.0
-        waste = sum(len(r.pages) * self.page_size - r.n_prefilled
+        waste = sum(first.covered(r) - r.n_prefilled
                     for r in self._slots if r is not None)
         return max(0.0, waste / cap)
+
+    def _page_occupancy(self):
+        """Share of the FULLER page pool in use (what the
+        `kv_page_occupancy` metric and gauge report)."""
+        return max(c.pool.num_live / (c.pool.num_pages - 1)
+                   for c in self._caches)
+
+    def _publish_load(self):
+        """The whole-engine load gauges, after a window or a release:
+        the slots held (a window's frontier rows AND a chunk-prefilling
+        straggler), the pages in use, the tail waste."""
+        live = sum(r is not None for r in self._slots)
+        _LIVE_SLOTS.set(live)
+        _SLOT_OCC.set(live / self.num_slots)
+        _PAGE_OCC.set(self._page_occupancy())
+        _PAGE_FRAG.set(self.kv_fragmentation())
 
     def metrics(self):
         """Live engine view + the process-global serving counters from
@@ -1808,10 +1912,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             "kv_pool_bytes": self.pool_bytes(),
             "slot_occupancy": live / self.num_slots,
             "mean_slot_occupancy": self.mean_occupancy,
-            "kv_page_occupancy":
-                self.pool.num_live / (self.pool.num_pages - 1),
+            "kv_page_occupancy": self._page_occupancy(),
             "kv_fragmentation": self.kv_fragmentation(),
-            "kv_pages_shared": self.pool.num_shared,
+            "kv_pages_shared": self._caches[0].pool.num_shared,
             "prefix_cache": (self.prefix_cache.snapshot()
                              if self.prefix_cache is not None else None),
             "sched": self.sched.snapshot(),
@@ -1862,8 +1965,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             return None
         from .fleet_serving.kv_tier import _TIER_BYTES, _TIER_PAGES
 
-        live = self.pool.num_live
-        per_page = self.pool_bytes() / max(1, self.pool.num_pages)
+        pool = self.prefix_cache.pool
+        live = pool.num_live
+        per_page = self.pool_bytes() / max(1, pool.num_pages)
         _TIER_PAGES.labels(tier="hbm").set(live)
         _TIER_BYTES.labels(tier="hbm").set(int(live * per_page))
         return self.kv_tier.snapshot()
@@ -1963,8 +2067,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self.reseed(self._seed)
         _ABORTS_TOTAL.inc()
         _QUEUE_DEPTH.set(0)
-        _LIVE_SLOTS.set(0)
-        _SLOT_OCC.set(0.0)
+        self._publish_load()
 
     def abort(self, request_id, reason="client", exc=None,
               counted=False):
@@ -1987,10 +2090,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             if req is not None and req.rid == rid:
                 self._release(slot, req)
                 self._resolve_cancel(req, reason, exc, counted=counted)
-                live = sum(r is not None for r in self._slots)
-                _LIVE_SLOTS.set(live)
-                _SLOT_OCC.set(live / self.num_slots if self.num_slots
-                              else 0.0)
+                self._publish_load()
                 return True
         for req in list(self.sched):
             if req.rid == rid:
@@ -2057,10 +2157,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 self._resolve_cancel(req, "deadline")
                 hit = True
         if hit:
-            live = sum(r is not None for r in self._slots)
-            _LIVE_SLOTS.set(live)
-            _SLOT_OCC.set(live / self.num_slots if self.num_slots
-                          else 0.0)
+            self._publish_load()
             _QUEUE_DEPTH.set(len(self.sched))
 
     # ---- brownout (fleet_serving.overload) ----
@@ -2123,16 +2220,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
     # ---- scheduler ----
 
     def _release(self, slot, req):
-        self.pool.free(req.pages)  # shared pages decref; trie keeps its
-        req.pages = []             # own reference, private pages free
-        for ks in self._extra:
-            ks.release(slot, req)
+        for c in self._caches:
+            c.release(slot, req)
         req.n_prefilled = 0
         req.draft_prefilled = 0   # preemption replay re-prefills BOTH pools
         req.cached_prefix = 0
         req.published_blocks = 0
         req.slot = None
-        self._page_tables[slot, :] = 0
         self._slots[slot] = None
         self._slot_gen += 1  # membership changed: staged arrays stale
 
@@ -2180,17 +2274,25 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._preempt(*pick, reason=reason)
         return True
 
-    def _alloc_page(self):
-        """Pool alloc with prefix-cache pressure relief: a dry pool
-        first reclaims LRU trie-only pages before the caller has to
-        preempt anything."""
+    def _grow(self, slot, req, n):
+        """Every cache kind takes the pages that the next `n` positions
+        of `req` need. Returns how many of them EVERY kind's pages then
+        cover: fewer than n when a pool ran dry (what was taken stays
+        with the request; a window narrows to it, `_plan` preempts)."""
         try:
-            return self.pool.alloc()
+            for c in self._caches:
+                c.grow(slot, req, req.n_prefilled, req.n_prefilled + n - 1)
+            return n
         except PoolExhausted:
-            if (self.prefix_cache is not None
-                    and self.prefix_cache.evict(1) > 0):
-                return self.pool.alloc()
-            raise
+            return min(n, min(c.covered(req) for c in self._caches)
+                       - req.n_prefilled)
+
+    def _pool_too_small(self, n_tokens):
+        """The cache kind whose WHOLE pool could not admit a sequence of
+        `n_tokens`, or None."""
+        return next((c for c in self._caches
+                     if c.pages_to_admit(n_tokens, self.token_budget)
+                     > c.pool.num_pages - 1), None)
 
     def _map_prefix(self, req):
         """Match the request's tokens against the radix trie and map
@@ -2248,37 +2350,40 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                        for r in self._slots):
                 return False
         # speculative k-token reservation (docs/SERVING.md): leave one
-        # page of headroom per live frontier slot so a burst of
-        # admissions can't drain the pool to where every verify window
-        # collapses to 1-token widths — admission waits behind the
-        # windows' working set, it never starves (runners finish and
-        # the headroom shrinks with them)
-        headroom = (self._spec.window_headroom()
-                    if self._spec is not None else 0)
-        # (b) pool provably short even in the BEST case: the trie can
-        # map at most resident_pages into the prompt and reclaim at
-        # most resident_pages more, so free + victims + 2·resident <
-        # prompt pages is infeasible regardless of what match() finds —
-        # O(slots) with no trie walk
-        # (a') a further cache kind cannot hold the first chunk's pages:
-        # wait for runners to finish or to move their windows on (their
-        # pages are not this request's to take)
-        for ks in self._extra:
-            req.kind_pages.setdefault(ks.index, {})
-            span = len(req.tokens) if ks.kind.window is None else min(
-                len(req.tokens), ks.kind.window + self.token_budget)
-            if ks.pool.num_free < -(-span // self.page_size) + 1:
+        # page of headroom per live frontier slot (a window's k tokens
+        # mostly fit the slot's tail page; one fresh page covers the
+        # spill) so a burst of admissions can't drain the pool to where
+        # every verify window collapses to 1-token widths — admission
+        # waits behind the windows' working set, it never starves
+        # (runners finish and the headroom shrinks with them)
+        headroom = 0 if self._spec is None else sum(
+            r is not None and r.n_prefilled == len(r.tokens) - 1
+            for r in self._slots)
+        # (b) a pool provably short even in the BEST case. A full kind:
+        # the trie can map at most resident_pages into the prompt and
+        # reclaim at most resident_pages more, so free + victims +
+        # 2·resident < prompt pages is infeasible whatever match()
+        # finds — O(slots), no trie walk. A window kind: wait for
+        # runners to finish or to move their windows on (their pages
+        # are not this request's to take)
+        n_tokens = len(req.tokens)
+        for c in self._caches:
+            need_all = c.pages_to_admit(n_tokens, self.token_budget)
+            free = c.pool.num_free - headroom
+            if free >= need_all:
+                continue
+            if c.kind.window is not None:
                 return False
-        need_all = -(-len(req.tokens) // self.page_size)
-        if self.pool.num_free - headroom < need_all:
             now = _time.perf_counter()
-            avail = self.pool.num_free - headroom + sum(
-                len(r.pages) for r in self._slots if r is not None
-                and self.sched.less_urgent(r, req, now))
-            resident = (self.prefix_cache.resident_pages
-                        if self.prefix_cache is not None else 0)
+            avail = free + sum(
+                len(r.kind_pages[c.index]) for r in self._slots
+                if r is not None and self.sched.less_urgent(r, req, now))
+            resident = c.trie.resident_pages if c.trie is not None else 0
             if avail + 2 * resident < need_all:
                 return False
+        # the first kind: the trie maps into it, the wire writes into
+        # it, and a victim's pages of it are what a preemption frees
+        first = self._caches[0]
         # an imported request's prompt KV arrives in its payload — a
         # trie mapping on top would alias pages the import must write
         pages = (self._map_prefix(req)
@@ -2287,30 +2392,27 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
         def give_up():
             if pages:
-                self.pool.free(pages)
+                first.pool.free(pages)
             req.cached_prefix = 0
             return False
 
         # feasibility FIRST: preempting a runner destroys its generated
         # progress, so don't start evicting until a slot AND enough
-        # reclaimable pages can possibly exist. `reclaimable` is an
+        # reclaimable pages can possibly exist. The sum is an
         # upper bound (a page shared by two victims counts twice) — the
         # loops below still give up cleanly when eviction falls short.
         # Skipped entirely on the uncontended fast path (free slot +
         # pool already covers the prompt): the trie walk is O(nodes).
-        need = (-(-len(req.tokens) // self.page_size) - len(pages)
-                + headroom)
-        if None not in self._slots or self.pool.num_free < need:
+        need = (first.pages_to_admit(n_tokens, self.token_budget)
+                - len(pages) + headroom)
+        if None not in self._slots or first.pool.num_free < need:
             now = _time.perf_counter()
             victims = [r for r in self._slots if r is not None
                        and self.sched.less_urgent(r, req, now)]
             if None not in self._slots and not victims:
                 return give_up()
-            reclaimable = self.pool.num_free + sum(
-                len(r.pages) for r in victims)
-            if self.prefix_cache is not None:
-                reclaimable += self.prefix_cache.reclaimable_pages()
-            if reclaimable < need:
+            if first.available() + sum(
+                    len(r.pages) for r in victims) < need:
                 return give_up()
         # a slot: free one, or preempt a strictly-less-urgent runner
         if None not in self._slots:
@@ -2319,10 +2421,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 return give_up()
         # the prompt's remaining pages must fit (head-of-class
         # blocking: a short prompt never jumps its own class's queue)
-        while self.pool.num_free < need:
-            short = need - self.pool.num_free
-            if (self.prefix_cache is not None
-                    and self.prefix_cache.evict(short) > 0):
+        while first.pool.num_free < need:
+            short = need - first.pool.num_free
+            if first.trie is not None and first.trie.evict(short) > 0:
                 continue
             if not self._preempt_one(None, worse_than=req,
                                      reason="priority"):
@@ -2330,19 +2431,19 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         slot = self._slots.index(None)
         req.slot = slot
         req.admit_seq = next(self._admit_counter)
-        req.pages = list(pages)
         req.n_prefilled = req.cached_prefix
+        held = pages
         if req._kv_import is not None:
             # disaggregated hand-off: write the streamed pages and join
             # at the frontier. The payload is CONSUMED — a later
             # preemption replay re-prefills the prompt the ordinary way
             # (greedy replay reproduces the identical continuation).
             imp, req._kv_import = req._kv_import, None
-            req.pages = [self._alloc_page()
-                         for _ in range(imp.num_pages)]
-            self._write_imported_pages(req.pages, imp)
+            held = [first.alloc() for _ in range(imp.num_pages)]
+            self._write_imported_pages(held, imp)
             req.n_prefilled = imp.n_prefilled
             req.trace.stamp("kv_import")
+        first.adopt(slot, req, held)
         # mirrored draft pool: a shared page's draft rows were written
         # by the publishing request's own catch-up (same page ids, same
         # tokens, same draft model), so the mapped prefix is draft-valid
@@ -2353,8 +2454,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         req.draft_prefilled = (req.cached_prefix
                                if self._spec is not None else 0)
         req.published_blocks = req.cached_prefix // self.hash_block_tokens
-        self._page_tables[slot, :] = 0
-        self._page_tables[slot, :len(req.pages)] = req.pages
         self._slots[slot] = req
         self._slot_gen += 1  # membership changed: staged arrays stale
         if self.prefix_cache is not None:
@@ -2446,23 +2545,14 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 alloc[slot] = take
             ok = True
             for slot, req in active:
-                last = req.n_prefilled + alloc[slot] - 1
-                try:
-                    while last // self.page_size >= len(req.pages):
-                        page = self._alloc_page()
-                        self._page_tables[slot, len(req.pages)] = page
-                        req.pages.append(page)
-                    for ks in self._extra:
-                        ks.grow(slot, req, req.n_prefilled, last)
-                except PoolExhausted:
-                    # the victim may be no MORE urgent than the growing
+                if self._grow(slot, req, alloc[slot]) < alloc[slot]:
+                    # a dry pool. The victim may be no MORE urgent than the growing
                     # sequence: a BATCH job's page growth must never
                     # evict an INTERACTIVE runner (equal urgency keeps
                     # the pre-fleet preempt-youngest baseline)
                     if not self._preempt_one(req, worse_than=req,
                                              allow_equal=True):
-                        kept = -(-len(req.tokens) // self.page_size)
-                        if (kept <= self.pool.num_pages - 1
+                        if (self._pool_too_small(len(req.tokens)) is None
                                 and any(r is not None and r is not req
                                         for r in self._slots)):
                             # every other runner outranks req: req
@@ -2500,7 +2590,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         completes, and per-request greedy/sampled outputs are
         schedule-invariant, so nothing observable changes per request."""
         out = self._step()
-        if self._extra:
+        if self._several:
             self._trim_windows()
         return out
 
@@ -2511,13 +2601,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         freed = 0
         for slot, req in enumerate(self._slots):
             if req is not None:
-                for ks in self._extra:
-                    freed += ks.trim(slot, req)
+                for c in self._caches:
+                    freed += c.trim(slot, req)
         self.stats["window_pages_freed"] += freed
-        self.stats[f"{self._kinds[0].name}_pages_live"] = \
-            self.pool.num_live
-        for ks in self._extra:
-            self.stats[f"{ks.kind.name}_pages_live"] = ks.pool.num_live
+        for c in self._caches:
+            self.stats[f"{c.kind.name}_pages_live"] = c.pool.num_live
 
     def _step(self):
         with _trace_span("llm_engine.admit",
@@ -2571,12 +2659,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         with _trace_span("llm_engine.reserve",
                          rows=len(active)) as span:
             window = self._reserve_window(active)
-            if self._extra:
-                span.set(full_pages=self.pool.num_live,
-                         window_pages=sum(ks.pool.num_live
-                                          for ks in self._extra),
-                         window_pages_freed=self.stats[
-                             "window_pages_freed"])
+            if self._several:   # `full_pages`, `window_pages`, …
+                span.set(window_pages_freed=self.stats[
+                    "window_pages_freed"], **{
+                        f"{c.kind.name}_pages": c.pool.num_live
+                        for c in self._caches})
         if window is None:
             return None
         (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst, gtrans,
@@ -2614,68 +2701,45 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         """Page reservation and host array fill of one fused window:
         the arguments of the dispatch, or None when the pool cannot
         cover a 1-token window."""
-        ps = self.page_size
         k = self.decode_k
 
-        def pages_needed(w):
-            tot = 0
-            for _, req in active:
-                writes = min(w, req.target - len(req.tokens))
-                last = req.n_prefilled + writes - 1
-                tot += max(0, last // ps + 1 - len(req.pages))
-            return tot
+        # a dry pool's `alloc` reclaims the trie's LRU pages, so they
+        # count (an upper bound: a short row spills further below)
+        avail = [c.available() for c in self._caches]
 
-        def extra_fits(w):
-            """Every further cache kind covers a window of w."""
-            for ks in self._extra:
+        def fits(w):
+            """Every cache kind covers a window of w."""
+            for c, free in zip(self._caches, avail):
                 need = 0
                 for _, req in active:
                     writes = min(w, req.target - len(req.tokens))
-                    need += len(ks.missing(req, req.n_prefilled,
-                                           req.n_prefilled + writes - 1))
-                if need > ks.pool.num_free:
+                    need += c.missing(req, req.n_prefilled,
+                                      req.n_prefilled + writes - 1)
+                if need > free:
                     return False
             return True
 
-        avail = self.pool.num_free
-        if self.prefix_cache is not None:
-            avail += self.prefix_cache.reclaimable_pages()
         # brownout window cap: a smaller w rides the `rem` runtime
         # argument of the SAME k-scan executable — degrading the window
         # never recompiles (overload.BrownoutController L3)
         cap = self._brownout.get("decode_k_cap")
         w = k if cap is None else max(1, min(k, int(cap)))
-        while w > 1 and (pages_needed(w) > avail or not extra_fits(w)):
+        while w > 1 and not fits(w):
             w -= 1        # spill: the largest window the pools cover
-        if pages_needed(w) > avail or not extra_fits(w):
+        if not fits(w):
             return None   # not even 1 token/row: single tick preempts
 
-        # reserve the window's pages up front (_alloc_page evicts LRU
-        # trie pages under pressure; reclaimable was an upper bound, so
-        # a short row spills further instead of failing the window)
-        rem_arg = {}
-        for slot, req in active:
-            want = min(w, req.target - len(req.tokens))
-            last = req.n_prefilled + want - 1
-            try:
-                while last // ps >= len(req.pages):
-                    page = self._alloc_page()
-                    self._page_tables[slot, len(req.pages)] = page
-                    req.pages.append(page)
-                for ks in self._extra:    # `extra_fits(w)` held above
-                    ks.grow(slot, req, req.n_prefilled, last)
-                writes = want
-            except PoolExhausted:
-                writes = min(want,
-                             len(req.pages) * ps - req.n_prefilled)
-                if writes < 1:
-                    return None
-            rem_arg[slot] = writes
-
+        # reserve the window's pages up front
         S = self.num_slots
+        rem = np.zeros((S,), np.int32)
+        for slot, req in active:
+            rem[slot] = self._grow(
+                slot, req, min(w, req.target - len(req.tokens)))
+            if rem[slot] < 1:
+                return None
+
         tok0 = np.zeros((S,), np.int32)
         pos0 = np.zeros((S,), np.int32)
-        rem = np.zeros((S,), np.int32)
         fin0 = np.ones((S,), bool)        # empty slots: finished
         eos = np.full((S,), -1, np.int32)
         temps = np.zeros((S,), np.float32)
@@ -2685,7 +2749,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         for slot, req in active:
             tok0[slot] = req.tokens[-1]
             pos0[slot] = req.n_prefilled
-            rem[slot] = rem_arg[slot]
             fin0[slot] = False
             if req.eos is not None:
                 eos[slot] = int(req.eos)
@@ -2726,7 +2789,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                         or len(req.tokens) >= req.target):
                     done = True   # in-executable masking already
                     break         # padded the rest of the window
-            if self._extra and emitted:
+            if self._several and emitted:
                 self._note_attended(req.n_prefilled, 1, steps=emitted)
             req.n_prefilled += emitted
             total += emitted
@@ -2746,23 +2809,27 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         _TOKENS_TOTAL.labels(phase="decode").inc(total)
         _TOK_PER_DISPATCH.set(total)
         _QUEUE_DEPTH.set(len(self.waiting))
-        # whole-engine load — `active` is only the window's frontier
-        # rows; a chunk-prefilling straggler still occupies its slot
-        live = sum(r is not None for r in self._slots)
-        _LIVE_SLOTS.set(live)
-        _SLOT_OCC.set(live / self.num_slots)
-        _PAGE_OCC.set(self.pool.num_live / (self.pool.num_pages - 1))
-        _PAGE_FRAG.set(self.kv_fragmentation())
+        self._publish_load()
         return finished
 
     def _step_tables(self):
         """The page tables a step is dispatched with: the one table
         [S, MP], or with several cache kinds [kinds, S, MP] in the
         model's order (serving_protocol.py)."""
-        if not self._extra:
-            return self._page_tables
-        return np.stack([self._page_tables]
-                        + [ks.tables for ks in self._extra])
+        if not self._several:
+            return self._caches[0].tables
+        return np.stack([c.tables for c in self._caches])
+
+    def _write_index(self, slots, positions, kv_lens):
+        """The pool row each row of a step writes its K/V to, [T] (0,
+        the trash page's, where `kv_lens` is 0: padding), or with
+        several cache kinds [kinds, T]: the same position through each
+        kind's own table."""
+        rows = np.flatnonzero(kv_lens)
+        widx = np.zeros((len(self._caches), len(kv_lens)), np.int32)
+        for c in self._caches:
+            widx[c.index, rows] = c.rows(slots[rows], positions[rows])
+        return widx if self._several else widx[0]
 
     def _note_attended(self, first_pos, rows, steps=1):
         """The least K/V a step must read, as positions a cache kind
@@ -2772,7 +2839,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         attend once, however the kernel blocks its rows: every earlier
         position, or from the first row's window on. `steps` > 1: that
         many steps of one row each (a fused window's iterations)."""
-        for kind in self._kinds:
+        for kind in (c.kind for c in self._caches):
             if steps == 1:
                 lo = 0 if kind.window is None else max(
                     0, first_pos - kind.window + 1)
@@ -2915,7 +2982,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
         tok = np.zeros((T,), np.int32)
         pos = np.zeros((T,), np.int32)
-        widx = np.zeros((T,), np.int32)   # 0 → trash page, row 0
         klen = np.zeros((T,), np.int32)   # 0 → padding token
         sid_np = np.zeros((T,), np.int32)
         if staged is not None:
@@ -2927,8 +2993,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 sid_np[row] = slot
                 tok[row] = req.tokens[p]
                 pos[row] = p
-                widx[row] = (req.pages[p // self.page_size]
-                             * self.page_size + p % self.page_size)
                 klen[row] = p + 1
                 if req.num_generated == 0:
                     req.trace.stamp("first_decode_dispatch")
@@ -2936,7 +3000,6 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         else:
             from ..distributed import mesh as mesh_mod
 
-            sid = sid_np
             # per-SLOT sampling frontier: the vocab head only runs on
             # these gathered rows (stale slots point at row 0; logits
             # ignored)
@@ -2948,9 +3011,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                     p = req.n_prefilled + k
                     tok[i] = req.tokens[p]
                     pos[i] = p
-                    sid[i] = slot
-                    widx[i] = (req.pages[p // self.page_size]
-                               * self.page_size + p % self.page_size)
+                    sid_np[i] = slot
                     klen[i] = p + 1
                     if p == len(req.tokens) - 1:
                         sample_idx[slot] = i
@@ -2966,20 +3027,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             # committed like the staged copies: a committed/uncommitted
             # flip at one arg position would cost a second executable
             sharding = mesh_mod.named_sharding()
-            sid = jax.device_put(sid, sharding)
+            sid = jax.device_put(sid_np, sharding)
             sample_idx = jax.device_put(sample_idx, sharding)
-        if self._extra:
-            # a write index a cache kind: the same position through
-            # each kind's own page table
-            rows = np.flatnonzero(klen)
-            slots = sid_np[rows]
-            page, off = pos[rows] // self.page_size, \
-                pos[rows] % self.page_size
-            widx = np.stack([widx] + [np.zeros_like(widx)
-                                      for _ in self._extra])
-            for ks in self._extra:
-                widx[ks.index, rows] = (
-                    ks.tables[slots, page] * self.page_size + off)
+        widx = self._write_index(sid_np, pos, klen)
         return (plan, i, tok, pos, sid, widx, klen, sample_idx,
                 sample_slots)
 
@@ -3017,14 +3067,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._note_launches(self._step_fn)
         _STEPS_TOTAL.inc()
         _DISPATCHES.inc()
-        # a ragged-window straggler tick covers only the PREFILL rows —
-        # its plan must not overwrite the window's whole-engine load
-        # gauges with straggler-only values (7 decoding rows + 1
-        # straggler would read as 1/8 occupancy), and the window's
-        # tokens-per-dispatch amortization stamp stays unless this
-        # tick actually decoded something
-        live_now = (len(plan) if only_slots is None
-                    else sum(r is not None for r in self._slots))
+        # a ragged-window straggler tick covers only the PREFILL rows:
+        # the load gauges count the whole engine's slots, and the
+        # window's tokens-per-dispatch amortization stamp stays unless
+        # this tick actually decoded something
+        live_now = sum(r is not None for r in self._slots)
         if only_slots is None or sample_slots:
             _TOK_PER_DISPATCH.set(len(sample_slots))
         # the flat-budget split: one decode token per sampling frontier,
@@ -3034,11 +3081,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         _QUEUE_DEPTH.set(len(self.waiting))
         _LIVE_SLOTS.set(live_now)
         _SLOT_OCC.set(live_now / self.num_slots)
-        _PAGE_OCC.set(self.pool.num_live / (self.pool.num_pages - 1))
+        _PAGE_OCC.set(self._page_occupancy())
 
         finished = []
         for slot, req, take in plan:
-            if self._extra:
+            if self._several:
                 self._note_attended(req.n_prefilled, take)
             req.n_prefilled += take
             if req.n_prefilled >= len(req.tokens) - 1:

@@ -63,10 +63,9 @@ import jax.numpy as jnp
 from ..observability import metrics as _obs
 from ..observability.tracing import trace_span as _trace_span
 from .llm_engine import (
-    _DISPATCHES, _FUSED_STEPS, _LIVE_SLOTS, _PAGE_FRAG, _PAGE_OCC,
-    _QUEUE_DEPTH, _SLOT_OCC, _STEPS_TOTAL, _TOK_PER_DISPATCH,
-    _TOKENS_TOTAL, _TTFT_SECONDS, PoolExhausted, _CompiledPagedStep,
-    _CompiledStepBase,
+    _DISPATCHES, _FUSED_STEPS, _QUEUE_DEPTH, _STEPS_TOTAL,
+    _TOK_PER_DISPATCH, _TOKENS_TOTAL, _TTFT_SECONDS,
+    _CompiledPagedStep, _CompiledStepBase,
 )
 
 __all__ = ["SpeculativeDecoder"]
@@ -271,17 +270,6 @@ class SpeculativeDecoder:
         return int(sum(int(a.nbytes) for a in self._kv)
                    + sum(int(s.nbytes) for s in self._kv_scales))
 
-    def window_headroom(self):
-        """Pages admission should leave free for the NEXT verify
-        window: one per live frontier slot (the window's k-token
-        reservation typically fits the slot's current tail page; one
-        fresh page covers the spill). Keeps a burst of admissions from
-        draining the pool to the point every window collapses to
-        1-token widths (docs/SERVING.md)."""
-        return sum(
-            1 for r in self.engine._slots
-            if r is not None and r.n_prefilled == len(r.tokens) - 1)
-
     def reset_pools(self):
         """abort_all path: the donated draft pytree may be consumed by
         a dispatch that died — re-zero (the engine re-creates the
@@ -309,7 +297,6 @@ class SpeculativeDecoder:
         from ..distributed import mesh as mesh_mod
 
         eng = self.engine
-        ps = eng.page_size
         T = self._draft_T
         sharding = mesh_mod.named_sharding()
         while True:
@@ -320,7 +307,6 @@ class SpeculativeDecoder:
             tok = np.zeros((T,), np.int32)
             pos = np.zeros((T,), np.int32)
             sid = np.zeros((T,), np.int32)
-            widx = np.zeros((T,), np.int32)
             klen = np.zeros((T,), np.int32)
             i = 0
             took = {}
@@ -332,15 +318,15 @@ class SpeculativeDecoder:
                     tok[i] = req.tokens[p]
                     pos[i] = p
                     sid[i] = slot
-                    widx[i] = (req.pages[p // ps] * ps + p % ps)
                     klen[i] = p + 1
                     i += 1
                 took[slot] = take
                 if i == T:
                     break
             _, (self._kv, self._kv_scales, eng._key) = self._prefill_fn(
-                tok, pos, jax.device_put(sid, sharding), widx,
-                eng._page_tables, klen,
+                tok, pos, jax.device_put(sid, sharding),
+                eng._write_index(sid, pos, klen), eng._step_tables(),
+                klen,
                 jax.device_put(np.zeros((1,), np.int32), sharding),
                 (self._kv, self._kv_scales, eng._key))
             eng._note_launches(self._prefill_fn)
@@ -358,7 +344,6 @@ class SpeculativeDecoder:
         narrows a row's width (down to 0: verify-only plain decode for
         that row) instead of re-tracing anything."""
         eng = self.engine
-        ps = eng.page_size
         k = self.k
         S = eng.num_slots
 
@@ -375,17 +360,9 @@ class SpeculativeDecoder:
         for slot, req in frontier:
             w = min(0 if req.spec_off else k_eff,
                     req.target - len(req.tokens))
-            last = req.n_prefilled + w
-            try:
-                while last // ps >= len(req.pages):
-                    page = eng._alloc_page()
-                    eng._page_tables[slot, len(req.pages)] = page
-                    req.pages.append(page)
-            except PoolExhausted:
-                covered = len(req.pages) * ps - 1 - req.n_prefilled
-                if covered < 0:
-                    return None   # frontier write itself has no page
-                w = min(w, covered)
+            w = eng._grow(slot, req, w + 1) - 1
+            if w < 0:
+                return None   # frontier write itself has no page
             width[slot] = w
 
         # draft catch-up (prompt replay / post-acceptance lag)
@@ -444,7 +421,7 @@ class SpeculativeDecoder:
                 d_emits, (self._kv, self._kv_scales, eng._key) = \
                     self._propose_fn(
                         tok_p, pos0, rem_p, fin_p, eos, temps, tops,
-                        streams, lag, tok0, eng._page_tables,
+                        streams, lag, tok0, eng._step_tables(),
                         (self._kv, self._kv_scales, eng._key))
                 # row s's proposals start after its lag replay:
                 # drafts[s, j] = emits[lag_s + j, s] (device gather —
@@ -465,7 +442,7 @@ class SpeculativeDecoder:
                     self._verify_fn(
                         tok0, pos0, drafts, wid, rem, fin_v, eos,
                         temps, tops, streams, gst, gtrans, gmask,
-                        eng._page_tables,
+                        eng._step_tables(),
                         (eng._kv, eng._kv_scales, eng._key))
                 emits = np.asarray(emits)  # [k+1, S]: the host sync
                 # already materialized by the sync above — the host
@@ -550,11 +527,5 @@ class SpeculativeDecoder:
         _TOKENS_TOTAL.labels(phase="decode").inc(total)
         _TOK_PER_DISPATCH.set(total)
         _QUEUE_DEPTH.set(len(eng.waiting))
-        # whole-engine load, not just the window's frontier rows — a
-        # chunk-prefilling straggler still occupies its slot
-        live = sum(r is not None for r in eng._slots)
-        _LIVE_SLOTS.set(live)
-        _SLOT_OCC.set(live / S)
-        _PAGE_OCC.set(eng.pool.num_live / (eng.pool.num_pages - 1))
-        _PAGE_FRAG.set(eng.kv_fragmentation())
+        eng._publish_load()
         return finished

@@ -29,8 +29,8 @@ proposal positions, and a proposal token the grammar masks simply
 fails exact-match and truncates acceptance there.
 
 Duck-typed to the `SpeculativeDecoder` surface the engine drives
-(`try_window` / `window_headroom` / `release_pools` / `reset_pools` /
-`pool_bytes` / `.k`), reporting 0 pool bytes — brownout L2 has
+(`try_window` / `release_pools` / `reset_pools` / `pool_bytes` /
+`.k`), reporting 0 pool bytes — brownout L2 has
 nothing to release and preemption owes no draft replay
 (`draft_prefilled` is dead weight here).
 """
@@ -85,14 +85,6 @@ class NgramSpeculator:
     def pool_bytes(self):
         return 0
 
-    def window_headroom(self):
-        """Same admission headroom contract as the draft decoder: one
-        free page per live frontier slot so the next verify window's
-        k-token reservation doesn't collapse to width 0."""
-        return sum(
-            1 for r in self.engine._slots
-            if r is not None and r.n_prefilled == len(r.tokens) - 1)
-
     def reset_pools(self):
         pass                      # no draft pool to re-zero
 
@@ -129,14 +121,11 @@ class NgramSpeculator:
         `SpeculativeDecoder.try_window`, minus every draft-model leg
         (no catch-up, no propose dispatch, no device gather)."""
         from ..llm_engine import (
-            _DISPATCHES, _FUSED_STEPS, _LIVE_SLOTS, _PAGE_FRAG,
-            _PAGE_OCC, _QUEUE_DEPTH, _SLOT_OCC, _STEPS_TOTAL,
+            _DISPATCHES, _FUSED_STEPS, _QUEUE_DEPTH, _STEPS_TOTAL,
             _TOK_PER_DISPATCH, _TOKENS_TOTAL, _TTFT_SECONDS,
-            PoolExhausted,
         )
 
         eng = self.engine
-        ps = eng.page_size
         k = self.k
         S = eng.num_slots
 
@@ -149,17 +138,9 @@ class NgramSpeculator:
             props = ([] if req.spec_off or not k_eff
                      else self._propose(req))
             w = min(len(props), k_eff, req.target - len(req.tokens))
-            last = req.n_prefilled + w
-            try:
-                while last // ps >= len(req.pages):
-                    page = eng._alloc_page()
-                    eng._page_tables[slot, len(req.pages)] = page
-                    req.pages.append(page)
-            except PoolExhausted:
-                covered = len(req.pages) * ps - 1 - req.n_prefilled
-                if covered < 0:
-                    return None   # frontier write itself has no page
-                w = min(w, covered)
+            w = eng._grow(slot, req, w + 1) - 1
+            if w < 0:
+                return None   # frontier write itself has no page
             width[slot] = w
             proposals[slot] = props[:w]
 
@@ -199,7 +180,7 @@ class NgramSpeculator:
                     self._verify_fn(
                         tok0, pos0, drafts, wid, rem, fin_v, eos,
                         temps, tops, streams, gst, gtrans, gmask,
-                        eng._page_tables,
+                        eng._step_tables(),
                         (eng._kv, eng._kv_scales, eng._key))
                 emits = np.asarray(emits)  # [k+1, S]: the host sync
         except Exception as e:
@@ -264,9 +245,5 @@ class NgramSpeculator:
         _TOKENS_TOTAL.labels(phase="decode").inc(total)
         _TOK_PER_DISPATCH.set(total)
         _QUEUE_DEPTH.set(len(eng.waiting))
-        live = sum(r is not None for r in eng._slots)
-        _LIVE_SLOTS.set(live)
-        _SLOT_OCC.set(live / S)
-        _PAGE_OCC.set(eng.pool.num_live / (eng.pool.num_pages - 1))
-        _PAGE_FRAG.set(eng.kv_fragmentation())
+        eng._publish_load()
         return finished

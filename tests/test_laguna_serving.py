@@ -171,22 +171,24 @@ def test_the_window_pool_frees_behind_the_window():
     more than window / page + 2 window pages, while the full pool keeps
     a page every 16 positions; both empty at the end."""
     eng = _engine(num_pages={"full": 20, "window": 7})
+    full, window = eng._caches
     req = eng.add_request(np.arange(40) % 250, max_new_tokens=56)
     most, full_most = 0, 0
     while eng.has_work():
         eng.step()
         if eng._slots[0] is not None:
             most = max(most, len(req.kind_pages[1]))
-            full_most = max(full_most, len(req.pages))
-            live = sorted(req.kind_pages[1])
+            full_most = max(full_most, len(req.kind_pages[0]))
+            live = list(req.kind_pages[1])
             # what is held is the tail of the context, and the table
             # reads 0 (the trash page) behind it
             assert live == list(range(live[0], live[-1] + 1))
-            assert not eng._extra[0].tables[0, :live[0]].any()
+            assert not window.tables[0, :live[0]].any()
+            assert window.tables[0, live[0]:live[-1] + 1].all()
     assert len(req.future.result()) == 96
     assert most <= 16 // 16 + 2 and full_most == 6
     assert eng.stats["window_pages_freed"] >= 3
-    assert eng.pool.num_live == 0 and eng._extra[0].pool.num_live == 0
+    assert full.pool.num_live == 0 and window.pool.num_live == 0
     assert eng.stats["full_pages_live"] == 0
 
 
@@ -201,13 +203,12 @@ def test_preemption_releases_both_pools_and_replays_the_same_tokens():
     got = [tight.add_request(p, max_new_tokens=40) for p in prompts]
     while tight.has_work():
         tight.step()
-        tight.pool.assert_consistent()
-        tight._extra[0].pool.assert_consistent()
+        for c in tight._caches:
+            c.pool.assert_consistent()
     assert tight.stats["preemptions"] > 0
     for a, b in zip(want, got):
         assert np.array_equal(a.future.result(), b.future.result())
-    assert tight.pool.num_live == 0
-    assert tight._extra[0].pool.num_live == 0
+    assert [c.pool.num_live for c in tight._caches] == [0, 0]
 
 
 @pytest.mark.parametrize("kw,word", [
@@ -240,7 +241,7 @@ def test_two_budgets_size_two_pools():
         kv_dtype="float32", num_slots=2, max_model_len=64)
     assert ecfg.num_pages == {"full": 11, "window": 6}
     eng = LLMEngine(LagunaForCausalLM(mc), ecfg)
-    assert eng.pool.num_pages == 11 and eng._extra[0].pool.num_pages == 6
+    assert [c.pool.num_pages for c in eng._caches] == [11, 6]
     shapes = {tuple(p.shape) for p in eng._kv}
     assert shapes == {(11, 2, 16, 16), (6, 2, 16, 16)}     # head-major
     assert eng.pool_bytes() == 11 * per["full"] + 6 * per["window"]
@@ -266,7 +267,8 @@ def test_gpt_answers_the_protocol_with_one_kind_and_keeps_its_layout():
     assert model.compute_dtype() == model.gpt.wte.weight._value.dtype
     eng = LLMEngine(model, LLMEngineConfig(num_slots=2, num_pages=9,
                                            decode_k=4))
-    assert eng._extra == [] and eng._step_tables() is eng._page_tables
+    (cache,) = eng._caches
+    assert eng._step_tables() is cache.tables
     assert {tuple(p.shape) for p in eng._kv} == {(9, 16, 4, 32)}
     req = eng.add_request(np.arange(10), max_new_tokens=6)
     while eng.has_work():

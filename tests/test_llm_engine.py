@@ -15,10 +15,12 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import inference
 from paddle_tpu.inference.llm_engine import (
-    LLMEngine, LLMEngineConfig, PagePool, PoolExhausted)
+    LLMEngine, LLMEngineConfig, PagePool, PoolExhausted, _CacheKindState,
+    _Request)
 from paddle_tpu.nn import functional as F
 from paddle_tpu.text.models import GPTForCausalLM
 from paddle_tpu.text.models.gpt import gpt_tiny
+from paddle_tpu.text.models.serving_protocol import CacheKind
 
 pytestmark = pytest.mark.serving
 
@@ -508,6 +510,177 @@ def test_page_pool_alloc_free_invariants():
     pool.free(pages[2:])
     pool.assert_consistent()
     assert pool.num_free == 4 and pool.num_live == 0
+
+
+# --------------------------------------------------------------------
+# the page bookkeeping of one cache kind (`_CacheKindState`)
+# --------------------------------------------------------------------
+
+_WINDOWS = pytest.mark.parametrize(
+    "window", [None, 16, 48], ids=["full", "window16", "window48"])
+
+
+def _kind_state(window, num_pages=50, slots=2, pages_per_seq=24):
+    kind = CacheKind("kv", (0,), 2, 16, window, False)
+    return _CacheKindState(0, kind, num_pages, 16, slots, pages_per_seq)
+
+
+def _fake_request():
+    return _Request([1, 2, 3], 1, None, None)
+
+
+def _must_hold(window, first, last, page=16):
+    """Brute force: the logical pages that hold a position which the
+    queries first … last read."""
+    lo = 0 if window is None else max(0, first - window + 1)
+    return {p // page for p in range(lo, last + 1)}
+
+
+@_WINDOWS
+def test_kind_state_holds_what_the_queries_read(window):
+    """Chunks of 1 … 40 positions as the engine feeds them (grow for the
+    chunk, write it, trim at the boundary), against the brute-force set
+    of pages, on two slots at once."""
+    ks = _kind_state(window)
+    rng = np.random.default_rng(3)
+    reqs = [_fake_request(), _fake_request()]
+    allocated = 0
+    while min(r.n_prefilled for r in reqs) < 300:
+        slot = int(rng.integers(0, 2))
+        req = reqs[slot]
+        held = req.kind_pages[0]
+        first = req.n_prefilled
+        last = first + int(rng.integers(1, 41)) - 1
+        need = _must_hold(window, first, last)
+        lack = len(need - set(held))
+        assert ks.missing(req, first, last) == lack
+        live = ks.pool.num_live
+        ks.grow(slot, req, first, last)
+        allocated += lack
+        assert ks.pool.num_live == live + lack
+        assert need <= set(held)
+        assert list(held) == list(range(held.first,
+                                        held.first + len(held)))
+        assert ks.covered(req) == (max(held) + 1) * 16 > last
+        # the table maps what is held, and nothing else
+        row = ks.tables[slot]
+        assert [int(row[j]) for j in held] == held.pages
+        assert np.count_nonzero(row) == len(held)
+        assert np.array_equal(
+            ks.rows(np.array([slot, slot]), np.array([first, last])),
+            [held.pages[first // 16 - held.first] * 16 + first % 16,
+             held.pages[last // 16 - held.first] * 16 + last % 16])
+        req.n_prefilled = last + 1
+        before = set(held)
+        freed = ks.trim(slot, req)
+        # what goes lies wholly behind what the next query reads
+        lo = min(_must_hold(window, req.n_prefilled, req.n_prefilled))
+        assert set(held) == {j for j in before if j >= lo}
+        assert freed == len(before) - len(held)
+        assert np.count_nonzero(ks.tables[slot]) == len(held)
+        if window is None:
+            assert freed == 0 and held.first == 0
+        else:
+            assert len(held) <= window // 16 + 2
+        ks.pool.assert_consistent()
+    assert ks.pool.num_live == sum(len(r.kind_pages[0]) for r in reqs)
+    for slot, req in enumerate(reqs):
+        ks.release(slot, req)
+        assert len(req.kind_pages[0]) == 0 and ks.covered(req) == 0
+    assert ks.pool.num_live == 0 and not ks.tables.any()
+    assert allocated > (20 if window is None else 40)
+
+
+@_WINDOWS
+def test_kind_state_covered_asks_for_nothing_and_allocates_nothing(
+        window):
+    ks = _kind_state(window)
+    req = _fake_request()
+    ks.grow(0, req, 0, 70)
+    req.n_prefilled = 71
+    ks.trim(0, req)
+    live, table = ks.pool.num_live, ks.tables.copy()
+    ks.pool.alloc = None            # any allocation would be a TypeError
+    for last in range(71, 80):      # the tail page covers 64 … 79
+        assert ks.missing(req, 71, last) == 0
+        ks.grow(0, req, 71, last)
+    assert ks.missing(req, 71, 80) == 1
+    assert ks.pool.num_live == live and np.array_equal(ks.tables, table)
+
+
+class _StubTrie:
+    """Holds pages of the pool the way the prefix trie does."""
+
+    def __init__(self, pool, pages):
+        self.pool, self.pages, self.calls = pool, pages, []
+
+    def reclaimable_pages(self):
+        return len(self.pages)
+
+    def evict(self, n):
+        self.calls.append(n)
+        gone, self.pages = self.pages[:n], self.pages[n:]
+        self.pool.free(gone)
+        return len(gone)
+
+
+@_WINDOWS
+def test_kind_state_reclaims_from_the_trie_before_it_gives_up(window):
+    ks = _kind_state(window, num_pages=7)       # 6 pages to hand out
+    ks.trie = _StubTrie(ks.pool, [ks.pool.alloc() for _ in range(2)])
+    assert ks.available() == 4 + 2
+    req = _fake_request()
+    ks.grow(0, req, 0, 63)                      # 4 pages: pool not dry
+    assert ks.trie.calls == [] and ks.available() == 2
+    ks.grow(0, req, 64, 95)                     # 2 more: the trie's
+    assert ks.trie.calls == [1, 1] and ks.trie.pages == []
+    assert ks.pool.num_free == 0 and len(req.kind_pages[0]) == 6
+    with pytest.raises(PoolExhausted):          # asked once more, then
+        ks.grow(0, req, 96, 140)                # … raises
+    assert ks.trie.calls == [1, 1, 1]
+    # what was taken before stays: `covered` says how far it reaches
+    assert ks.covered(req) == 96 and ks.missing(req, 96, 140) == 3
+    ks.release(0, req)
+    req2 = _fake_request()
+    with pytest.raises(PoolExhausted):
+        ks.grow(1, req2, 0, 16 * 6 + 5)         # 7 pages of 6
+    assert ks.covered(req2) == 96               # the six it got
+    ks.pool.assert_consistent()
+
+
+@_WINDOWS
+def test_kind_state_adopts_shared_pages_and_releases_its_share(window):
+    ks = _kind_state(window)
+    theirs = [ks.pool.alloc() for _ in range(3)]     # the trie's pages
+    mapped = [ks.pool.share(p) for p in theirs[:2]]  # … two mapped
+    req = _fake_request()
+    ks.adopt(1, req, mapped)
+    assert req.kind_pages[0].pages == mapped and req.pages == mapped
+    assert list(ks.tables[1, :3]) == mapped + [0]
+    assert ks.covered(req) == 32 and ks.missing(req, 32, 40) == 1
+    ks.grow(1, req, 32, 40)                          # a private page
+    private = req.pages[2]
+    assert ks.pool.refcount(private) == 1
+    assert [ks.pool.refcount(p) for p in theirs] == [2, 2, 1]
+    ks.release(1, req)
+    # shared pages lose ONE holder and stay live; the private one goes
+    assert [ks.pool.refcount(p) for p in theirs] == [1, 1, 1]
+    assert ks.pool.refcount(private) == 0
+    assert req.pages == [] and not ks.tables.any()
+    assert ks.pool.num_live == 3
+    ks.pool.assert_consistent()
+
+
+@_WINDOWS
+def test_kind_state_asks_for_the_prompt_or_the_window_at_admission(
+        window):
+    ks = _kind_state(window)
+    for n, budget in ((5, 24), (40, 24), (200, 24), (200, 64)):
+        if window is None:
+            want = -(-n // 16)
+        else:       # the first chunk, the window behind it, one ahead
+            want = -(-min(n, window + budget) // 16) + 1
+        assert ks.pages_to_admit(n, budget) == want
 
 
 def test_engine_rejects_unservable_requests():
