@@ -86,10 +86,14 @@ def split_fused_qkv(qkv, batch, seq, num_heads, head_dim):
     'sp' — the one attention input layout every transformer here uses."""
     from ....ops import manipulation as manip
 
-    qkv = manip.reshape(qkv, [batch, seq, 3, num_heads, head_dim])
+    # each a lane-aligned slice of the LAST dim, then split into heads: a
+    # [b, s, 3, nh, 64] view makes XLA lay the whole activation out with
+    # the sequence minor and copy it back for the attention kernel
+    d = num_heads * head_dim
     out = []
     for i in range(3):
-        t = manip.squeeze(manip.slice(qkv, [2], [i], [i + 1]), [2])
+        t = manip.reshape(manip.slice(qkv, [2], [i * d], [(i + 1) * d]),
+                          [batch, seq, num_heads, head_dim])
         out.append(shard_activation(t, "dp", "sp", "mp", None))
     return tuple(out)
 

@@ -3,6 +3,8 @@ chip: the installed TPU compiler lowers `ragged_paged_attention` at the
 shapes the serving paths launch, so what Mosaic refuses (a slice that is
 not tile-aligned, too much VMEM) fails here and not on the chip.
 Interpret-mode parity (tests/test_llm_engine.py) cannot see either.
+The resident flash kernels (PR 31) are compiled here too, at the
+training steps' widths, for the same reason and the reason below.
 
 Nothing runs and nothing is timed. All of these live in ONE file: the
 worker that describes the topology holds libtpu until it exits.
@@ -120,3 +122,47 @@ def test_head_major_gqa_kernel_compiles_for_v5e(one_chip, tokens, heads,
     with jax.enable_x64(False):
         text = jax.jit(call).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# The RESIDENT flash kernels (a 128-lane block of [B, S, H*D] with its
+# whole sequence in VMEM) at the widths of the training steps: what a
+# whole-sequence block, an in-kernel transpose or a dynamic lane offset
+# costs Mosaic is only seen here. (id, (b, s, h, d), dtype, causal,
+# kv_lens)
+_RESIDENT_FLASH = [
+    ("gpt2m_step", (16, 1024, 16, 64), "bfloat16", True, False),
+    ("cgpt1p3b_step", (4, 2048, 16, 128), "bfloat16", True, False),
+    ("padded_bert_batch", (16, 512, 12, 64), "bfloat16", False, True),
+    ("longest_bf16", (1, 4096, 2, 64), "bfloat16", True, True),
+    ("longest_f32", (1, 2048, 2, 64), "float32", True, False),
+    ("three_tiles_of_128", (2, 384, 2, 128), "bfloat16", True, True),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,causal,with_lens",
+    [pytest.param(*c[1:], id=c[0]) for c in _RESIDENT_FLASH])
+def test_resident_flash_compiles_for_v5e(one_chip, shape, dtype, causal,
+                                         with_lens):
+    from paddle_tpu.ops.pallas_kernels import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((shape[0],), jnp.int32, sharding=one_chip)
+    assert fa.resident_eligible(x, x, x)
+
+    def step(q, k, v, g, kv_lens=None):
+        _o, vjp = jax.vjp(lambda a, b, c: fa.flash_attention_bshd(
+            a, b, c, causal=causal, kv_lens=kv_lens), q, k, v)
+        return vjp(g)
+
+    before = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(step).lower(
+                x, x, x, x, *([lens] if with_lens else [])
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_default_matmul_precision", before)
+    # the forward and ONE backward kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

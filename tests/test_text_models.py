@@ -461,3 +461,22 @@ def test_gpt_config_recompute_loss_parity():
     np.testing.assert_allclose(losses[False], losses[True], rtol=1e-5)
     np.testing.assert_allclose(losses[False], losses["dots_saveable"],
                                rtol=1e-5)
+
+
+def test_split_fused_qkv_is_the_q_k_v_thirds_split_into_heads():
+    """[b, s, 3·d] → three [b, s, nh, hd]: the same values a
+    [b, s, 3, nh, hd] view gives, taken as lane-aligned slices of the
+    last dim (which XLA does not answer with a relayout)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet.meta_parallel.mp_layers import (
+        split_fused_qkv)
+
+    b, s, nh, hd = 2, 8, 4, 16
+    x = np.random.default_rng(0).standard_normal(
+        (b, s, 3 * nh * hd)).astype(np.float32)
+    got = split_fused_qkv(paddle.to_tensor(x), b, s, nh, hd)
+    want = x.reshape(b, s, 3, nh, hd)
+    assert len(got) == 3
+    for i, t in enumerate(got):
+        assert list(t.shape) == [b, s, nh, hd]
+        np.testing.assert_array_equal(t.numpy(), want[:, :, i])
