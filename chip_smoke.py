@@ -408,9 +408,105 @@ def _paged_cases(sm):
     return out
 
 
+def _latent_cases(sm):
+    """The latent (MLA) walk in the ABSORBED form against the EXPANDED
+    form in plain float32 `jax.numpy`, at sarvam-105b's serving shape:
+    64 heads of 128 + 64, a bf16 pool of 576-wide rows stored as 640
+    lanes, pages of 16, contexts of 10 k and 16 k; a fused window's rows
+    (one a slot) and a tick's block of 8 rows of one slot."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.ops.pallas_kernels.paged_attention import (
+        latent_paged_attention)
+
+    H, nope, rope, lat, vd, P = (4, 16, 8, 32, 16, 16) if sm.rehearse \
+        else (64, 128, 64, 512, 128, 16)
+    store = -(-(lat + rope) // 128) * 128
+    ctxs = (40, 64) if sm.rehearse else (10240, 16384)
+    dt = jnp.float32 if sm.rehearse else jnp.bfloat16
+    tol = TOL_PAGED * 10 if sm.rehearse else TOL_PAGED_BF16
+    MP = -(-max(ctxs) // P)
+    n_pool = 2 * MP + 1
+    rng = np.random.default_rng(13)
+    rows = rng.standard_normal((n_pool, P, store)) * 0.5
+    rows[..., lat + rope:] = 0.0
+    pool = jnp.asarray(rows, dt)
+    pt = jnp.asarray(rng.permutation(np.arange(1, n_pool)).reshape(2, MP),
+                     jnp.int32)
+    w_uk = jnp.asarray(rng.standard_normal((H, nope, lat)) * lat ** -0.5, dt)
+    w_uv = jnp.asarray(rng.standard_normal((H, lat, vd)) * lat ** -0.5, dt)
+    scale = (nope + rope) ** -0.5
+    out = {}
+    # (name, slot ids, lengths, rows a block)
+    cases = [("window", np.array([0, 1]), np.array(ctxs), None),
+             ("tick_block", np.zeros(8, int),
+              np.concatenate([ctxs[0] - 5 + np.arange(6), [0, 0]]), 8)]
+    for name, sid, lens, qb in cases:
+        T = len(sid)
+        q_nope = jnp.asarray(rng.standard_normal((T, H, nope)), dt)
+        q_rope = jnp.asarray(rng.standard_normal((T, H, rope)), dt)
+        key = f"latent_h{H}_r{lat + rope}_{jnp.dtype(dt).name}_{name}"
+
+        def run_case(sid=sid, lens=lens, qb=qb, q_nope=q_nope,
+                     q_rope=q_rope, key=key):
+            f32 = jnp.float32
+
+            def absorbed(q_nope, q_rope):
+                qc = jnp.einsum("thd,hdc->thc", q_nope, w_uk,
+                                preferred_element_type=f32)
+                qa = jnp.concatenate([qc, q_rope.astype(f32)], -1).astype(dt)
+                qa = jnp.pad(qa, ((0, 0), (0, 0),
+                                  (0, store - qa.shape[-1])))
+                oc = latent_paged_attention(
+                    qa, pool, pt, jnp.asarray(sid, jnp.int32),
+                    jnp.asarray(lens, jnp.int32), lat, scale,
+                    q_per_slot=qb, interpret=sm.rehearse)
+                return jnp.einsum("thc,hcd->thd", oc, w_uv,
+                                  preferred_element_type=f32)
+
+            def expanded(q_nope, q_rope):
+                outs = []
+                for t in range(len(sid)):
+                    n = int(lens[t])
+                    if n == 0:
+                        outs.append(jnp.zeros((H, vd), f32))
+                        continue
+                    pos = np.arange(n)
+                    r = pool[pt[sid[t], pos // P], pos % P].astype(f32)
+                    c, kr = r[:, :lat], r[:, lat:lat + rope]
+                    k_nope = jnp.einsum("uc,hdc->uhd", c, w_uk.astype(f32))
+                    v = jnp.einsum("uc,hcd->uhd", c, w_uv.astype(f32))
+                    s = (jnp.einsum("hd,uhd->hu", q_nope[t].astype(f32),
+                                    k_nope)
+                         + jnp.einsum("hr,ur->hu", q_rope[t].astype(f32),
+                                      kr)) * scale
+                    outs.append(jnp.einsum(
+                        "hu,uhd->hd", jax.nn.softmax(s, -1), v))
+                return jnp.stack(outs)
+
+            got = jax.block_until_ready(jax.jit(absorbed)(q_nope, q_rope))
+            with jax.default_matmul_precision("highest"):
+                want = expanded(q_nope, q_rope)
+            err = _maxdiff(got, want)
+            pad_zero = bool(np.all(
+                np.asarray(got, np.float32)[np.asarray(lens) == 0] == 0))
+            sm.say(f"kernel {key}: absorbed walk against the expanded "
+                   f"form max|Δ| {err:.2e} (tol {tol:g}) padding rows "
+                   f"zero={pad_zero}")
+            sm.check(math.isfinite(err) and err <= tol and pad_zero,
+                     f"kernel {key}: err {err}, pad_zero {pad_zero}")
+            return {"max_abs_err": err, "pad_rows_zero": pad_zero}
+
+        sm.case(key, run_case, out)
+    return out
+
+
 def phase_kernels(sm):
     out = _flash_cases(sm)
     out.update(_paged_cases(sm))
+    out.update(_latent_cases(sm))
     return {"cases": out}
 
 

@@ -600,6 +600,10 @@ class LLMEngineConfig:
         kinds = model_config.cache_kinds()
         ck = kinds[0] if kind is None else next(
             k for k in kinds if k.name == kind)
+        if ck.latent:
+            # one pool a layer, a row a token at the width it is stored
+            return (len(ck.layers) * page_size * ck.row_store
+                    * jnp.dtype(dt).itemsize)
         nh, hd = ck.kv_heads, ck.head_dim
         if quantized == 4:
             per_row = nh * (hd // 2)      # packed nibbles
@@ -935,7 +939,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # several kinds: what assumes one geometry refuses (below), and
         # the per-kind counters of `stats` exist
         self._several = len(kinds) > 1
-        if self._several:
+        # a latent kind (one pool a layer, rows with no head axis) is
+        # refused what reads `[page, heads, head_dim]` pools, as several
+        # kinds are refused what assumes one geometry; both keep the
+        # page gauges and counters a kind (`<kind>_pages_live`, …)
+        self._latent = any(k.latent for k in kinds)
+        self._kind_stats = self._several or self._latent
+        if self._kind_stats:
             on = [name for name, v in (
                 ("prefix_cache=True", cfg.prefix_cache),
                 ("kv_tier", cfg.kv_tier),
@@ -945,10 +955,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 raise ValueError(
                     f"{', '.join(on)}: not with a model of "
                     f"{len(kinds)} cache kinds "
-                    f"({[k.name for k in kinds]}). The prefix trie, "
-                    "the tier store, the KV wire and the speculative "
-                    "draft pool assume ONE page geometry and one page "
-                    "table a slot (ROADMAP.md B-I)")
+                    f"({[k.name for k in kinds]}"
+                    f"{', latent' if self._latent else ''}). The prefix "
+                    "trie, the tier store, the KV wire and the "
+                    "speculative draft pool assume ONE page geometry of "
+                    "`[page, heads, head_dim]` pools and one page table "
+                    "a slot (ROADMAP.md B-I)")
         # pool in the configured kv_dtype (default: the model's compute
         # dtype — decode is HBM-bound, same reasoning as generate()'s
         # cache dtype; "int8" quantizes each written row per (token,
@@ -967,10 +979,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         cache_dt, self.kv_quantized = _qrt.resolve_kv_dtype(
             cfg.kv_dtype, compute_dt)
         if self.kv_quantized and (
-                self._several or any(k.head_major for k in kinds)):
+                self._kind_stats or any(k.head_major for k in kinds)):
             raise ValueError(
-                f"kv_dtype={cfg.kv_dtype!r}: head-major pools and "
-                "models with several cache kinds keep float pools")
+                f"kv_dtype={cfg.kv_dtype!r}: head-major and latent "
+                "pools and models with several cache kinds keep float "
+                "pools")
         hd = kinds[0].head_dim
         # kv_quantized is the code width (0 float / 8 / 4 — truthy when
         # quantized); int4 packs two nibbles per byte along head_dim,
@@ -990,7 +1003,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         sharding = mesh_mod.named_sharding()  # replicated on the mesh
 
         # k0, v0, k1, v1 … in LAYER order, each pool shaped by its
-        # layer's kind
+        # layer's kind (a latent kind's layer has one pool, not two)
         kind_of = {i: n for n, k in enumerate(kinds) for i in k.layers}
         n_layers = len(kind_of)
         if sorted(kind_of) != list(range(n_layers)):
@@ -1000,10 +1013,11 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         def _fresh_pools():
             pools = [
                 jax.device_put(
-                    jnp.zeros(kinds[kind_of[i // 2]].pool_shape(
-                        pages_of[kind_of[i // 2]], self.page_size,
+                    jnp.zeros(kinds[kind_of[i]].pool_shape(
+                        pages_of[kind_of[i]], self.page_size,
                         hd_store), cache_dt), sharding)
-                for i in range(2 * n_layers)]
+                for i in range(n_layers)
+                for _ in range(kinds[kind_of[i]].pools_per_layer)]
             scales = []
             if self.kv_quantized:
                 sshape = _qrt.kv_scale_shape(num_pages, self.page_size,
@@ -1077,6 +1091,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       # (`_note_launches`); both 0 on the jnp path
                       "paged_attn_mxu_launches": 0,
                       "paged_attn_vpu_launches": 0}
+        if self._latent:       # the latent walk's launches
+            self.stats["paged_attn_latent_launches"] = 0
         # the model's own step counters (e.g. an expert layer's), summed
         # into `stats` under their names; a tick's arrive with the next
         # read the ENGINE thread makes anyway (`_note_counters`: never a
@@ -1086,7 +1102,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._pending_counters = []
         for name in self._counter_names:
             self.stats[name] = 0
-        if self._several:
+        if self._kind_stats:
             self.stats["window_pages_freed"] = 0
             for k in kinds:
                 self.stats[f"{k.name}_pages_live"] = 0
@@ -1313,10 +1329,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       model)."""
         grammar_obj = self._resolve_constraint(grammar, json_schema,
                                                eos_token_id, spec_mode)
-        if self._several and (prefill_only or kv_import is not None):
+        if self._kind_stats and (prefill_only or kv_import is not None):
             raise ValueError(
                 "prefill_only / kv_import: the KV wire carries one page "
-                "geometry; this model has "
+                "geometry of keys and values a head; this model has "
                 f"{[c.kind.name for c in self._caches]} (ROADMAP.md B-I)")
         toks = np.asarray(prompt).reshape(-1)
         if toks.size == 0:
@@ -2590,7 +2606,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         completes, and per-request greedy/sampled outputs are
         schedule-invariant, so nothing observable changes per request."""
         out = self._step()
-        if self._several:
+        if self._kind_stats:
             self._trim_windows()
         return out
 
@@ -2659,7 +2675,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         with _trace_span("llm_engine.reserve",
                          rows=len(active)) as span:
             window = self._reserve_window(active)
-            if self._several:   # `full_pages`, `window_pages`, …
+            if self._kind_stats:  # `full_pages`, `latent_pages`, …
                 span.set(window_pages_freed=self.stats[
                     "window_pages_freed"], **{
                         f"{c.kind.name}_pages": c.pool.num_live
@@ -2789,7 +2805,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                         or len(req.tokens) >= req.target):
                     done = True   # in-executable masking already
                     break         # padded the rest of the window
-            if self._several and emitted:
+            if self._kind_stats and emitted:
                 self._note_attended(req.n_prefilled, 1, steps=emitted)
             req.n_prefilled += emitted
             total += emitted
@@ -3085,7 +3101,7 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
         finished = []
         for slot, req, take in plan:
-            if self._several:
+            if self._kind_stats:
                 self._note_attended(req.n_prefilled, take)
             req.n_prefilled += take
             if req.n_prefilled >= len(req.tokens) - 1:

@@ -28,28 +28,54 @@ __all__ = ["route_top_k", "held_experts_ffn", "grouped_matmul"]
 _scope = jax.named_scope
 
 
-def route_top_k(x, router_w, top_k, renormalise=True):
-    """Softmax routing in float32 over ALL `router_w.shape[1]` experts:
-    x [T, d], router_w [d, E_all] → (weights [T, top_k] float32, expert
-    ids [T, top_k] int32). `renormalise` divides the chosen weights by
-    their sum (norm_topk_prob)."""
+def route_top_k(x, router_w, top_k, renormalise=True, scoring="softmax",
+                select_bias=None):
+    """Routing in float32 over ALL `router_w.shape[1]` experts: x
+    [T, d], router_w [d, E_all] → (weights [T, top_k] float32, expert
+    ids [T, top_k] int32). `scoring` turns the logits into scores:
+    "softmax" over the experts, or "sigmoid" of each. The `top_k`
+    largest of score + `select_bias` [E_all] are chosen (the bias
+    selects only: an auxiliary-loss-free load balance); the weights are
+    the chosen SCORES, divided by their sum with `renormalise`
+    (norm_topk_prob)."""
     with _scope("moe_router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             router_w.astype(jnp.float32),
                             precision="highest")
-        probs = jax.nn.softmax(logits, axis=-1)
-        w, ids = jax.lax.top_k(probs, int(top_k))
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"router scoring {scoring!r}")
+        if select_bias is None:
+            w, ids = jax.lax.top_k(scores, int(top_k))
+        else:
+            _, ids = jax.lax.top_k(
+                scores + select_bias.astype(jnp.float32), int(top_k))
+            w = jnp.take_along_axis(scores, ids, axis=-1)
         if renormalise:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
         return w, ids.astype(jnp.int32)
 
 
-# the Pallas grouped matmul's (k, n) tile: the whole contraction of the
-# first projection and 1 024 columns read best at 48 and at 512 rows on a
-# v5e (PERF.md §6, PR 28); rows a tile are its 128
+# the Pallas grouped matmul's tiles: 128 rows, and of (k, n) the whole
+# contraction of the first projection and 1 024 columns read best at 48
+# and at 512 rows on a v5e at d 3072 (PERF.md §6, PR 28). A function of
+# the shapes: the contraction tile is the largest divisor of k in whole
+# 128-lane tiles that two buffers of `[tile_k, tile_n]` keep inside the
+# scoped VMEM (3 072 of d 3072 and 1 024 of 1 024 as before; 2 048 of d
+# 4096 and of 2 048)
 _GMM_TILE_M = 128
-_GMM_TILE_K = 3072
+_GMM_TILE_K_MOST = 3072
 _GMM_TILE_N = 1024
+
+
+def _gmm_tile_k(k):
+    for tile in range(min(_GMM_TILE_K_MOST, k) // 128 * 128, 0, -128):
+        if k % tile == 0:
+            return tile
+    return min(_GMM_TILE_K_MOST, k)
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -64,7 +90,7 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
         m, k = lhs.shape
         n = rhs.shape[2]
-        tiling = (min(_GMM_TILE_M, m), min(_GMM_TILE_K, k),
+        tiling = (min(_GMM_TILE_M, m), _gmm_tile_k(k),
                   min(_GMM_TILE_N, n))
         return gmm(lhs, rhs, group_sizes,
                    preferred_element_type=lhs.dtype, tiling=tiling)

@@ -404,6 +404,71 @@ def paged_attention_gqa_jnp(qv, kpool, vpool, tables, sids, ls, starts=None,
     return jnp.where((ls > 0)[:, None, None], out, jnp.zeros_like(out))
 
 
+def paged_attention_latent(qv, pool, tables, sids, ls, v_dim, scale,
+                           frontier_offset=None, block_layout=None):
+    """Ragged paged attention over a LATENT pool, on raw arrays: q
+    [T, H, R] ABSORBED queries, pool [N, P, R] one row a token with no
+    head axis (every head reads every row), tables [S, MP], sids / ls
+    [T] as `paged_attention`. A row's score is q · row · `scale`; its
+    value the row's first `v_dim` lanes: out [T, H, v_dim], which the
+    caller projects up a head. The Pallas latent walk on a TPU,
+    `paged_attention_latent_jnp` elsewhere. `block_layout`: as
+    `paged_attention_gqa` (query blocks of one slot's rows, its pages
+    copied once a block)."""
+    if not _pallas_backend_ok():
+        return paged_attention_latent_jnp(qv, pool, tables, sids, ls,
+                                          v_dim, scale, frontier_offset)
+    from ...ops.pallas_kernels import paged_attention as pa_kernel
+
+    if block_layout is None:
+        return pa_kernel.latent_paged_attention(
+            qv, pool, tables, sids, ls, v_dim, scale,
+            frontier_offset=frontier_offset)
+    lay = block_layout
+    out = pa_kernel.latent_paged_attention(
+        lay.spread(qv), pool, tables, lay.sids, lay.lens, v_dim, scale,
+        frontier_offset=frontier_offset, q_per_slot=lay.rows)
+    return out[lay.dest]
+
+
+def paged_attention_latent_jnp(qv, pool, tables, sids, ls, v_dim, scale,
+                               frontier_offset=None):
+    """`paged_attention_latent` in plain jnp: what every non-TPU backend
+    runs and what the latent walk is compared with. The slot-grid shape
+    of `paged_attention_jnp`."""
+    import jax
+
+    n_pages, page_size, row = pool.shape
+    tokens, heads, _ = qv.shape
+    n_slots, pages_per_seq = tables.shape
+    L = pages_per_seq * page_size
+    ls = ls.astype(jnp.int32)
+    if frontier_offset is not None:
+        ls = jnp.where(ls > 0, ls + jnp.asarray(frontier_offset,
+                                                jnp.int32), 0)
+    sids = sids.astype(jnp.int32)
+    l_idx = jnp.arange(L, dtype=jnp.int32)
+    phys = (tables.astype(jnp.int32)[:, l_idx // page_size]
+            * page_size + (l_idx % page_size)[None, :])   # [S, L]
+    rows = pool.reshape(n_pages * page_size, row)[phys]   # [S, L, R]
+    eq = sids[:, None] == sids[None, :]
+    cpos = jnp.sum(jnp.tril(eq, -1), axis=1)              # [T]
+    qs = jnp.zeros((n_slots, tokens, heads, row), qv.dtype).at[
+        (sids, cpos)].set(qv)
+    hi = jnp.zeros((n_slots, tokens), jnp.int32).at[(sids, cpos)].set(ls)
+    sc = jnp.einsum("schr,slr->shcl", qs, rows,
+                    preferred_element_type=jnp.float32) * scale
+    seen = l_idx[None, None, :] < hi[:, :, None]          # [S, C, L]
+    sc = jnp.where(seen[:, None], sc, jnp.float32(-1e30))
+    w = jax.nn.softmax(sc, axis=-1).astype(rows.dtype)
+    # [S, H·C, L] · [S, L, v]: the slot leads both operands
+    o = jnp.matmul(w.reshape(n_slots, heads * tokens, L),
+                   rows[..., :v_dim], preferred_element_type=jnp.float32)
+    o = jnp.swapaxes(o.reshape(n_slots, heads, tokens, v_dim), 1, 2)
+    out = o.astype(qv.dtype)[(sids, cpos)]
+    return jnp.where((ls > 0)[:, None, None], out, jnp.zeros_like(out))
+
+
 def _pallas_backend_ok():
     """The shared Pallas gate policy: kernels flag on AND a real TPU
     backend (ONE place — both the flash and the paged gates call it).

@@ -843,3 +843,234 @@ def _mxu_body(b, n_pages, kvlens, q_ref, k_buf, v_buf, acc_ref, m_ref,
         l_ref[...] = jnp.broadcast_to(l_new, (rows, 128)).reshape(l_ref.shape)
 
     return attend
+
+
+# ---- the latent walk ---------------------------------------------------
+# At the end of the file on purpose (see the note above the page-major
+# walk's bodies): nothing above moves.
+#
+# A LATENT pool is `[num_pages, page_size, R]`: ONE row a token with no
+# head axis (multi-head latent attention keeps `[c | k_rope]`, R = 576,
+# and every head reads it). Keys AND values come from that row: a head's
+# score is its ABSORBED query `[q_nope · W_UKᵀ | q_rope]` (R wide) against
+# the row, its value the row's first `v_dim` lanes; the caller projects
+# the `[H, v_dim]` result up afterwards. All heads share every row, so
+# the MXU's free dimension is `rows · H` with no mask between heads:
+# per group of pages `q[qb·H, R] · rowsᵀ[R, G·P]`, a lane-dense softmax,
+# `p · rows[:, :v_dim]`.
+
+# the scoped VMEM the latent walk asks for: its score tile `[qb·H,
+# group·P]` and accumulator `[qb·H, v_dim]` in float32 beside the two
+# page halves outgrow the 16 MiB default at 16 rows of 64 heads
+_LATENT_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _latent_group_tokens(qb):
+    """Tokens a DMA group of the latent walk holds (two such halves of
+    `[tokens, R]` in VMEM), a static function of the block's rows. A
+    group costs a fixed few microseconds beside its bytes, so a lone
+    row (the fused window's launch: bound by the copies) reads best at
+    2 048 (28.7 / 35.9 / 39.9 / 41.4 / 41.3 % of 819 GB/s at 256 / 512 /
+    1 024 / 2 048 / 4 096 tokens, 32 rows at 13 k of context on a v5e);
+    a block of several rows (the tick: bound by the MXU) at 1 024, whose
+    score tile is half as large (3.55 against 4.34 ms at the start of a
+    prompt, 18.10 against 18.16 ms at 15 k: PERF.md §6, PR 33, step 0)."""
+    return 2048 if qb == 1 else 1024
+
+
+def _latent_walk_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, pool_hbm,
+                        o_ref, buf, sems, acc_ref, m_ref, l_ref, *,
+                        pages_per_seq, group, v_dim, scale):
+    """One grid step = one query block: `q_ref` [qb, H, R] (qb rows of
+    ONE slot, live rows first), the walk over the slot's live pages the
+    loop in here. `buf` is `[2, group·P, R]`: two halves, in each the
+    group's pages one under the other, so the group's rows are ONE
+    `[group·P, R]` operand. A block whose rows past the first are all
+    padding (a decoding row in the tick's slot-block layout) multiplies
+    its one live row's `[H, R]` alone."""
+    b = pl.program_id(0)
+    qb, heads, dim = q_ref.shape
+    rows = buf.shape[1]
+    page_size = rows // group
+    precision = (jax.lax.Precision.HIGHEST if buf.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    kvlens, kvmax = _block_kv_lens(lens_ref, off_ref, b, qb)
+    n_pages = jnp.minimum((kvmax + (page_size - 1)) // page_size,
+                          pages_per_seq)
+    n_groups = (n_pages + (group - 1)) // group
+    table = sid_ref[b * qb] * pages_per_seq
+
+    # a last group's dead pages are never copied: what they multiply
+    # (weight exactly 0) must be finite
+    @pl.when(b == 0)
+    def _zero():
+        buf[...] = jnp.zeros_like(buf)
+
+    def live_in(g):
+        return jnp.minimum(group, n_pages - g * group)
+
+    def group_copies(g, half, start):
+        def one(i, carry):
+            page = pt_ref[table + g * group + i] if start else 0
+            dma = pltpu.make_async_copy(
+                pool_hbm.at[page],
+                buf.at[half, pl.ds(i * page_size, page_size), :],
+                sems.at[half])
+            dma.start() if start else dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(g), one, None)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend_rows(n):
+        """The group's fold for the block's first `n` rows (static)."""
+        mm_rows = n * heads
+        block_row = jax.lax.broadcasted_iota(
+            jnp.int32, (mm_rows, 1), 0) // heads
+        kvlen = jnp.zeros((mm_rows, 1), jnp.int32)
+        for i in range(n):
+            kvlen = jnp.where(block_row == i, kvlens[i], kvlen)
+        kvlen = jnp.minimum(kvlen, n_pages * page_size)
+        col = jax.lax.broadcasted_iota(jnp.int32, (mm_rows, rows), 1)
+
+        def attend(g, half):
+            q = q_ref[:n].reshape(mm_rows, dim)
+            kv = buf[half]                                   # [rows, R]
+            valid = col < kvlen - g * rows
+            s = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[:mm_rows, :1]
+            l_prev = l_ref[:mm_rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[:mm_rows] = alpha * acc_ref[:mm_rows] \
+                + jax.lax.dot_general(
+                    p.astype(kv.dtype), kv[:, :v_dim],
+                    (((1,), (0,)), ((), ())), precision=precision,
+                    preferred_element_type=jnp.float32)
+            m_ref[:mm_rows] = jnp.broadcast_to(m_new, (mm_rows, 128))
+            l_ref[:mm_rows] = jnp.broadcast_to(l_new, (mm_rows, 128))
+
+        return attend
+
+    attend_one = attend_rows(1)
+    if qb > 1:
+        attend_all = attend_rows(qb)
+        others = kvlens[1]
+        for kl in kvlens[2:]:
+            others = jnp.maximum(others, kl)
+
+    @pl.when(n_groups > 0)
+    def _first():
+        group_copies(0, 0, True)
+
+    def one_group(g, carry):
+        half = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            group_copies(g + 1, 1 - half, True)
+
+        group_copies(g, half, False)
+        if qb == 1:
+            attend_one(g, half)
+        else:
+            @pl.when(others == 0)
+            def _single():
+                attend_one(g, half)
+
+            @pl.when(others > 0)
+            def _block():
+                attend_all(g, half)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, one_group, None)
+    l = l_ref[:, :1]
+    # padding rows (kv_len 0) attended nothing: l == 0 -> zeros
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype).reshape(o_ref.shape)
+
+
+def _latent_walks(page_size, dtype):
+    """True where the latent walk can copy a page `[P, R]` into its
+    place in a group buffer: whole sublane tiles of the pool's dtype."""
+    return page_size % (8 * (4 // jnp.dtype(dtype).itemsize)) == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_paged_call(q_shape, q_dtype, pool_shape, pool_dtype,
+                       pages_per_seq, qb, v_dim, scale, group_tokens,
+                       interpret):
+    """The latent launch for one set of static shapes, ONE jitted
+    function a set (as `_paged_call`)."""
+    tokens, heads, dim = q_shape
+    _, page_size, row = pool_shape
+    if row != dim or not 0 < v_dim <= row:
+        raise ValueError(
+            f"absorbed queries are as wide as the pool's row ({dim} / "
+            f"{row}) and values its first v_dim ({v_dim}) lanes")
+    if not _latent_walks(page_size, pool_dtype):
+        raise ValueError(
+            f"a latent {jnp.dtype(pool_dtype).name} pool needs pages of "
+            f"whole sublane tiles; got page_size {page_size}")
+    if tokens % qb:
+        raise ValueError(f"{tokens} rows are not whole blocks of {qb}")
+    group = max(1, min(pages_per_seq, int(group_tokens) // page_size))
+
+    q_spec = pl.BlockSpec((qb, heads, dim), lambda b, *_: (b, 0, 0))
+    o_spec = pl.BlockSpec((qb, heads, v_dim), lambda b, *_: (b, 0, 0))
+    launch = pl.pallas_call(
+        functools.partial(_latent_walk_kernel, pages_per_seq=pages_per_seq,
+                          group=group, v_dim=v_dim, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(tokens // qb,),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=o_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, group * page_size, row), pool_dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((qb * heads, v_dim), jnp.float32),
+                pltpu.VMEM((qb * heads, 128), jnp.float32),
+                pltpu.VMEM((qb * heads, 128), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((tokens, heads, v_dim), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )
+    jitted = jax.jit(launch, inline=True)
+    jitted.body = "latent"
+    return jitted
+
+
+def latent_paged_attention(q, pool, page_tables, slot_ids, kv_lens, v_dim,
+                           scale, frontier_offset=None, q_per_slot=None,
+                           group_tokens=None, interpret=False):
+    """q [T, H, R] absorbed queries, pool [N, P, R] latent rows,
+    page_tables [S, MP], slot_ids / kv_lens [T] → out [T, H, v_dim]:
+    softmax(q · row · scale) over the row's slot's positions below
+    kv_len, times the rows' first `v_dim` lanes. `frontier_offset` and
+    `q_per_slot` as `ragged_paged_attention`: the latter a CONTRACT
+    (every block of that many rows is one slot's, its live rows first).
+    `scale` and `v_dim` are static; `group_tokens` overrides
+    `_latent_group_tokens` (the sweep's and the tests')."""
+    if frontier_offset is None:
+        frontier_offset = 0
+    call = _latent_paged_call(
+        q.shape, q.dtype, pool.shape, pool.dtype, page_tables.shape[1],
+        int(q_per_slot or 1), int(v_dim), float(scale),
+        int(group_tokens or _latent_group_tokens(int(q_per_slot or 1))),
+        interpret)
+    for sites in _open_site_counts.stack:
+        sites[call.body] = sites.get(call.body, 0) + 1
+    return call(jnp.asarray(slot_ids, jnp.int32),
+                jnp.asarray(page_tables, jnp.int32).reshape(-1),
+                jnp.asarray(kv_lens, jnp.int32),
+                jnp.asarray(frontier_offset, jnp.int32).reshape((1,)),
+                q, pool)

@@ -17,7 +17,8 @@ its arithmetic. Between them:
         one ragged step over flat tokens (Tensors in and out): returns
         (logits [1, S, vocab], *new pools, *new scale planes[, counters
         [len(step_counters)]]). `kv` is the flat list k0, v0, k1, v1 …
-        in LAYER order, each pool shaped by its layer's kind.
+        in LAYER order, each pool shaped by its layer's kind; a layer
+        of a LATENT kind has ONE pool there, not two.
     model._paged_decode_fused(k, page_size, tok0, pos0, rem, fin0, eos,
         temps, top_ps, streams, page_tables, kv, kv_scales, key, ...)
         `k` such steps in one scan with sampling inside (raw arrays):
@@ -32,8 +33,12 @@ import collections
 __all__ = ["CacheKind"]
 
 
+_LANES = 128
+
+
 class CacheKind(collections.namedtuple(
-        "CacheKind", "name layers kv_heads head_dim window head_major")):
+        "CacheKind", "name layers kv_heads head_dim window head_major "
+        "row_dim", defaults=(None,))):
     """One kind of K/V cache.
 
     name        what the engine's counters and spans call it
@@ -45,10 +50,33 @@ class CacheKind(collections.namedtuple(
                 and the cache manager frees pages wholly behind that.
     head_major  pool layout: False [pages, page, kv_heads, head_dim],
                 True [pages, kv_heads, page, head_dim]
+    row_dim     None: a layer keeps TWO pools, keys and values a head.
+                An int R: a LATENT kind. A layer keeps ONE pool [pages,
+                page, R'], a row a token with no head axis, from which
+                every head reads keys AND values (multi-head latent
+                attention's `[c | k_rope]`); `kv_heads` and `head_dim`
+                say nothing (None). R' is R in whole 128-lane tiles
+                (`row_store`): the device stores a narrower last
+                dimension at that width anyway, and a kernel can only
+                copy whole tiles of it; the lanes past R hold zeros.
     """
     __slots__ = ()
 
+    @property
+    def latent(self):
+        return self.row_dim is not None
+
+    @property
+    def pools_per_layer(self):
+        return 1 if self.latent else 2
+
+    @property
+    def row_store(self):
+        return -(-self.row_dim // _LANES) * _LANES
+
     def pool_shape(self, num_pages, page_size, head_dim_store=None):
+        if self.latent:
+            return (num_pages, page_size, self.row_store)
         hd = self.head_dim if head_dim_store is None else head_dim_store
         if self.head_major:
             return (num_pages, self.kv_heads, page_size, hd)

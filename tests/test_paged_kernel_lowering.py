@@ -124,6 +124,61 @@ def test_head_major_gqa_kernel_compiles_for_v5e(one_chip, tokens, heads,
     assert "tpu_custom_call" in text
 
 
+# The LATENT walk at sarvam-105b's serving shapes: 64 heads, a row of
+# 576 stored as 640 lanes, a bf16 pool of 3 GiB over 5 layers, pages of
+# 16, 32 slots of 16 384 positions. (id, tokens, q_per_slot, tokens a
+# DMA group)
+_LATENT_LAUNCHES = [
+    ("cell_window", 32, None, None),
+    ("cell_tick_blocks_of_16", 2048 + 32 * 15, 16, None),
+    ("tick_blocks_of_8", 1248, 8, 2048),
+]
+
+
+@pytest.mark.parametrize(
+    "tokens,qps,group",
+    [pytest.param(*c[1:], id=c[0]) for c in _LATENT_LAUNCHES])
+def test_latent_walk_compiles_for_v5e(one_chip, tokens, qps, group):
+    from paddle_tpu.ops.pallas_kernels.paged_attention import (
+        latent_paged_attention)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((tokens, 64, 640), jnp.bfloat16),
+            sds((31458, 16, 640), jnp.bfloat16),
+            sds((32, 1024), jnp.int32), sds((tokens,), jnp.int32),
+            sds((tokens,), jnp.int32), sds((), jnp.int32)]
+
+    def call(q, pool, pt, sid, lens, off):
+        return latent_paged_attention(
+            q, pool, pt, sid, lens, 512, 0.1, frontier_offset=off,
+            q_per_slot=qps, group_tokens=group)
+
+    with jax.enable_x64(False):
+        text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_mosaic_refuses_a_page_slice_of_576_lanes(one_chip):
+    """Why the latent row is STORED 640 wide (`CacheKind.row_store`):
+    the device tiles a `[pages, 16, 576]` pool at 640 lanes anyway, and
+    a manual copy of one page of it is no whole-tile slice."""
+    from paddle_tpu.ops.pallas_kernels.paged_attention import (
+        latent_paged_attention)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((32, 64, 576), jnp.bfloat16),
+            sds((1025, 16, 576), jnp.bfloat16), sds((32, 32), jnp.int32),
+            sds((32,), jnp.int32), sds((32,), jnp.int32)]
+    with jax.enable_x64(False), pytest.raises(
+            Exception, match="aligned to tiling"):
+        jax.jit(lambda q, pool, pt, sid, lens: latent_paged_attention(
+            q, pool, pt, sid, lens, 512, 0.1)).lower(*args).compile()
+
+
 # The RESIDENT flash kernels (a 128-lane block of [B, S, H*D] with its
 # whole sequence in VMEM) at the widths of the training steps: what a
 # whole-sequence block, an in-kernel transpose or a dynamic lane offset
