@@ -409,17 +409,19 @@ def _paged_cases(sm):
 
 
 def _latent_cases(sm):
-    """The latent (MLA) walk in the ABSORBED form against the EXPANDED
-    form in plain float32 `jax.numpy`, at sarvam-105b's serving shape:
-    64 heads of 128 + 64, a bf16 pool of 576-wide rows stored as 640
-    lanes, pages of 16, contexts of 10 k and 16 k; a fused window's rows
-    (one a slot) and a tick's block of 8 rows of one slot."""
+    """The latent (MLA) walk in the ABSORBED form, and the kernel that
+    serves the EXPANDED form, against the expanded form in plain float32
+    `jax.numpy`, at sarvam-105b's serving shape: 64 heads of 128 + 64, a
+    bf16 pool of 576-wide rows stored as 640 lanes, pages of 16,
+    contexts of 10 k and 16 k; a fused window's rows (one a slot), a
+    tick's block of 8 rows of one slot, and two runs of 6 rows that end
+    at the two contexts (the expanded kernel's)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from paddle_tpu.ops.pallas_kernels.paged_attention import (
-        latent_paged_attention)
+        latent_expanded_attention, latent_paged_attention)
 
     H, nope, rope, lat, vd, P = (4, 16, 8, 32, 16, 16) if sm.rehearse \
         else (64, 128, 64, 512, 128, 16)
@@ -439,10 +441,13 @@ def _latent_cases(sm):
     w_uv = jnp.asarray(rng.standard_normal((H, lat, vd)) * lat ** -0.5, dt)
     scale = (nope + rope) ** -0.5
     out = {}
-    # (name, slot ids, lengths, rows a block)
+    # (name, slot ids, lengths, rows a block; "runs": the expanded
+    # kernel, each slot's rows a run)
     cases = [("window", np.array([0, 1]), np.array(ctxs), None),
              ("tick_block", np.zeros(8, int),
-              np.concatenate([ctxs[0] - 5 + np.arange(6), [0, 0]]), 8)]
+              np.concatenate([ctxs[0] - 5 + np.arange(6), [0, 0]]), 8),
+             ("expanded_runs", np.repeat([0, 1], 6),
+              np.concatenate([c - 5 + np.arange(6) for c in ctxs]), "runs")]
     for name, sid, lens, qb in cases:
         T = len(sid)
         q_nope = jnp.asarray(rng.standard_normal((T, H, nope)), dt)
@@ -466,6 +471,23 @@ def _latent_cases(sm):
                 return jnp.einsum("thc,hcd->thd", oc, w_uv,
                                   preferred_element_type=f32)
 
+            def expanded_kernel(q_nope, q_rope):
+                # a run a slot: rows 0–5 and 16–21 of the laid-out rows
+                # (the first run's sub-block runs over the second's rows)
+                sub = 16 if sm.rehearse else 512
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                q = jnp.pad(q, ((0, 0), (0, 0), (0, store - lat - rope)))
+                q = jnp.zeros((16 + sub,) + q.shape[1:], q.dtype).at[
+                    np.r_[0:6, 16:22]].set(q)
+                i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+                o = latent_expanded_attention(
+                    q.reshape(16 + sub, -1), pool, w_uk, w_uv, pt,
+                    i32([0, 1]), i32([0, 16]), i32(lens[[0, 6]]),
+                    i32([6, 6]), scale, sub_rows=sub,
+                    tile_tokens=16 if sm.rehearse else None,
+                    interpret=sm.rehearse)
+                return o[np.r_[0:6, 16:22]].reshape(12, H, vd)
+
             def expanded(q_nope, q_rope):
                 outs = []
                 for t in range(len(sid)):
@@ -486,13 +508,15 @@ def _latent_cases(sm):
                         "hu,uhd->hd", jax.nn.softmax(s, -1), v))
                 return jnp.stack(outs)
 
-            got = jax.block_until_ready(jax.jit(absorbed)(q_nope, q_rope))
+            served = expanded_kernel if qb == "runs" else absorbed
+            got = jax.block_until_ready(jax.jit(served)(q_nope, q_rope))
             with jax.default_matmul_precision("highest"):
                 want = expanded(q_nope, q_rope)
             err = _maxdiff(got, want)
             pad_zero = bool(np.all(
                 np.asarray(got, np.float32)[np.asarray(lens) == 0] == 0))
-            sm.say(f"kernel {key}: absorbed walk against the expanded "
+            what = "expanded kernel" if qb == "runs" else "absorbed walk"
+            sm.say(f"kernel {key}: {what} against the expanded "
                    f"form max|Δ| {err:.2e} (tol {tol:g}) padding rows "
                    f"zero={pad_zero}")
             sm.check(math.isfinite(err) and err <= tol and pad_zero,

@@ -358,6 +358,60 @@ class SlotBlockLayout:
             self.dest].set(x)
 
 
+class SlotRunLayout:
+    """Which rows of a flat step stand in RUNS of at least `min_rows`
+    rows of ONE slot at consecutive positions (a prefill chunk: sids
+    equal, lens ascending by one), and those rows laid out again so
+    that every such run starts at a multiple of `align` rows, with
+    `spare` rows past the last: what the EXPANDED latent walk takes
+    (`paged_attention_latent_expanded`: its sub-blocks start at a run's
+    first row and the last of them runs past the run's rows). At most
+    `T // min_rows` runs qualify, so the layout is `total` = T + that
+    many · (align − 1) rows, rounded up, + spare. `expanded` [T] bool the
+    rows in such runs; `dest` [T] where each went (every other row to
+    the last row, which no run holds); `src` [total] a row to read for
+    each laid-out row; `run_slots` / `run_row0` / `run_first` /
+    `run_rows` [max_runs] int32 a run's slot, first laid-out row, first
+    row's kv length and live rows (0: no run), in the order of their
+    rows."""
+
+    def __init__(self, sids, lens, min_rows, align, spare):
+        import jax
+
+        T = sids.shape[0]
+        align = int(align)
+        self.max_runs = T // int(min_rows)
+        self.total = -(-(T + self.max_runs * (align - 1)) // align) \
+            * align + int(spare)
+        r = jnp.arange(T, dtype=jnp.int32)
+        sids = sids.astype(jnp.int32)
+        lens = lens.astype(jnp.int32)
+        live = lens > 0
+        starts = live & ((r == 0) | (sids != jnp.roll(sids, 1))
+                         | ~jnp.roll(live, 1)
+                         | (lens != jnp.roll(lens, 1) + 1))
+        run = jnp.cumsum(starts) - 1          # of a live row
+        size = jax.ops.segment_sum(live.astype(jnp.int32),
+                                   jnp.where(live, run, T),
+                                   num_segments=T)[run.clip(0)]
+        self.expanded = live & (size >= int(min_rows))
+        head = starts & self.expanded
+        k = (jnp.cumsum(head) - 1).clip(0)    # rank among the runs kept
+
+        def a_run(x):
+            return jnp.zeros((self.max_runs,), jnp.int32).at[
+                jnp.where(head, k, self.max_runs)].set(x, mode="drop")
+
+        self.run_slots, self.run_first = a_run(sids), a_run(lens)
+        self.run_rows = a_run(size)
+        padded = -(-self.run_rows // align) * align
+        self.run_row0 = jnp.cumsum(padded) - padded
+        self.dest = jnp.where(
+            self.expanded, self.run_row0[k] + r - a_run(r)[k],
+            self.total - 1)
+        self.src = jnp.zeros((self.total,), jnp.int32).at[self.dest].set(r)
+
+
 def paged_attention_gqa_jnp(qv, kpool, vpool, tables, sids, ls, starts=None,
                             frontier_offset=None):
     """`paged_attention_gqa` in plain jnp: what every non-TPU backend
@@ -467,6 +521,85 @@ def paged_attention_latent_jnp(qv, pool, tables, sids, ls, v_dim, scale,
     o = jnp.swapaxes(o.reshape(n_slots, heads, tokens, v_dim), 1, 2)
     out = o.astype(qv.dtype)[(sids, cpos)]
     return jnp.where((ls > 0)[:, None, None], out, jnp.zeros_like(out))
+
+
+def latent_run_layout(sids, lens, min_rows):
+    """The `SlotRunLayout` the expanded latent walk takes of a step's
+    rows: runs from `min_rows` rows on, laid out at the kernel's row
+    alignment with a sub-block to spare."""
+    from ...ops.pallas_kernels import paged_attention as pa_kernel
+
+    return SlotRunLayout(sids, lens, min_rows,
+                         pa_kernel.LATENT_EXPANDED_ROW_ALIGN,
+                         pa_kernel.latent_expanded_tiles()[0])
+
+
+def paged_attention_latent_expanded(q_nope, q_rope, pool, w_uk, w_uv,
+                                    tables, sids, ls, scale, runs):
+    """The EXPANDED form of latent attention over pages, for the rows
+    of `runs.expanded` (the `latent_run_layout` of these rows): q_nope
+    [T, H, nope] and q_rope [T, H, rope] as the model has them before
+    any absorption, pool [N, P, R] (`[c | k_r | zeros]` a row), w_uk
+    [H, nope, latent], w_uv [H, latent, v]. Per head softmax((q_nope ·
+    (c W_UKᵀ) + q_rope · k_r) · scale) · (c W_UV) over the row's slot's
+    positions below its kv length, the up-projected rows rounded to the
+    pool's dtype: out [T, H, v] in the pool's dtype, no W_UV left to
+    apply; zeros in every row outside the runs. The Pallas kernel that
+    up-projects in VMEM on a TPU, `paged_attention_latent_expanded_jnp`
+    elsewhere."""
+    if not _pallas_backend_ok():
+        return paged_attention_latent_expanded_jnp(
+            q_nope, q_rope, pool, w_uk, w_uv, tables, sids,
+            jnp.where(runs.expanded, ls, 0), scale)
+    from ...ops.pallas_kernels import paged_attention as pa_kernel
+
+    latent, rope = w_uk.shape[2], q_rope.shape[-1]
+    # [q_nope | q_rope | zeros]: the rotary part as wide as the pool
+    # row's lanes past the latent (whose lanes past k_r hold zeros)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(pool.dtype)
+    q = jnp.pad(q, ((0, 0), (0, 0),
+                    (0, pool.shape[-1] - latent - rope)))
+    tokens, heads, _ = q.shape
+    out = pa_kernel.latent_expanded_attention(
+        q.reshape(tokens, -1)[runs.src], pool, w_uk, w_uv, tables,
+        runs.run_slots, runs.run_row0, runs.run_first, runs.run_rows, scale)
+    return jnp.where(runs.expanded[:, None], out[runs.dest], 0).reshape(
+        tokens, heads, -1)
+
+
+def paged_attention_latent_expanded_jnp(q_nope, q_rope, pool, w_uk, w_uv,
+                                        tables, sids, ls, scale):
+    """`paged_attention_latent_expanded` in plain jnp (every live row):
+    what every non-TPU backend runs and what the kernel is compared
+    with. It up-projects EVERY slot's positions: small shapes only."""
+    import jax
+
+    n_pages, page_size, row = pool.shape
+    latent, rope = w_uk.shape[2], q_rope.shape[-1]
+    n_slots, pages_per_seq = tables.shape
+    L = pages_per_seq * page_size
+    dt, f32 = pool.dtype, jnp.float32
+    ls = ls.astype(jnp.int32)
+    sids = sids.astype(jnp.int32)
+    l_idx = jnp.arange(L, dtype=jnp.int32)
+    phys = (tables.astype(jnp.int32)[:, l_idx // page_size]
+            * page_size + (l_idx % page_size)[None, :])   # [S, L]
+    rows = pool.reshape(n_pages * page_size, row)[phys]   # [S, L, R]
+    lat, kr = rows[..., :latent], rows[..., latent:latent + rope]
+    k_nope = jnp.einsum("slc,hnc->shln", lat, w_uk,
+                        preferred_element_type=f32).astype(dt)
+    v = jnp.einsum("slc,hcv->shlv", lat, w_uv,
+                   preferred_element_type=f32).astype(dt)
+    sc = jnp.einsum("thn,thln->thl", q_nope.astype(dt), k_nope[sids],
+                    preferred_element_type=f32) \
+        + jnp.einsum("thr,tlr->thl", q_rope.astype(dt), kr[sids],
+                     preferred_element_type=f32)
+    sc = jnp.where(l_idx[None, None, :] < ls[:, None, None], sc * scale,
+                   f32(-1e30))
+    w = jax.nn.softmax(sc, axis=-1).astype(dt)
+    o = jnp.einsum("thl,thlv->thv", w, v[sids],
+                   preferred_element_type=f32).astype(dt)
+    return jnp.where((ls > 0)[:, None, None], o, jnp.zeros_like(o))
 
 
 def _pallas_backend_ok():

@@ -1074,3 +1074,317 @@ def latent_paged_attention(q, pool, page_tables, slot_ids, kv_lens, v_dim,
                 jnp.asarray(kv_lens, jnp.int32),
                 jnp.asarray(frontier_offset, jnp.int32).reshape((1,)),
                 q, pool)
+
+
+# ---- the latent walk, EXPANDED -----------------------------------------
+# Below the absorbed walk on purpose (nothing above moves).
+#
+# The same attention in its other form, for a RUN of one slot's rows at
+# consecutive positions (a prefill chunk): the absorbed form pays
+# 2·(2·latent + rope) FLOPs a head a row attended, the expanded form
+# 2·(nope + rope + v) plus the up-projection 2·latent·(nope + v) a head a
+# cached row ONCE A RUN: at 64 heads of 128 + 64 / 128 over a latent of
+# 512 the two cross at 171 rows. One grid step holds a few heads'
+# queries of EVERY run of the launch (`[total, nope + rope']` a head)
+# and, per run, walks the slot's live pages in tiles of tokens with the
+# absorbed walk's double-buffered copies; a tile is up-projected in VMEM
+# for each head of the step (`k_nope = c · W_UKᵀ`, `v = c · W_UV`, rounded
+# to the pool's dtype as the eager forward rounds them) and then meets
+# the run's rows a SUB-BLOCK at a time: scores formed transposed `[tile,
+# sub]` (max and sum are lane-major rows, reduced over sublanes: what
+# the resident flash kernel learned, PERF.md §6, PR 31) and the
+# accumulator transposed with them (`vᵀ[v, tile] · p[tile, sub]`: no
+# transpose of p or of its rescale a tile), no mask where the
+# sub-block's first row sees the whole tile, nothing at all where its
+# last row sees none of it. The score tile never leaves VMEM.
+
+# a run starts at a multiple of this many laid-out rows (a sublane tile
+# of a 16-bit query, two of a 32-bit one), so a sub-block is read and
+# written at an aligned row wherever its run starts
+LATENT_EXPANDED_ROW_ALIGN = 16
+
+
+def latent_expanded_tiles():
+    """(rows a sub-block, tokens a tile) of the expanded walk. One
+    slot's 2 016 rows ending at 13 312, bf16, 64 heads, 4 a grid step,
+    ms a layer on a v5e, the absorbed walk's 26.07 beside them: (256,
+    512) 11.60, (256, 1 024) 10.80, (256, 2 048) 10.76, (512, 512)
+    11.04, (512, 1 024) 10.72, (512, 2 048) 12.07, (1 024, 512) 11.09,
+    (1 024, 1 024) 11.54, (1 024, 2 048) 15.31; from the start of a
+    prompt (992 rows) 0.70–0.78 to a tile of 1 024, 1.18–2.01 at 2 048:
+    PERF.md §6, PR 34, step 0."""
+    return 512, 1024
+
+
+def _latent_expanded_kernel(slot_ref, row0_ref, first_ref, rows_ref, pt_ref,
+                            q_ref, wuk_ref, wuv_ref, pool_hbm, o_ref, buf,
+                            sems, acc_ref, m_ref, l_ref, *, pages_per_seq,
+                            tile_pages, sub, latent, scale):
+    """One grid step = `hh` heads of every run. `q_ref` [total, hh ·
+    (nope + rope')] (rope' the rotary lanes padded with zeros to the
+    pool row's lanes past the latent), `wuk_ref` [hh, nope, latent],
+    `wuv_ref` [hh, v, latent], `o_ref` [total, hh·v]. Run r (scalar
+    prefetch), in the order of their rows: slot `slot_ref[r]`,
+    `rows_ref[r]` live rows (0: no run) from row `row0_ref[r]` (a
+    multiple of `LATENT_EXPANDED_ROW_ALIGN`), row i of it at kv length
+    `first_ref[r] + i`. A run's last sub-block runs past its rows (over
+    the next run's, which that run then writes, or over rows nobody
+    reads). `buf` [2, tile, R]: two halves, in each the tile's pages
+    one under the other."""
+    g = pl.program_id(0)
+    hh, nope, vd = wuk_ref.shape[0], wuk_ref.shape[1], wuv_ref.shape[1]
+    qd = q_ref.shape[1] // hh
+    tile = buf.shape[1]
+    page_size = tile // tile_pages
+    dt = buf.dtype
+    precision = (jax.lax.Precision.HIGHEST if dt == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+
+    def dot(a, b, contract):
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=jnp.float32)
+
+    nt, nn = ((1,), (1,)), ((1,), (0,))     # a · bᵀ, a · b
+
+    # a last tile's dead pages are never copied: what they multiply
+    # (weight exactly 0) must be finite
+    @pl.when(g == 0)
+    def _zero():
+        buf[...] = jnp.zeros_like(buf)
+
+    # a transposed score's kv position in its tile less its row in its
+    # sub-block: the causal mask is ONE compare with a scalar
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, (tile, sub), 0)
+             - jax.lax.broadcasted_iota(jnp.int32, (tile, sub), 1))
+
+    def one_run(r, carry):
+        n = rows_ref[r]
+
+        @pl.when(n > 0)
+        def _run():
+            first = first_ref[r]
+            table = slot_ref[r] * pages_per_seq
+            row0 = row0_ref[r]
+            nsb = (n + (sub - 1)) // sub
+            n_pages = jnp.minimum(
+                (first + n - 1 + (page_size - 1)) // page_size,
+                pages_per_seq)
+            n_tiles = (n_pages + (tile_pages - 1)) // tile_pages
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+            def rows_of(s):
+                return pl.ds(pl.multiple_of(
+                    row0 + s * sub, LATENT_EXPANDED_ROW_ALIGN), sub)
+
+            def copies(ti, half, start):
+                def one(i, c):
+                    page = pt_ref[table + ti * tile_pages + i] if start \
+                        else 0
+                    dma = pltpu.make_async_copy(
+                        pool_hbm.at[page],
+                        buf.at[half, pl.ds(i * page_size, page_size), :],
+                        sems.at[half])
+                    dma.start() if start else dma.wait()
+                    return c
+
+                jax.lax.fori_loop(
+                    0, jnp.minimum(tile_pages, n_pages - ti * tile_pages),
+                    one, None)
+
+            def fold(h, s, st, vt):
+                m_prev = m_ref[h, s, :1, :]                  # [1, sub]
+                l_prev = l_ref[h, s, :1, :]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(st, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # every row saw position 0 in the run's first tile, so
+                # m_new is finite wherever a masked score is folded
+                pt = jnp.exp(st - m_new)
+                l_new = alpha * l_prev + jnp.sum(pt, axis=0, keepdims=True)
+                acc_ref[h, s] = acc_ref[h, s] * alpha + dot(
+                    vt, pt.astype(dt), nn)                   # [v, sub]
+                m_ref[h, s] = jnp.broadcast_to(m_new, (8, sub))
+                l_ref[h, s] = jnp.broadcast_to(l_new, (8, sub))
+
+            copies(0, 0, True)
+
+            def one_tile(ti, carry):
+                half = ti % 2
+
+                @pl.when(ti + 1 < n_tiles)
+                def _next():
+                    copies(ti + 1, 1 - half, True)
+
+                copies(ti, half, False)
+                j0 = ti * tile
+                rows = buf[half]
+                lat, kr = rows[:, :latent], rows[:, latent:]
+                # sub-blocks from `lo` see some of the tile (the last
+                # row of sub-block s sees positions below first +
+                # (s + 1)·sub - 1), those from `mid` all of it
+                lo = jnp.minimum(jnp.maximum(j0 - first + 1, 0) // sub, nsb)
+                mid = jnp.clip(
+                    (jnp.maximum(j0 + tile - first, 0) + (sub - 1)) // sub,
+                    lo, nsb)
+                # every head's up-projection first, then a sub-block
+                # meets all of them in one loop body: the heads' chains
+                # (product, softmax, product) are independent and
+                # overlap (10.98 against 11.48 ms: PERF.md §6, PR 34)
+                ks = [dot(lat, wuk_ref[h], nt).astype(dt) for h in range(hh)]
+                vts = [dot(wuv_ref[h], lat, nt).astype(dt)   # [v, tile]
+                       for h in range(hh)]
+
+                def attend(s, masked):
+                    at = rows_of(s)
+                    keep = ahead < first + s * sub - j0
+                    for h in range(hh):
+                        q = q_ref[at, h * qd:(h + 1) * qd]
+                        # scaled in f32 AFTER the product, as the
+                        # absorbed walk: q is not rounded again
+                        st = (dot(ks[h], q[:, :nope], nt)
+                              + dot(kr, q[:, nope:], nt)) * scale
+                        if masked:
+                            st = jnp.where(keep, st, NEG_INF)
+                        fold(h, s, st, vts[h])
+
+                jax.lax.fori_loop(lo, mid, lambda s, c: attend(s, True),
+                                  None)
+                jax.lax.fori_loop(mid, nsb, lambda s, c: attend(s, False),
+                                  None)
+                return carry
+
+            jax.lax.fori_loop(0, n_tiles, one_tile, None)
+
+            def finish(s, carry):
+                for h in range(hh):
+                    o = (acc_ref[h, s] / l_ref[h, s, :1, :]).T
+                    o_ref[rows_of(s), h * vd:(h + 1) * vd] = o.astype(
+                        o_ref.dtype)
+                return carry
+
+            jax.lax.fori_loop(0, nsb, finish, None)
+
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0], one_run, None)
+
+
+def _latent_expanded_heads(heads, v_dim):
+    """Heads a grid step: the fewest whose values fill whole 128-lane
+    tiles of the `[total, H·v]` result, doubled to 4 while they divide
+    the heads (a tile's copies are issued once for all of them, and
+    their chains overlap: 11.39 / 10.72 ms at 2 / 4; 13.11 / 12.14 /
+    11.69 / 11.64 at 1 / 2 / 4 / 8 in an earlier form of the kernel:
+    PERF.md §6, PR 34, step 0); all the heads where no such count
+    divides them."""
+    hh = 128 // math.gcd(128, v_dim)
+    while hh < 4 and heads % (2 * hh) == 0:
+        hh *= 2
+    return hh if heads % hh == 0 else heads
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_expanded_call(q_shape, q_dtype, pool_shape, pool_dtype,
+                          wuk_shape, wuv_shape, pages_per_seq, max_runs,
+                          sub, tile_tokens, hh, scale, interpret):
+    """The expanded launch for one set of static shapes, ONE jitted
+    function a set (as `_paged_call`)."""
+    total, width = q_shape
+    _, page_size, row = pool_shape
+    heads, nope, latent = wuk_shape
+    vd = wuv_shape[1]
+    if wuv_shape != (heads, vd, latent):
+        raise ValueError(
+            f"W_UK {wuk_shape} / W_UVᵀ {wuv_shape} are not [heads, nope, "
+            f"latent] / [heads, v, latent] of the same heads")
+    qd = nope + row - latent
+    if width != heads * qd or not 0 < latent < row:
+        raise ValueError(
+            f"a head's query is [nope | rotary padded to the row's lanes "
+            f"past the latent]: {heads} · ({nope} + {row - latent}), got "
+            f"{width}")
+    if not _latent_walks(page_size, pool_dtype):
+        raise ValueError(
+            f"a latent {jnp.dtype(pool_dtype).name} pool needs pages of "
+            f"whole sublane tiles; got page_size {page_size}")
+    if total < sub or total % LATENT_EXPANDED_ROW_ALIGN or heads % hh:
+        raise ValueError(
+            f"{total} rows of {heads} heads are not at least a sub-block "
+            f"of {sub}, whole tiles of {LATENT_EXPANDED_ROW_ALIGN} rows "
+            f"and steps of {hh} heads")
+    tile_pages = max(1, min(pages_per_seq, int(tile_tokens) // page_size))
+    tile = tile_pages * page_size
+    nsub = -(-total // sub)
+
+    launch = pl.pallas_call(
+        functools.partial(_latent_expanded_kernel,
+                          pages_per_seq=pages_per_seq,
+                          tile_pages=tile_pages, sub=sub, latent=latent,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(heads // hh,),
+            in_specs=[
+                pl.BlockSpec((total, hh * qd), lambda g, *_: (0, g)),
+                pl.BlockSpec((hh, nope, latent), lambda g, *_: (g, 0, 0)),
+                pl.BlockSpec((hh, vd, latent), lambda g, *_: (g, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=pl.BlockSpec((total, hh * vd), lambda g, *_: (0, g)),
+            scratch_shapes=[
+                pltpu.VMEM((2, tile, row), pool_dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((hh, nsub, vd, sub), jnp.float32),
+                pltpu.VMEM((hh, nsub, 8, sub), jnp.float32),
+                pltpu.VMEM((hh, nsub, 8, sub), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((total, heads * vd), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )
+
+    jitted = jax.jit(launch, inline=True)
+    jitted.body = "latent_expanded"
+    return jitted
+
+
+def latent_expanded_attention(q, pool, w_uk, w_uv, page_tables, run_slots,
+                              run_row0, run_first, run_rows, scale,
+                              sub_rows=None, tile_tokens=None,
+                              heads_per_step=None, interpret=False):
+    """The EXPANDED form over latent pages, for runs of one slot's rows
+    at consecutive positions whose own latent rows are in the pool. q
+    [total, H · (nope + rope')], a head after the other: `[q_nope |
+    q_rope | zeros]`, the rotary part padded to the pool row's lanes
+    past the latent (two dimensions: on the device `[total, H, ·]` is
+    tiled otherwise, and a reshape of `total` rows is a copy); pool
+    [N, P, R] with the latent in a row's first lanes; w_uk [H, nope,
+    latent]; w_uv [H, latent, v]; page_tables [S, MP]. Run r (`run_*` [runs] int32, in
+    the order of their rows): `run_rows[r]` live rows (0: none) of slot
+    `run_slots[r]` from row `run_row0[r]` of `total`, a multiple of
+    `LATENT_EXPANDED_ROW_ALIGN` with a whole sub-block of rows from a
+    run's last sub-block's start still inside `total` (a CONTRACT:
+    `nn.functional.attention.SlotRunLayout`), row i of it at kv length
+    `run_first[r] + i` (it attends positions below that). Returns
+    [total, H · v]: softmax((q_nope · (c W_UKᵀ) + q_rope · k_r) · scale) ·
+    (c W_UV) a head in the runs' rows, numbers nobody may use in every
+    other row. `scale` is static; `sub_rows`, `tile_tokens` and
+    `heads_per_step` override the defaults (the sweep's and the
+    tests')."""
+    heads, vd = w_uv.shape[0], w_uv.shape[2]
+    sub, tile = latent_expanded_tiles()
+    call = _latent_expanded_call(
+        q.shape, q.dtype, pool.shape, pool.dtype, w_uk.shape,
+        (heads, vd, w_uv.shape[1]), page_tables.shape[1],
+        int(run_rows.shape[0]), int(sub_rows or sub),
+        int(tile_tokens or tile),
+        int(heads_per_step or _latent_expanded_heads(heads, vd)),
+        float(scale), interpret)
+    for sites in _open_site_counts.stack:
+        sites[call.body] = sites.get(call.body, 0) + 1
+    i32 = lambda x: jnp.asarray(x, jnp.int32)   # noqa: E731
+    # [H, v, latent]: vᵀ = W_UVᵀ · cᵀ
+    return call(i32(run_slots), i32(run_row0), i32(run_first), i32(run_rows),
+                i32(page_tables).reshape(-1), q, w_uk,
+                jnp.swapaxes(w_uv, 1, 2), pool)

@@ -63,7 +63,9 @@ import numpy as np
 from ... import nn
 from ...nn import expert_layer
 from ...nn.functional import attention as _attention
-from ...nn.functional.attention import (paged_attention_latent,
+from ...nn.functional.attention import (latent_run_layout,
+                                        paged_attention_latent,
+                                        paged_attention_latent_expanded,
                                         SlotBlockLayout)
 from ...tensor_core import Tensor
 from .gpt import sample_tokens
@@ -82,6 +84,17 @@ _scope = jax.named_scope
 # context, 20.19 / 18.10 / 17.26 ms from 15 k, 3.56 / 3.55 / 3.70 ms from 0,
 # on a v5e: PERF.md §6, PR 33, step 0 (b))
 _TICK_ROWS_PER_BLOCK = 16
+
+# a run of one slot's rows in the single tick takes the EXPANDED form
+# from this many rows on: the module docstring's two forms cost the same
+# FLOPs at 171 rows of 64 heads, and on a v5e from 8 k of context the
+# expanded kernel, whose cost up to a sub-block of 512 rows is that of
+# 512, takes 2.93 / 2.93 / 2.95 ms at 128 / 256 / 512 rows where the
+# absorbed walk takes 1.20 / 2.35 / 4.63 (PERF.md §6, PR 34, step 0). A
+# tick of fewer rows holds no expanded launch at all. Also how many of
+# the rows LEFT to the absorbed form one launch of it serves (gathered,
+# a chunk of as many rows at a time, beside the expanded runs).
+_EXPANDED_MIN_ROWS = 512
 
 
 class SarvamMLAConfig:
@@ -244,6 +257,27 @@ def _row_write(pool, rows, write_idx):
     return flat.reshape(pool.shape)
 
 
+class _TickForms:
+    """Which form each row of a single tick takes, from what the tick's
+    rows are (slot_ids, kv_lens [T]); made once a tick, used by every
+    layer. `runs`: the runs of at least `_EXPANDED_MIN_ROWS` rows of one
+    slot, which attend EXPANDED. Every other live row attends ABSORBED,
+    `_EXPANDED_MIN_ROWS` of them at a time: `left` their rows in order
+    (a slot's side by side still), T where there is none, `chunks` how
+    many such chunks hold one."""
+
+    def __init__(self, slot_ids, kv_lens):
+        T = slot_ids.shape[0]
+        A = _EXPANDED_MIN_ROWS
+        self.runs = latent_run_layout(slot_ids, kv_lens, A)
+        left = (kv_lens > 0) & ~self.runs.expanded
+        rank = jnp.cumsum(left) - 1
+        self.left = jnp.full((-(-T // A) * A,), T, jnp.int32).at[
+            jnp.where(left, rank, T)].set(
+                jnp.arange(T, dtype=jnp.int32), mode="drop")
+        self.chunks = (rank[-1] + A) // A
+
+
 class SarvamMLADecoderLayer(nn.Layer):
     def __init__(self, config, index):
         super().__init__()
@@ -296,11 +330,15 @@ class SarvamMLAForCausalLM(nn.Layer):
     # int32 counters the step bodies return: the expert layer's three
     # (summed over sparse layers); latent rows the step's queries must
     # read (a row once a layer a slot) and those of them read for a
-    # slot's ONE query row; the step's query rows by form (all × layers)
+    # slot's ONE query row; the step's query rows by form (all × layers).
+    # The last is the ENGINE's count (`_note_launches` adds a tick's
+    # expanded launches to the entry of `stats` this name opens); the
+    # step bodies add 0 to it
     step_counters = ("moe_assignments", "moe_assignments_held",
                      "moe_experts_touched", "mla_rows_attended_least",
                      "mla_rows_attended_single", "mla_rows_absorbed",
-                     "mla_rows_expanded")
+                     "mla_rows_expanded",
+                     "paged_attn_latent_expanded_launches")
 
     def __init__(self, config):
         super().__init__()
@@ -441,25 +479,81 @@ class SarvamMLAForCausalLM(nn.Layer):
 
     # ---- the engine's step bodies -----------------------------------
 
+    def _walk_absorbed(self, layer, q_nope, q_rope, pool, page_tables,
+                       slot_ids, kv_lens, frontier_offset, layout):
+        """o [T, H, v] float32 of rows that attend ABSORBED: W_UK folded
+        into the query, the walk over the rows' latent pages, W_UV on
+        what it returns."""
+        c = self.config
+        with _scope("mla_q"):
+            qa = self._absorb(layer, q_nope, q_rope)
+            qa = jnp.pad(qa, ((0, 0), (0, 0),
+                              (0, pool.shape[-1] - qa.shape[-1])))
+        with _scope("mla_walk"):
+            oc = paged_attention_latent(
+                qa, pool, page_tables, slot_ids, kv_lens, c.kv_lora_rank,
+                c.softmax_scale(), frontier_offset, layout)
+        with _scope("mla_out"):
+            w = layer.w_uv._value
+            return _heads_mm(oc.astype(w.dtype), w)
+
+    def _walk_by_form(self, layer, q_nope, q_rope, pool, page_tables,
+                      slot_ids, kv_lens, forms, blocks):
+        """o [T, H, v] of a tick whose long runs attend EXPANDED and
+        whose other rows ABSORBED (`forms`, a `_TickForms`), merged by
+        row: the same numbers a row whichever form serves it, to the
+        rounding of the products. The absorbed rows are gathered a
+        chunk of `_EXPANDED_MIN_ROWS` at a time (one chunk beside a long
+        run, as a rule), so that form's products and its launch's
+        blocks are those of the rows it serves, not of the tick's."""
+        c = self.config
+        T, A = slot_ids.shape[0], _EXPANDED_MIN_ROWS
+        with _scope("mla_walk"):
+            o = paged_attention_latent_expanded(
+                q_nope, q_rope, pool, layer.w_uk._value, layer.w_uv._value,
+                page_tables, slot_ids, kv_lens, c.softmax_scale(),
+                forms.runs)
+
+        def chunk(k, o):
+            rows = jax.lax.dynamic_slice(forms.left, (k * A,), (A,))
+            at = jnp.minimum(rows, T - 1)
+            sids = slot_ids[at]
+            lens = jnp.where(rows < T, kv_lens[at], 0)
+            layout = SlotBlockLayout(
+                sids, lens, _TICK_ROWS_PER_BLOCK,
+                min(A, page_tables.shape[0])) if blocks else None
+            got = self._walk_absorbed(
+                layer, q_nope[at], q_rope[at], pool, page_tables, sids,
+                lens, None, layout)
+            return o.at[rows].set(got.astype(o.dtype), mode="drop")
+
+        return jax.lax.fori_loop(0, forms.chunks, chunk, o)
+
     def _paged_core(self, tok, pos, slot_ids, write_idx, page_tables,
                     kv_lens, sample_idx, kv, frontier_offset=None,
                     slot_blocks=False):
         """Raw arrays; ONE cache kind, so write_idx [T], page_tables
         [S, MP] and `kv` one latent pool a layer. `slot_blocks`: the
         single tick's rows (a slot's side by side), which the walk takes
-        in blocks of `_TICK_ROWS_PER_BLOCK` of one slot; the fused
-        window has one row a slot. Returns (logits [S, vocab] float32,
-        new kv, counters int32 [7])."""
+        in blocks of `_TICK_ROWS_PER_BLOCK` of one slot, and in the
+        EXPANDED form where a slot has `_EXPANDED_MIN_ROWS` of them (a
+        tick of fewer rows, or a pool in another dtype than the weights
+        that expand it, has one form); the fused window has one row a
+        slot. Returns (logits [S, vocab] float32, new kv, counters
+        int32 [7])."""
         c = self.config
         T = tok.shape[0]
         n_slots = page_tables.shape[0]
         valid = kv_lens > 0
         tables = _rope_tables(c, pos)
-        scale = c.softmax_scale()
         row_store = kv[0].shape[-1]
-        layout = None
-        if slot_blocks and _attention._pallas_backend_ok():
-            with _scope("attn"):
+        blocks = slot_blocks and _attention._pallas_backend_ok()
+        layout = forms = None
+        with _scope("attn"):
+            if slot_blocks and T >= _EXPANDED_MIN_ROWS \
+                    and kv[0].dtype == self.layers[0].w_uk._value.dtype:
+                forms = _TickForms(slot_ids, kv_lens)
+            elif blocks:
                 layout = SlotBlockLayout(slot_ids, kv_lens,
                                          _TICK_ROWS_PER_BLOCK, n_slots)
         with _scope("embed"):
@@ -480,17 +574,16 @@ class SarvamMLAForCausalLM(nn.Layer):
                     pool = _row_write(kv[i], row, write_idx)
                 with _scope("mla_q"):
                     q_nope, q_rope = self._queries(layer, n, tables)
-                    qa = self._absorb(layer, q_nope, q_rope)
-                    qa = jnp.pad(qa, ((0, 0), (0, 0),
-                                      (0, row_store - qa.shape[-1])))
-                with _scope("mla_walk"):
-                    oc = paged_attention_latent(
-                        qa, pool, page_tables, slot_ids, kv_lens,
-                        c.kv_lora_rank, scale, frontier_offset, layout)
+                if forms is None:
+                    o = self._walk_absorbed(
+                        layer, q_nope, q_rope, pool, page_tables, slot_ids,
+                        kv_lens, frontier_offset, layout)
+                else:
+                    o = self._walk_by_form(
+                        layer, q_nope, q_rope, pool, page_tables, slot_ids,
+                        kv_lens, forms, blocks)
                 with _scope("mla_out"):
-                    w = layer.w_uv._value
-                    x = self._attn_out(
-                        layer, x, _heads_mm(oc.astype(w.dtype), w))
+                    x = self._attn_out(layer, x, o)
             new_kv.append(pool)
             x, cnt = self._ffn(layer, x, valid)
             moe = moe + cnt
@@ -502,9 +595,11 @@ class SarvamMLAForCausalLM(nn.Layer):
             lens, slot_ids, num_segments=n_slots).clip(0)
         alone = jax.ops.segment_sum(
             valid.astype(jnp.int32), slot_ids, num_segments=n_slots) == 1
+        expanded = (jnp.zeros((), jnp.int32) if forms is None
+                    else jnp.sum(forms.runs.expanded))
         counters = jnp.concatenate([moe, c.num_layers * jnp.stack([
             jnp.sum(longest), jnp.sum(jnp.where(alone, longest, 0)),
-            jnp.sum(valid), jnp.zeros((), jnp.int32)]).astype(jnp.int32)])
+            jnp.sum(valid) - expanded, expanded]).astype(jnp.int32)])
         with _scope("lm_head"):
             x = x[sample_idx]
         return self._head(x), new_kv, counters
@@ -514,7 +609,7 @@ class SarvamMLAForCausalLM(nn.Layer):
                            kv_scales=None, frontier_offset=None,
                            max_q_per_slot=None):
         """The single tick (serving_protocol.py): Tensors in and out;
-        returns (logits [1, S, vocab], *new pools, counters)."""
+        returns (logits [1, S, vocab], *new pools, counters [8])."""
         if kv_scales:
             raise ValueError("SarvamMLAForCausalLM serves float pools")
         val = lambda t: None if t is None else t._value   # noqa: E731
@@ -523,7 +618,8 @@ class SarvamMLAForCausalLM(nn.Layer):
             val(page_tables), val(kv_lens), val(sample_idx),
             [val(p) for p in kv], val(frontier_offset), slot_blocks=True)
         t = lambda v: Tensor(v, stop_gradient=True)       # noqa: E731
-        return (t(logits[None]), *[t(p) for p in new_kv], t(counters))
+        return (t(logits[None]), *[t(p) for p in new_kv],
+                t(jnp.pad(counters, (0, 1))))
 
     def _paged_decode_fused(self, k, page_size, tok0, pos0, rem, fin0,
                             eos_ids, temps, top_ps, streams, page_tables,
@@ -531,7 +627,7 @@ class SarvamMLAForCausalLM(nn.Layer):
                             gstate0=None, gtrans=None, gmask=None):
         """`k` decode iterations in one scan, sampling inside (the
         contract of `LagunaForCausalLM._paged_decode_fused`). Returns
-        (emits [k, S], new kv, [], counters [k, 7])."""
+        (emits [k, S], new kv, [], counters [k, 8])."""
         if lag is not None or gtrans is not None or kv_scales:
             raise ValueError(
                 "SarvamMLAForCausalLM's fused window takes no draft lag, "
@@ -566,4 +662,4 @@ class SarvamMLAForCausalLM(nn.Layer):
         (_, _, kv_f), (emits, counters) = jax.lax.scan(
             body, (tok0, fin0, list(kv)),
             jnp.arange(int(k), dtype=jnp.int32))
-        return emits, kv_f, [], counters
+        return emits, kv_f, [], jnp.pad(counters, ((0, 0), (0, 1)))

@@ -160,6 +160,106 @@ def test_latent_walk_compiles_for_v5e(one_chip, tokens, qps, group):
     assert "tpu_custom_call" in text
 
 
+# The EXPANDED latent walk at the same serving shapes: a tick of 2 048
+# rows laid out with its runs at multiples of 16 rows (`SlotRunLayout`:
+# at most 4 runs of 512 rows, 2 048 + 4 · 15 rows and a sub-block of 512
+# to spare), queries `[nope | rope | zeros]` 256 wide a head, W_UK / W_UV
+# a head. (id, laid-out rows, runs)
+_LATENT_EXPANDED_LAUNCHES = [
+    ("cell_tick_runs_of_512", 2048 + 64 + 512, 4),
+    ("one_sub_block", 512, 1),
+]
+
+
+@pytest.mark.parametrize(
+    "total,runs",
+    [pytest.param(*c[1:], id=c[0]) for c in _LATENT_EXPANDED_LAUNCHES])
+def test_latent_expanded_walk_compiles_for_v5e(one_chip, total, runs):
+    """Inside the VMEM it asks for (`_LATENT_VMEM_LIMIT_BYTES`): 4
+    heads' queries, accumulators and results of every laid-out row
+    beside two tiles of 1 024 tokens and a `[1 024, 512]` score."""
+    from paddle_tpu.ops.pallas_kernels.paged_attention import (
+        latent_expanded_attention)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((total, 64 * 256), jnp.bfloat16),
+            sds((31458, 16, 640), jnp.bfloat16),
+            sds((64, 128, 512), jnp.bfloat16),
+            sds((64, 512, 128), jnp.bfloat16),
+            sds((32, 1024), jnp.int32)] + [sds((runs,), jnp.int32)] * 4
+
+    def call(q, pool, w_uk, w_uv, pt, slots, row0, first, rows):
+        return latent_expanded_attention(q, pool, w_uk, w_uv, pt, slots,
+                                         row0, first, rows, 0.1)
+
+    with jax.enable_x64(False):
+        text = jax.jit(call).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_sarvam_tick_holds_an_expanded_and_an_absorbed_walk_a_layer(
+        one_chip, monkeypatch):
+    """sarvam-105b's tick program at the serving widths (a dense and a
+    sparse layer of the five), traced as on a TPU: a layer's custom
+    calls are the expanded walk, the absorbed walk (inside the loop over
+    chunks of the rows left to it) and, in the sparse layer, the
+    experts' two grouped products; nothing else is a kernel."""
+    from paddle_tpu.nn import expert_layer
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.text.models.sarvam_mla import (SarvamMLAConfig,
+                                                   SarvamMLAForCausalLM)
+
+    monkeypatch.setattr(attention, "_pallas_backend_ok", lambda: True)
+    monkeypatch.setattr(expert_layer, "_pallas_backend_ok", lambda: True)
+    layers, rows, slots = 2, 2048, 32
+    model = SarvamMLAForCausalLM(SarvamMLAConfig(
+        vocab_size=65536, hidden_size=4096, num_layers=layers,
+        num_heads=64, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=16384,
+        moe_intermediate_size=2048, num_routed_experts=128,
+        num_experts_per_tok=8, num_experts_held=32,
+        routed_scaling_factor=2.5, max_seq_len=16384, dtype="bfloat16",
+        init_weights=False))
+    params = list(model.state_dict().values())
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(values, tok, pos, sid, widx, pt, klen, smp, kv):
+        for p, v in zip(params, values):
+            p._value = v
+        return model._paged_core(tok, pos, sid, widx, pt, klen, smp, kv,
+                                 slot_blocks=True)
+
+    shapes = [sds(p._value.shape, p._value.dtype) for p in params]
+    # (the suite's process-wide "highest" is no precision the experts'
+    # grouped product has for bf16 operands; the chip runs without it)
+    before = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    try:
+        with jax.enable_x64(False):
+            text = jax.jit(step, donate_argnums=(8,)).lower(
+                shapes, sds((rows,)), sds((rows,)), sds((rows,)),
+                sds((rows,)), sds((slots, 1024)), sds((rows,)),
+                sds((slots,)),
+                [sds((31458, 16, 640), jnp.bfloat16)] * layers
+            ).compile().as_text()
+    finally:
+        jax.config.update("jax_default_matmul_precision", before)
+        for p, v in zip(params, shapes):
+            p._value = v
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    walks = [ln for ln in calls if "mla_walk/pallas_call" in ln]
+    assert len(walks) == 2 * layers
+    assert sum("while/body/mla_walk" in ln for ln in walks) == layers
+    assert sum("mla_expand" in ln for ln in calls) == 0
+    assert len([ln for ln in calls if "moe_experts" in ln]) == 2
+    assert len(calls) == 2 * layers + 2
+
+
 def test_mosaic_refuses_a_page_slice_of_576_lanes(one_chip):
     """Why the latent row is STORED 640 wide (`CacheKind.row_store`):
     the device tiles a `[pages, 16, 576]` pool at 640 lanes anyway, and

@@ -16,6 +16,14 @@ bf16, 64 heads, rows of 576 stored as 640 lanes, pages of 16, best of
            (gather the slot's latent rows, up-project k_nope and v, a
            causal product a block of queries): ms and temporaries, at
            token budgets 512 / 1 024 / 2 048
+  expanded the EXPANDED form as a kernel that up-projects in VMEM
+           (`latent_expanded_attention`): one slot's 2 016 rows ending
+           at 13 312 and 992 rows from c0 = 0 / 8 192 / 15 360, by rows a
+           sub-block, tokens a tile and heads a grid step: ms a layer,
+           % of 197 TFLOP/s on the expanded FLOPs + the expansion, the
+           largest gap to the absorbed walk's numbers; then short runs
+           from 8 192 in both forms (where the forms cross), and what
+           the absorbed launch costs when every row of it is dead
 
 Fails where JAX finds no TPU; `--rehearse-cpu` runs tiny shapes through
 the Pallas interpreter to debug the script (its times mean nothing).
@@ -51,7 +59,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--out", default="chiprun_out")
     ap.add_argument("--rehearse-cpu", action="store_true")
-    ap.add_argument("--tables", default="decode,tick,expand")
+    ap.add_argument("--tables", default="decode,tick,expand,expanded")
     args = ap.parse_args(argv)
 
     import jax
@@ -60,7 +68,7 @@ def main(argv=None):
 
     from paddle_tpu.nn.functional.attention import SlotBlockLayout
     from paddle_tpu.ops.pallas_kernels.paged_attention import (
-        latent_paged_attention)
+        latent_expanded_attention, latent_paged_attention)
 
     rehearse = args.rehearse_cpu
     if not rehearse and jax.default_backend() != "tpu":
@@ -94,7 +102,8 @@ def main(argv=None):
         return lambda q, sids, lens: jitted(pool, tables, q, sids, lens)
 
     contexts = (60, 200, 250) if rehearse else (10240, 13312, 16384)
-    if "decode" in args.tables:
+    tables_asked = args.tables.split(",")
+    if "decode" in tables_asked:
         q = jnp.asarray(rng.normal(size=(S, H, RS)), dt)
         sids = jnp.arange(S, dtype=jnp.int32)
         for gt in ((32, 64) if rehearse else (256, 512, 1024, 2048, 4096)):
@@ -134,7 +143,7 @@ def main(argv=None):
         if key not in cache:
             cache[key] = jax.jit(fn)
         return cache[key]
-    if "tick" in args.tables:
+    if "tick" in tables_asked:
         q = jnp.asarray(rng.normal(size=(T, H, RS)), dt)
         for qb in ((4,) if rehearse else (8, 16, 32)):
             for gt in ((32,) if rehearse else (1024, 2048)):
@@ -163,11 +172,11 @@ def main(argv=None):
                           "pct_peak_absorbed": 100 * flops / (ms / 1e3)
                           / PEAK_BF16})
 
-    if "expand" in args.tables:
-        nope, vd = (16, 16) if rehearse else (128, 128)
-        rope = R - V
-        w_uk = jnp.asarray(rng.normal(size=(H, nope, V)) * 0.02, dt)
-        w_uv = jnp.asarray(rng.normal(size=(H, V, vd)) * 0.02, dt)
+    nope, vd = (16, 16) if rehearse else (128, 128)
+    rope = R - V
+    w_uk = jnp.asarray(rng.normal(size=(H, nope, V)) * 0.02, dt)
+    w_uv = jnp.asarray(rng.normal(size=(H, V, vd)) * 0.02, dt)
+    if "expand" in tables_asked:
 
         def expanded(pool, tables, qn, qr, c0, slot, qblock):
             """One slot's `rows` prompt rows from context c0: gather
@@ -239,6 +248,117 @@ def main(argv=None):
                     continue
                 note({"table": "expand", "budget": budget, "rows": rows,
                       "absorbed_walk_qb": qb, "ms": ms})
+
+    if "expanded" in tables_asked:
+        total = 64 if rehearse else 2048 + 512   # a sub-block to spare
+        max_runs = 4
+        qd = nope + RS - V
+        qx = rng.normal(size=(total, H, nope + rope))
+        qx = jnp.asarray(np.concatenate(
+            [qx, np.zeros((total, H, qd - nope - rope))], -1), dt)
+
+        def one_run(first, n):
+            z = np.zeros(max_runs, np.int32)
+            return tuple(jnp.asarray(np.concatenate([[v], z[1:]]), jnp.int32)
+                         for v in (S - 1, 0, first, n))
+
+        def expanded(sub, tile, hh):
+            def fn(pool, tables, q, wk, wv, slots, row0, first, rows):
+                return latent_expanded_attention(
+                    q, pool, wk, wv, tables, slots, row0, first, rows,
+                    scale, sub_rows=sub, tile_tokens=tile,
+                    heads_per_step=hh, interpret=rehearse)
+            return jitted(fn, "expanded", sub, tile, hh)
+
+        def absorbed_rows(n, first, qb, gt):
+            """The absorbed walk + the up-projection of its result over
+            the run's rows: what the expanded kernel must equal."""
+            def fn(pool, tables, q, wk, wv, first):
+                qa = jnp.einsum("thn,hnc->thc", q[:, :, :nope], wk,
+                                preferred_element_type=jnp.float32)
+                qa = jnp.concatenate(
+                    [qa.astype(dt), q[:, :, nope:]], -1)    # [n, H, RS]
+                lens = first + jnp.arange(q.shape[0], dtype=jnp.int32)
+                o = latent_paged_attention(
+                    qa, pool, tables, jnp.full((q.shape[0],), S - 1), lens,
+                    V, scale, q_per_slot=qb, group_tokens=gt,
+                    interpret=rehearse)
+                return jnp.einsum("thc,hcv->thv", o, wv,
+                                  preferred_element_type=jnp.float32)
+            return jitted(fn, "absorbed_rows", n, qb, gt)(
+                pool, tables, qx[:n], w_uk, w_uv, jnp.int32(first))
+
+        def flops(first, n):
+            attended = n * first + n * (n - 1) / 2
+            return (2 * H * (nope + rope + vd) * attended
+                    + 2 * H * V * (nope + vd) * (first + n - 1))
+
+        cases = ([(40, 100), (24, 0)] if rehearse else
+                 [(2016, 13312 - 2016), (992, 0), (992, 8192), (992, 15360)])
+        grid = ([(8, 32, 2), (16, 16, 4)] if rehearse else
+                [(sub, tile, hh) for sub in (256, 512, 1024)
+                 for tile in (512, 1024, 2048) for hh in (2, 4)])
+        # two dimensions, as the kernel takes them (on the device a
+        # reshape of `[total, H, ·]` is a copy, and would be timed)
+        q2 = qx.reshape(total, -1)
+        want = {}
+        for sub, tile, hh in grid:
+            for n, c0 in cases:
+                run = one_run(c0 + 1, n)
+                row = {"table": "expanded", "sub": sub, "tile": tile,
+                       "heads": hh, "rows": n, "c0": c0}
+                try:
+                    launch = expanded(sub, tile, hh)
+                    ops = (pool, tables, q2, w_uk, w_uv, *run)
+                    ms = _best(launch, ops)
+                    if (n, c0) not in want:
+                        qb, gt = (4, 32) if rehearse else (16, 1024)
+                        m = n - n % qb
+                        want[n, c0] = (m, np.asarray(
+                            absorbed_rows(m, c0 + 1, qb, gt), np.float32))
+                    m, ref = want[n, c0]
+                    got = np.asarray(launch(*ops)[:m], np.float32).reshape(
+                        m, H, vd)
+                    row.update(ms=ms, pct_peak=100 * flops(c0 + 1, n)
+                               / (ms / 1e3) / PEAK_BF16,
+                               gap_to_absorbed=float(
+                                   np.abs(got - ref).max()),
+                               ref_abs_max=float(np.abs(ref).max()))
+                except Exception as e:  # noqa: BLE001
+                    row["error"] = str(e)[:300]
+                note(row)
+        # where the forms cross: a short run from 8 k, both forms
+        sub, tile = (8, 32) if rehearse else (None, None)
+        for n in ((8, 16) if rehearse else (64, 128, 256, 512)):
+            c0 = 100 if rehearse else 8192
+            row = {"table": "expanded", "crossover_rows": n, "c0": c0}
+            try:
+                row["ms_expanded"] = _best(
+                    expanded(sub, tile, None),
+                    (pool, tables, q2, w_uk, w_uv, *one_run(c0 + 1, n)))
+                qb, gt = (4, 32) if rehearse else (16, 1024)
+                q = jnp.asarray(rng.normal(size=(n, H, RS)), dt)
+                row["ms_absorbed"] = _best(walk(qb, gt), (
+                    q, jnp.full((n,), S - 1, jnp.int32),
+                    c0 + 1 + jnp.arange(n, dtype=jnp.int32)))
+            except Exception as e:  # noqa: BLE001
+                row["error"] = str(e)[:300]
+            note(row)
+        # the absorbed launch over rows it no longer serves (all dead):
+        # a tick's whole block layout, and a compacted one
+        for rows_live in ((16,) if rehearse else (2048, 512, 256)):
+            qb = 4 if rehearse else 16
+            t = rows_live + min(rows_live, S) * (qb - 1)
+            t += -t % qb
+            q = jnp.zeros((t, H, RS), dt)
+            z = jnp.zeros((t,), jnp.int32)
+            try:
+                ms = _best(walk(qb, 32 if rehearse else 1024), (q, z, z))
+                note({"table": "expanded", "dead_absorbed_rows": rows_live,
+                      "layout_rows": t, "ms": ms})
+            except Exception as e:  # noqa: BLE001
+                note({"table": "expanded", "dead_absorbed_rows": rows_live,
+                      "error": str(e)[:300]})
 
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, "latent_walk_sweep.json"), "w") as f:
