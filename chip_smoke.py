@@ -527,10 +527,94 @@ def _latent_cases(sm):
     return out
 
 
+def _delta_rule_cases(sm):
+    """The gated delta rule's two forms (`nn/functional/delta_rule.py`:
+    the RECURRENT step as a Pallas kernel, the CHUNKED form in plain
+    XLA) against the recurrence a token at a time in plain float32
+    `jax.numpy` at highest precision, at Ling-3.0-flash-VL's serving
+    shape: 32 heads of 128 × 128, decays down to e^-5 a token, a run of
+    200 rows of one slot that starts mid-sequence from a state that is
+    not zero."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.nn.functional import delta_rule as dr
+    from paddle_tpu.nn.functional.attention import SlotRunLayout
+
+    H, dk, S, T, n = (2, 128, 3, 96, 70) if sm.rehearse \
+        else (32, 128, 8, 256, 200)
+    rng = np.random.default_rng(0)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)   # noqa: E731
+    q, k, v = f(T, H, dk) * dk ** -0.5, f(T, H, dk), f(T, H, dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -5.0 * jax.nn.sigmoid(2.0 * f(T, H, dk))
+    beta = jax.nn.sigmoid(f(T, H))
+    state0 = f(S, H, dk, dk)
+    sids = np.zeros((T,), np.int32)
+    lens = np.zeros((T,), np.int32)
+    sids[:n], lens[:n] = 1, 1000 + np.arange(n)
+
+    def by_token(st):
+        def step(s_, row):
+            q_t, k_t, v_t, g_t, b_t = row
+            s_ = s_ * jnp.exp(g_t)[:, :, None]
+            u = b_t[:, None] * (v_t - jnp.einsum("hkv,hk->hv", s_, k_t))
+            s_ = s_ + k_t[:, :, None] * u[:, None, :]
+            return s_, jnp.einsum("hkv,hk->hv", s_, q_t)
+        return jax.lax.scan(step, st, (q[:n], k[:n], v[:n], g[:n],
+                                       beta[:n]))
+
+    with jax.default_matmul_precision("highest"):
+        want_s, want_o = jax.jit(by_token)(state0[1])
+    out = {}
+
+    def chunked():
+        def fn(st):
+            runs = SlotRunLayout(jnp.asarray(sids), jnp.asarray(lens), 64,
+                                 dr.CHUNK, 0)
+            return dr.delta_rule_chunked(st, q, k, v, g, beta, runs)[:2]
+        o, st = jax.jit(fn)(state0)
+        return o[:n], st
+
+    def recurrent():
+        live = jnp.arange(S) == 1
+
+        def fn(st):
+            def one(t, carry):
+                o, st = carry
+                row = lambda a: jnp.broadcast_to(   # noqa: E731
+                    a[t], (S,) + a.shape[1:])
+                got, st = dr.delta_rule_step(
+                    st, row(q), row(k), row(v), row(g), row(beta), live,
+                    jnp.zeros((S,), bool),
+                    kernel=None if not sm.rehearse else False)
+                return o.at[t].set(got[1]), st
+            return jax.lax.fori_loop(
+                0, n, one, (jnp.zeros((n, H, dk), jnp.float32), st))
+        return jax.jit(fn)(state0)
+
+    for name, fn in (("chunked", chunked), ("recurrent", recurrent)):
+        def run_case(fn=fn, name=name):
+            o, st = jax.block_until_ready(fn())
+            err_o, err_s = _maxdiff(o, want_o), _maxdiff(st[1], want_s)
+            kept = bool(np.array_equal(np.asarray(st[0]),
+                                       np.asarray(state0[0])))
+            sm.say(f"kernel delta_rule_{name}: against the recurrence a "
+                   f"token at a time max|Δ| o {err_o:.2e} state "
+                   f"{err_s:.2e} (tol 2e-3), other slots kept={kept}")
+            sm.check(err_o <= 2e-3 and err_s <= 2e-3 and kept,
+                     f"kernel delta_rule_{name}: {err_o} {err_s} {kept}")
+            return {"max_abs_err": max(err_o, err_s)}
+        sm.case(f"delta_rule_h{H}_d{dk}_{name}", run_case, out)
+    return out
+
+
 def phase_kernels(sm):
     out = _flash_cases(sm)
     out.update(_paged_cases(sm))
     out.update(_latent_cases(sm))
+    out.update(_delta_rule_cases(sm))
     return {"cases": out}
 
 

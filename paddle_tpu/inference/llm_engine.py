@@ -627,6 +627,12 @@ class LLMEngineConfig:
             return max(2, int(budget) // per_page + 1)  # + trash
 
         if isinstance(budget_bytes, dict):
+            state = {k.name for k in model_config.cache_kinds() if k.state}
+            if state & set(budget_bytes):
+                raise ValueError(
+                    f"cache kind(s) {sorted(state & set(budget_bytes))} "
+                    "keep a fixed slab a slot, not pages: they take no "
+                    "page budget (`pool_bytes()` counts their slabs)")
             num_pages = {k: pages(b, k) for k, b in budget_bytes.items()}
         else:
             num_pages = pages(budget_bytes)
@@ -909,7 +915,19 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # the model's cache kinds (text/models/serving_protocol.py), a
         # `_CacheKindState` each. The FIRST keeps every page: the trie,
         # the tier, the KV wire and the draft pool hang on it
-        kinds = list(mcfg.cache_kinds())
+        all_kinds = list(mcfg.cache_kinds())
+        # a STATE kind keeps one fixed slab a slot a layer and no pages:
+        # it has no pool, no table and no `_CacheKindState` (a slot holds
+        # its slab while it holds the slot, so growth, trimming and
+        # release have nothing to do); `_state_kinds` keeps its gauges
+        kinds = [k for k in all_kinds if not k.state]
+        self._state_kinds = [k for k in all_kinds if k.state]
+        if not kinds or all_kinds[:len(kinds)] != kinds:
+            raise ValueError(
+                "the model's cache kinds "
+                f"{[k.name for k in all_kinds]} must list at least one "
+                "PAGED kind, and the paged kinds first: the scheduler "
+                "admits, grows and preempts by pages")
         if kinds[0].window is not None:
             raise ValueError(
                 f"cache kind {kinds[0].name!r} comes first and has a "
@@ -944,7 +962,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         # kinds are refused what assumes one geometry; both keep the
         # page gauges and counters a kind (`<kind>_pages_live`, …)
         self._latent = any(k.latent for k in kinds)
-        self._kind_stats = self._several or self._latent
+        self._kind_stats = self._several or self._latent \
+            or bool(self._state_kinds)
         if self._kind_stats:
             on = [name for name, v in (
                 ("prefix_cache=True", cfg.prefix_cache),
@@ -954,13 +973,19 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             if on:
                 raise ValueError(
                     f"{', '.join(on)}: not with a model of "
-                    f"{len(kinds)} cache kinds "
-                    f"({[k.name for k in kinds]}"
+                    f"{len(all_kinds)} cache kinds "
+                    f"({[k.name for k in all_kinds]}"
                     f"{', latent' if self._latent else ''}). The prefix "
                     "trie, the tier store, the KV wire and the "
                     "speculative draft pool assume ONE page geometry of "
                     "`[page, heads, head_dim]` pools and one page table "
-                    "a slot (ROADMAP.md B-I)")
+                    "a slot (ROADMAP.md B-I)" + (
+                        ". A state kind's slab is not pages at all: a "
+                        "shared prefix, a spilled block, an imported "
+                        "payload or a rejected draft would each need a "
+                        "SNAPSHOT of the state at that position, which "
+                        "the engine does not keep (ROADMAP.md B-I.6)"
+                        if self._state_kinds else ""))
         # pool in the configured kv_dtype (default: the model's compute
         # dtype — decode is HBM-bound, same reasoning as generate()'s
         # cache dtype; "int8" quantizes each written row per (token,
@@ -982,8 +1007,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 self._kind_stats or any(k.head_major for k in kinds)):
             raise ValueError(
                 f"kv_dtype={cfg.kv_dtype!r}: head-major and latent "
-                "pools and models with several cache kinds keep float "
-                "pools")
+                "pools and models with several cache kinds or a state "
+                "kind keep float pools (a state slab is float32 by its "
+                "kind)")
         hd = kinds[0].head_dim
         # kv_quantized is the code width (0 float / 8 / 4 — truthy when
         # quantized); int4 packs two nibbles per byte along head_dim,
@@ -1004,20 +1030,28 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
         # k0, v0, k1, v1 … in LAYER order, each pool shaped by its
         # layer's kind (a latent kind's layer has one pool, not two)
-        kind_of = {i: n for n, k in enumerate(kinds) for i in k.layers}
+        kind_of = {i: n for n, k in enumerate(all_kinds)
+                   for i in k.layers}
         n_layers = len(kind_of)
-        if sorted(kind_of) != list(range(n_layers)):
+        if sorted(kind_of) != list(range(n_layers)) or n_layers != sum(
+                len(k.layers) for k in all_kinds):
             raise ValueError("the cache kinds must cover every layer "
                              "once")
 
+        def _layer_arrays(kind, n):
+            """[(shape, dtype)] of what one layer of `kind` keeps."""
+            if kind.state:
+                return kind.slab_arrays(self.num_slots, compute_dt)
+            return [(kind.pool_shape(pages_of[n], self.page_size,
+                                     hd_store), cache_dt)
+                    ] * kind.pools_per_layer
+
         def _fresh_pools():
             pools = [
-                jax.device_put(
-                    jnp.zeros(kinds[kind_of[i]].pool_shape(
-                        pages_of[kind_of[i]], self.page_size,
-                        hd_store), cache_dt), sharding)
+                jax.device_put(jnp.zeros(shape, dt), sharding)
                 for i in range(n_layers)
-                for _ in range(kinds[kind_of[i]].pools_per_layer)]
+                for shape, dt in _layer_arrays(all_kinds[kind_of[i]],
+                                               kind_of[i])]
             scales = []
             if self.kv_quantized:
                 sshape = _qrt.kv_scale_shape(num_pages, self.page_size,
@@ -1107,6 +1141,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             for k in kinds:
                 self.stats[f"{k.name}_pages_live"] = 0
                 self.stats[f"kv_positions_least_{k.name}"] = 0
+        if self._state_kinds:
+            # slabs (a slot a layer) running requests hold now, and
+            # those an admission or a replay restarted from zero
+            self._slab_layers = sum(len(k.layers)
+                                    for k in self._state_kinds)
+            self.stats["state_slabs_live"] = 0
+            self.stats["state_slabs_zeroed"] = 0
         # recent per-request phase timelines (reqtrace), appended at
         # first token / prefill export — the `metrics()` drill-down
         self._timelines = collections.deque(maxlen=64)
@@ -2235,9 +2276,28 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
     # ---- scheduler ----
 
+    def _admit_slabs(self, req):
+        """A request takes its slot's slabs of every state kind: they
+        restart from ZERO. Nothing is dispatched for it: the request's
+        first row stands at position 0 (checked here: nothing may map a
+        prefix or import pages beside a state kind), and the model's
+        step starts a slot's state from zero at such a row
+        (serving_protocol.py), whatever a finished or preempted request
+        left in the slab."""
+        if req.n_prefilled or req.cached_prefix:
+            raise RuntimeError(
+                f"request {req.rid} joins at position {req.n_prefilled}: "
+                "a state slab can only be rebuilt from position 0")
+        self.stats["state_slabs_zeroed"] += self._slab_layers
+        self.stats["state_slabs_live"] += self._slab_layers
+
     def _release(self, slot, req):
         for c in self._caches:
             c.release(slot, req)
+        if self._state_kinds:
+            # the slab goes with the slot; a preempted request's replay
+            # rebuilds it from position 0 (snapshots: ROADMAP.md B-I.6)
+            self.stats["state_slabs_live"] -= self._slab_layers
         req.n_prefilled = 0
         req.draft_prefilled = 0   # preemption replay re-prefills BOTH pools
         req.cached_prefix = 0
@@ -2472,6 +2532,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         req.published_blocks = req.cached_prefix // self.hash_block_tokens
         self._slots[slot] = req
         self._slot_gen += 1  # membership changed: staged arrays stale
+        if self._state_kinds:
+            self._admit_slabs(req)
         if self.prefix_cache is not None:
             self.prefix_cache.note_mapped(
                 req.cached_prefix, pages,
@@ -2680,6 +2742,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                     "window_pages_freed"], **{
                         f"{c.kind.name}_pages": c.pool.num_live
                         for c in self._caches})
+            if self._state_kinds:
+                span.set(state_slabs=self.stats["state_slabs_live"])
         if window is None:
             return None
         (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst, gtrans,

@@ -29,7 +29,7 @@ _scope = jax.named_scope
 
 
 def route_top_k(x, router_w, top_k, renormalise=True, scoring="softmax",
-                select_bias=None):
+                select_bias=None, n_group=None, topk_group=None):
     """Routing in float32 over ALL `router_w.shape[1]` experts: x
     [T, d], router_w [d, E_all] → (weights [T, top_k] float32, expert
     ids [T, top_k] int32). `scoring` turns the logits into scores:
@@ -37,7 +37,10 @@ def route_top_k(x, router_w, top_k, renormalise=True, scoring="softmax",
     largest of score + `select_bias` [E_all] are chosen (the bias
     selects only: an auxiliary-loss-free load balance); the weights are
     the chosen SCORES, divided by their sum with `renormalise`
-    (norm_topk_prob)."""
+    (norm_topk_prob). With `n_group` the selection is GROUP-LIMITED: the
+    experts stand in `n_group` groups of consecutive ids, a group's
+    score is the sum of its 2 largest score + bias, and only the
+    `topk_group` best groups' experts can be chosen."""
     with _scope("moe_router"):
         logits = jnp.matmul(x.astype(jnp.float32),
                             router_w.astype(jnp.float32),
@@ -48,11 +51,22 @@ def route_top_k(x, router_w, top_k, renormalise=True, scoring="softmax",
             scores = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"router scoring {scoring!r}")
-        if select_bias is None:
+        if select_bias is None and not n_group:
             w, ids = jax.lax.top_k(scores, int(top_k))
         else:
-            _, ids = jax.lax.top_k(
-                scores + select_bias.astype(jnp.float32), int(top_k))
+            sel = scores if select_bias is None else \
+                scores + select_bias.astype(jnp.float32)
+            if n_group:
+                T, E = sel.shape
+                G, per = int(n_group), E // int(n_group)
+                group = jnp.sum(jax.lax.top_k(
+                    sel.reshape(T, G, per), 2)[0], axis=-1)
+                _, best = jax.lax.top_k(group, int(topk_group))
+                keep = jnp.zeros((T, G), bool).at[
+                    jnp.arange(T)[:, None], best].set(True)
+                sel = jnp.where(jnp.repeat(keep, per, axis=1), sel,
+                                -jnp.inf)
+            _, ids = jax.lax.top_k(sel, int(top_k))
             w = jnp.take_along_axis(scores, ids, axis=-1)
         if renormalise:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -69,6 +83,7 @@ def route_top_k(x, router_w, top_k, renormalise=True, scoring="softmax",
 _GMM_TILE_M = 128
 _GMM_TILE_K_MOST = 3072
 _GMM_TILE_N = 1024
+_GMM_TILE_N_MOST = 1280
 
 
 def _gmm_tile_k(k):
@@ -76,6 +91,21 @@ def _gmm_tile_k(k):
         if k % tile == 0:
             return tile
     return min(_GMM_TILE_K_MOST, k)
+
+
+def _gmm_tile_n(n):
+    """1 024 columns where they divide n (2 048, 3 072, 4 096: as before);
+    else the largest divisor of n in whole 128-lane tiles up to 1 280: a
+    last tile that is half empty costs its whole copy (on a v5e at 192
+    assignments over 128 experts, d 2560: n 1 536 at 1 024 / 768 / 512
+    columns 1.292 / 1.216 / 1.179 ms, n 2 560 at 1 024 / 1 280 / 2 560 0.696 /
+    0.623 / 0.626 ms: PERF.md §6, PR 35, step 0 (c))."""
+    if n <= _GMM_TILE_N or n % _GMM_TILE_N == 0:
+        return min(_GMM_TILE_N, n)
+    for tile in range(_GMM_TILE_N_MOST, 0, -128):
+        if n % tile == 0:
+            return tile
+    return _GMM_TILE_N
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -90,8 +120,7 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
         m, k = lhs.shape
         n = rhs.shape[2]
-        tiling = (min(_GMM_TILE_M, m), _gmm_tile_k(k),
-                  min(_GMM_TILE_N, n))
+        tiling = (min(_GMM_TILE_M, m), _gmm_tile_k(k), _gmm_tile_n(n))
         return gmm(lhs, rhs, group_sizes,
                    preferred_element_type=lhs.dtype, tiling=tiling)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)

@@ -108,7 +108,8 @@ class SarvamMLAConfig:
                  num_experts_held=None, first_expert=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True,
                  rope_theta=10000.0, rope_scaling=None, rms_norm_eps=1e-6,
-                 max_seq_len=8192, dtype="bfloat16", init_weights=True):
+                 max_seq_len=8192, dtype="bfloat16", init_weights=True,
+                 n_group=None, topk_group=None):
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.num_layers = int(num_layers)
@@ -135,6 +136,9 @@ class SarvamMLAConfig:
         self.max_seq_len = int(max_seq_len)
         self.dtype = str(dtype)
         self.init_weights = bool(init_weights)   # as LagunaConfig's
+        # group-limited routing (`expert_layer.route_top_k`); None: none
+        self.n_group = int(n_group) if n_group else None
+        self.topk_group = int(topk_group) if n_group else None
         if self.first_expert + self.num_experts_held \
                 > self.num_routed_experts:
             raise ValueError("held experts run past the router's width")
@@ -429,7 +433,8 @@ class SarvamMLAForCausalLM(nn.Layer):
                 w, ids = expert_layer.route_top_k(
                     n, layer.router._value, c.num_experts_per_tok,
                     c.norm_topk_prob, scoring="sigmoid",
-                    select_bias=layer.router_bias._value)
+                    select_bias=layer.router_bias._value,
+                    n_group=c.n_group, topk_group=c.topk_group)
                 routed, counters = expert_layer.held_experts_ffn(
                     n.astype(layer.experts_down._value.dtype), w, ids,
                     valid, layer.experts_gate_up._value,
@@ -542,20 +547,10 @@ class SarvamMLAForCausalLM(nn.Layer):
         slot. Returns (logits [S, vocab] float32, new kv, counters
         int32 [7])."""
         c = self.config
-        T = tok.shape[0]
-        n_slots = page_tables.shape[0]
         valid = kv_lens > 0
         tables = _rope_tables(c, pos)
-        row_store = kv[0].shape[-1]
-        blocks = slot_blocks and _attention._pallas_backend_ok()
-        layout = forms = None
-        with _scope("attn"):
-            if slot_blocks and T >= _EXPANDED_MIN_ROWS \
-                    and kv[0].dtype == self.layers[0].w_uk._value.dtype:
-                forms = _TickForms(slot_ids, kv_lens)
-            elif blocks:
-                layout = SlotBlockLayout(slot_ids, kv_lens,
-                                         _TICK_ROWS_PER_BLOCK, n_slots)
+        how = self._tick_walk(slot_ids, kv_lens, page_tables.shape[0],
+                              kv[0].dtype, slot_blocks)
         with _scope("embed"):
             x = self.embed._value[tok].astype(jnp.float32)
         new_kv = []
@@ -563,32 +558,81 @@ class SarvamMLAForCausalLM(nn.Layer):
         for i, layer in enumerate(self.layers):
             with _scope("attn"), _scope("attn_mla"):
                 n = _rms_norm(x, layer.attn_norm._value, c.rms_norm_eps)
-                with _scope("mla_latent_write"):
-                    lat, kr = self._latent_row(layer, n, tables)
-                    row = jnp.concatenate([lat, kr], axis=-1)
-                    # the pool's row is stored in whole 128-lane tiles
-                    # (`CacheKind.pool_shape`); the lanes past row_dim
-                    # hold zeros and multiply zeros
-                    row = jnp.pad(row, ((0, 0),
-                                        (0, row_store - row.shape[-1])))
-                    pool = _row_write(kv[i], row, write_idx)
-                with _scope("mla_q"):
-                    q_nope, q_rope = self._queries(layer, n, tables)
-                if forms is None:
-                    o = self._walk_absorbed(
-                        layer, q_nope, q_rope, pool, page_tables, slot_ids,
-                        kv_lens, frontier_offset, layout)
-                else:
-                    o = self._walk_by_form(
-                        layer, q_nope, q_rope, pool, page_tables, slot_ids,
-                        kv_lens, forms, blocks)
+                o, pool = self._attend_pages(
+                    layer, n, kv[i], tables, write_idx, page_tables,
+                    slot_ids, kv_lens, frontier_offset, how)
                 with _scope("mla_out"):
                     x = self._attn_out(layer, x, o)
             new_kv.append(pool)
             x, cnt = self._ffn(layer, x, valid)
             moe = moe + cnt
-        # latent rows the step must read: each slot's longest row's
-        # context, once a layer; the frontier offset advances live rows
+        counters = jnp.concatenate([moe, self._walk_counters(
+            c.num_layers, slot_ids, kv_lens, page_tables.shape[0],
+            frontier_offset, how)])
+        with _scope("lm_head"):
+            x = x[sample_idx]
+        return self._head(x), new_kv, counters
+
+    def _tick_walk(self, slot_ids, kv_lens, n_slots, pool_dtype,
+                   slot_blocks):
+        """How a step's rows attend the latent pages, made once a step:
+        (`_TickForms` or None, `SlotBlockLayout` or None, whether the
+        absorbed walk takes slot blocks). The single tick's rows
+        (`slot_blocks`) go in blocks of `_TICK_ROWS_PER_BLOCK` of one
+        slot, and from `_EXPANDED_MIN_ROWS` rows a tick on its long runs
+        EXPANDED (a pool in another dtype than the weights that expand
+        it has one form); the fused window has one row a slot."""
+        T = slot_ids.shape[0]
+        blocks = slot_blocks and _attention._pallas_backend_ok()
+        layout = forms = None
+        with _scope("attn"):
+            if slot_blocks and T >= _EXPANDED_MIN_ROWS \
+                    and pool_dtype == self._latent_weight_dtype():
+                forms = _TickForms(slot_ids, kv_lens)
+            elif blocks:
+                layout = SlotBlockLayout(slot_ids, kv_lens,
+                                         _TICK_ROWS_PER_BLOCK, n_slots)
+        return forms, layout, blocks
+
+    def _latent_weight_dtype(self):
+        return self.layers[0].w_uk._value.dtype
+
+    def _attend_pages(self, layer, n, pool, tables, write_idx, page_tables,
+                      slot_ids, kv_lens, frontier_offset, how):
+        """A latent layer's attention over its pages for the normed input
+        n [T, d]: the rows `[c | k_r]` written into `pool`, the queries,
+        the walk in the form `how` gives each row. Returns (o [T, H, v]
+        float32, the new pool)."""
+        forms, layout, blocks = how
+        with _scope("mla_latent_write"):
+            lat, kr = self._latent_row(layer, n, tables)
+            row = jnp.concatenate([lat, kr], axis=-1)
+            # the pool's row is stored in whole 128-lane tiles
+            # (`CacheKind.pool_shape`); the lanes past row_dim hold
+            # zeros and multiply zeros
+            row = jnp.pad(row, ((0, 0),
+                                (0, pool.shape[-1] - row.shape[-1])))
+            pool = _row_write(pool, row, write_idx)
+        with _scope("mla_q"):
+            q_nope, q_rope = self._queries(layer, n, tables)
+        if forms is None:
+            return self._walk_absorbed(
+                layer, q_nope, q_rope, pool, page_tables, slot_ids,
+                kv_lens, frontier_offset, layout), pool
+        return self._walk_by_form(
+            layer, q_nope, q_rope, pool, page_tables, slot_ids, kv_lens,
+            forms, blocks), pool
+
+    @staticmethod
+    def _walk_counters(n_layers, slot_ids, kv_lens, n_slots,
+                       frontier_offset, how):
+        """int32 [4] over `n_layers` latent layers: the latent rows the
+        step must read (each slot's longest row's context, once a layer;
+        the frontier offset advances live rows), those of them read for
+        a slot's ONE query row, the query rows by form (absorbed,
+        expanded)."""
+        forms = how[0]
+        valid = kv_lens > 0
         lens = jnp.where(valid, kv_lens + (
             0 if frontier_offset is None else frontier_offset), 0)
         longest = jax.ops.segment_max(
@@ -597,12 +641,9 @@ class SarvamMLAForCausalLM(nn.Layer):
             valid.astype(jnp.int32), slot_ids, num_segments=n_slots) == 1
         expanded = (jnp.zeros((), jnp.int32) if forms is None
                     else jnp.sum(forms.runs.expanded))
-        counters = jnp.concatenate([moe, c.num_layers * jnp.stack([
+        return n_layers * jnp.stack([
             jnp.sum(longest), jnp.sum(jnp.where(alone, longest, 0)),
-            jnp.sum(valid) - expanded, expanded]).astype(jnp.int32)])
-        with _scope("lm_head"):
-            x = x[sample_idx]
-        return self._head(x), new_kv, counters
+            jnp.sum(valid) - expanded, expanded]).astype(jnp.int32)
 
     def _paged_decode_core(self, tok, pos_ids, slot_ids, write_idx,
                            page_tables, kv_lens, sample_idx, kv,
@@ -611,13 +652,14 @@ class SarvamMLAForCausalLM(nn.Layer):
         """The single tick (serving_protocol.py): Tensors in and out;
         returns (logits [1, S, vocab], *new pools, counters [8])."""
         if kv_scales:
-            raise ValueError("SarvamMLAForCausalLM serves float pools")
+            raise ValueError(f"{type(self).__name__} serves float pools")
         val = lambda t: None if t is None else t._value   # noqa: E731
         logits, new_kv, counters = self._paged_core(
             val(tok), val(pos_ids), val(slot_ids), val(write_idx),
             val(page_tables), val(kv_lens), val(sample_idx),
             [val(p) for p in kv], val(frontier_offset), slot_blocks=True)
         t = lambda v: Tensor(v, stop_gradient=True)       # noqa: E731
+        # + 0 for the engine's own count, `step_counters`' last name
         return (t(logits[None]), *[t(p) for p in new_kv],
                 t(jnp.pad(counters, (0, 1))))
 
@@ -630,8 +672,8 @@ class SarvamMLAForCausalLM(nn.Layer):
         (emits [k, S], new kv, [], counters [k, 8])."""
         if lag is not None or gtrans is not None or kv_scales:
             raise ValueError(
-                "SarvamMLAForCausalLM's fused window takes no draft lag, "
-                "grammar tables or quantized pools")
+                f"{type(self).__name__}'s fused window takes no draft "
+                "lag, grammar tables or quantized pools")
         S = tok0.shape[0]
         sl = jnp.arange(S, dtype=jnp.int32)
         pt = jnp.asarray(page_tables, jnp.int32)           # [S, MP]

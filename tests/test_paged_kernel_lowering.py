@@ -321,3 +321,49 @@ def test_resident_flash_compiles_for_v5e(one_chip, shape, dtype, causal,
         jax.config.update("jax_default_matmul_precision", before)
     # the forward and ONE backward kernel
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_delta_rules_recurrent_step_compiles_for_v5e(one_chip):
+    """PR 35: 96 slots of 32 heads' 128 × 128 float32 state, a slot's 2
+    MiB a block, in place (the state argument aliased to the result): 4
+    blocks double-buffered are over the default scoped VMEM, which the
+    kernel raises."""
+    from paddle_tpu.ops.pallas_kernels.delta_rule import (
+        delta_rule_recurrent)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, dk = 96, 32, 128
+    f32 = jnp.float32
+    args = [sds((S, H, dk, dk), f32)] + [sds((S, dk, H), f32)] * 4 + [
+        sds((S, H, dk), f32), sds((S,), jnp.int32), sds((1,), jnp.int32)]
+    with jax.enable_x64(False):
+        compiled = jax.jit(delta_rule_recurrent, donate_argnums=(0,)).lower(
+            *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * dk * dk * 4   # in place
+    assert mem.temp_size_in_bytes == 0
+
+
+def test_the_delta_rules_chunk_kernel_compiles_for_v5e(one_chip):
+    """PR 35: a 2 048-row tick's layout (95 chunks of 32 rows), 32 heads:
+    a chunk's five operands and a slot's 2 MiB state in and out a grid
+    step; float32 products at HIGHEST, a product with the left operand
+    transposed, dynamic head indices."""
+    from paddle_tpu.ops.pallas_kernels.delta_rule import delta_rule_chunks
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, dk, N, C = 96, 32, 128, 95, 32
+    f32 = jnp.float32
+    args = [sds((S, H, dk, dk), f32)] + [sds((N, H, C, dk), f32)] * 5 + [
+        sds((N,), jnp.int32)] * 3 + [sds((1,), jnp.int32)]
+    with jax.enable_x64(False):
+        compiled = jax.jit(delta_rule_chunks, donate_argnums=(0,)).lower(
+            *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        S * H * dk * dk * 4
