@@ -29,6 +29,7 @@ chip. It stamps platform=cpu on every line, refuses to run on anything
 but the CPU, and is never selected by the absence of a chip.
 """
 import argparse
+import functools
 import hashlib
 import json
 import math
@@ -529,12 +530,14 @@ def _latent_cases(sm):
 
 def _delta_rule_cases(sm):
     """The gated delta rule's two forms (`nn/functional/delta_rule.py`:
-    the RECURRENT step as a Pallas kernel, the CHUNKED form in plain
-    XLA) against the recurrence a token at a time in plain float32
+    the RECURRENT step and the CHUNKED form, each a Pallas kernel)
+    against the recurrence a token at a time in plain float32
     `jax.numpy` at highest precision, at Ling-3.0-flash-VL's serving
     shape: 32 heads of 128 × 128, decays down to e^-5 a token, a run of
     200 rows of one slot that starts mid-sequence from a state that is
-    not zero."""
+    not zero; the chunked form also on the same rows standing from flat
+    row 37 on (no multiple of a chunk, nor of a tile of 8 rows: the
+    kernel reads them where they lie)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -551,9 +554,7 @@ def _delta_rule_cases(sm):
     g = -5.0 * jax.nn.sigmoid(2.0 * f(T, H, dk))
     beta = jax.nn.sigmoid(f(T, H))
     state0 = f(S, H, dk, dk)
-    sids = np.zeros((T,), np.int32)
-    lens = np.zeros((T,), np.int32)
-    sids[:n], lens[:n] = 1, 1000 + np.arange(n)
+    at = 19 if sm.rehearse else 37
 
     def by_token(st):
         def step(s_, row):
@@ -569,13 +570,23 @@ def _delta_rule_cases(sm):
         want_s, want_o = jax.jit(by_token)(state0[1])
     out = {}
 
-    def chunked():
+    def chunked(at=0):
+        sids = np.zeros((T,), np.int32)
+        lens = np.zeros((T,), np.int32)
+        sids[at:at + n], lens[at:at + n] = 1, 1000 + np.arange(n)
+        # the run's rows moved to flat row `at`, the rest of the tick dead
+        there = lambda a: jnp.roll(a, at, axis=0)   # noqa: E731
+
         def fn(st):
             runs = SlotRunLayout(jnp.asarray(sids), jnp.asarray(lens), 64,
                                  dr.CHUNK, 0)
-            return dr.delta_rule_chunked(st, q, k, v, g, beta, runs)[:2]
+            return dr.delta_rule_chunked(st, there(q), there(k), there(v),
+                                         there(g), there(beta), runs)[:2]
         o, st = jax.jit(fn)(state0)
-        return o[:n], st
+        off_run = jnp.concatenate([o[:at], o[at + n:]])
+        sm.check(not bool(jnp.any(off_run)),
+                 "kernel delta_rule_chunked: rows off the run are not zero")
+        return o[at:at + n], st
 
     def recurrent():
         live = jnp.arange(S) == 1
@@ -594,7 +605,10 @@ def _delta_rule_cases(sm):
                 0, n, one, (jnp.zeros((n, H, dk), jnp.float32), st))
         return jax.jit(fn)(state0)
 
-    for name, fn in (("chunked", chunked), ("recurrent", recurrent)):
+    for name, fn in (("chunked", chunked),
+                     ("chunked_from_an_odd_row",
+                      functools.partial(chunked, at)),
+                     ("recurrent", recurrent)):
         def run_case(fn=fn, name=name):
             o, st = jax.block_until_ready(fn())
             err_o, err_s = _maxdiff(o, want_o), _maxdiff(st[1], want_s)

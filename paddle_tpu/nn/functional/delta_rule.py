@@ -13,11 +13,10 @@ with α_t = exp(g_t) a key CHANNEL (g ≤ 0) and β_t a head.
                elsewhere. What a decoding row takes, and every row of a
                run too short to chunk, a row an iteration.
     CHUNKED    `delta_rule_chunked`: the runs of a `SlotRunLayout` (rows
-               of one slot at consecutive positions, laid out again so
-               that a run starts a chunk), C rows a chunk, from the
-               slot's stored state, the state after the run written
-               back. With G the cumulative sum of g inside a chunk and
-               Γ = exp(G):
+               of one slot at consecutive positions), C rows a chunk
+               from a run's first row on, from the slot's stored state,
+               the state after the run written back. With G the
+               cumulative sum of g inside a chunk and Γ = exp(G):
                  A[i, j] = β_i Σ_c k_ic k_jc Γ_ic / Γ_jc   (j < i)
                  B[i, j] =     Σ_c q_ic k_jc Γ_ic / Γ_jc   (j ≤ i)
                  T = (I + A)⁻¹;  W = T (β Γ ⊙ K);  Û = T (β V)
@@ -31,10 +30,11 @@ with α_t = exp(g_t) a key CHANNEL (g ≤ 0) and β_t a head.
                last row before the sub-block of i): both factors then
                lie in (e^-80·…, e^80] for g ≥ −5 and neither overflows
                float32, which exp(−G_j) alone would after 18 rows. A
-               Pallas kernel on a TPU (a chunk a grid step, the state
-               carried in the result's block); in plain XLA elsewhere:
-               batched products, `solve_triangular`, a `fori_loop` over
-               the chunks in use.
+               Pallas kernel on a TPU (a chunk a grid step, copied from
+               the flat rows where they lie, the state carried in the
+               result's block); in plain XLA elsewhere: the runs laid
+               out again so that each starts a chunk, batched products,
+               `solve_triangular`, a `fori_loop` over the chunks in use.
 
 Both forms treat a row that is not live as the identity on the state (α
 = 1, β = 0, k = 0).
@@ -46,10 +46,13 @@ from .attention import _pallas_backend_ok
 
 __all__ = ["delta_rule_step", "delta_rule_chunked", "CHUNK", "SUB_BLOCK"]
 
-# rows a chunk: one run of 2 048 rows of a 2 048-row tick, a layer, in
-# plain XLA on a v5e: 6.72 ms at 32, 10.23 at 64, 25.94 at 128 (PERF.md §6,
-# PR 35, step 0 (b)); a sub-block is the longest stretch over which
-# exp(−G) stays inside float32 at the gate's lower bound of −5 a token
+# rows a chunk: one run of 2 048 rows of a 2 048-row tick, a layer, on a
+# v5e: in plain XLA 6.72 ms at 32, 10.24 at 64, 25.92 at 128; the Pallas
+# kernel 2.10 at 32, 2.28 at 64, 2.70 at 128 (PERF.md §6, PR 36, step 0:
+# four heads' 32 × 32 blocks fill one 128 × 128 operand; PR 35's kernel
+# from laid-out operands: 5.41 · 5.33 · 6.37); a sub-block is the longest
+# stretch over which exp(−G) stays inside float32 at the gate's lower
+# bound of −5 a token
 CHUNK = 32
 SUB_BLOCK = 16
 
@@ -124,19 +127,49 @@ def _chunk_terms(q, k, v, g, beta):
 
 def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
                        kernel=None):
-    """The CHUNKED form over the runs of `runs` (a `SlotRunLayout` whose
-    `align` is `chunk`, spare 0) of a flat step's rows: q k g [T, H,
-    d_k], v [T, H, d_v], β [T, H], float32; `state` [S, H, d_k, d_v]
-    float32. A run whose first row's kv length is 1 (position 0) starts
-    from zero, any other from its slot's stored state. Returns (o [T, H,
-    d_v] float32, zero off the runs' rows; the new state; chunks run).
-    `kernel`: None takes the Pallas kernel where Pallas kernels run; a
-    bool says which."""
+    """The CHUNKED form over the runs of `runs` (a `SlotRunLayout`) of a
+    flat step's rows: q k g [T, H, d_k], v [T, H, d_v], β [T, H],
+    float32; `state` [S, H, d_k, d_v] float32. A run whose first row's kv
+    length is 1 (position 0) starts from zero, any other from its slot's
+    stored state. Returns (o [T, H, d_v] float32, zero off the runs'
+    rows; the new state; chunks run). `kernel`: None takes the Pallas
+    kernel where Pallas kernels run (T holds a chunk, H whole tiles of 8
+    heads), which reads the runs' rows where they lie; a bool says which.
+    The plain form lays the runs out again, a run from a multiple of
+    `chunk` (`runs.align` is then `chunk`, spare 0)."""
     T, H, dk = k.shape
     dv = v.shape[-1]
     C = int(chunk)
+    if kernel is None:
+        kernel = _pallas_backend_ok() and T >= C and H % 8 == 0
+    # chunk n is chunk `part` of run `run_of`: a run's chunks in order,
+    # the runs in the order of their rows
+    N = T // C + runs.max_runs if kernel else runs.total // C
+    per_run = -(-runs.run_rows // C)
+    ends = jnp.cumsum(per_run)
+    n_used = ends[-1]
+    chunks = jnp.arange(N, dtype=jnp.int32)
+    run_of = jnp.minimum(jnp.searchsorted(ends, chunks, side="right").astype(
+        jnp.int32), runs.max_runs - 1)
+    part = chunks - (ends - per_run)[run_of]
+    starts_run = part == 0
+    slot_of = runs.run_slots[run_of]
+    fresh = runs.run_first[run_of] == 1
+
+    if kernel:
+        from ...ops.pallas_kernels.delta_rule import delta_rule_chunks
+
+        # a run's first FLAT row: the one its first laid-out row reads
+        row0 = runs.src[runs.run_row0][run_of] + part * C
+        live = jnp.clip(runs.run_rows[run_of] - part * C, 0, C)
+        # past the chunks in use: the last used chunk's slot (no block moves)
+        slot_of = slot_of[jnp.minimum(chunks, jnp.maximum(n_used - 1, 0))]
+        o, state = delta_rule_chunks(
+            state, q, k, v, g, beta, jnp.zeros((T, H, dv), jnp.float32),
+            row0, live, slot_of, starts_run, fresh, n_used[None], chunk=C)
+        return o, state, n_used
+
     total = runs.total
-    N = total // C
     laid = jnp.zeros((total,), bool).at[
         jnp.where(runs.expanded, runs.dest, total)].set(True, mode="drop")
 
@@ -146,33 +179,7 @@ def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
         x = x.reshape((N, C) + x.shape[1:])
         return jnp.moveaxis(x, 2, 1)                           # [N,H,C,·]
 
-    # chunk n belongs to the run whose laid-out rows hold row n·C
-    padded = -(-runs.run_rows // C) * C
-    ends = jnp.cumsum(padded)
-    n_used = ends[-1] // C
-    first_row = jnp.arange(N, dtype=jnp.int32) * C
-    run_of = jnp.searchsorted(ends, first_row, side="right").astype(jnp.int32)
-    run_of = jnp.minimum(run_of, runs.max_runs - 1)
-    starts_run = first_row == runs.run_row0[run_of]
-    ends_run = first_row + C == ends[run_of]
-    slot_of = runs.run_slots[run_of]
-    fresh = runs.run_first[run_of] == 1
-
-    def unlay(O):
-        O = jnp.moveaxis(O, 1, 2).reshape(total, H, dv)
-        return jnp.where(runs.expanded[:, None, None], O[runs.dest], 0.0)
-
-    if _pallas_backend_ok() if kernel is None else kernel:
-        from ...ops.pallas_kernels.delta_rule import delta_rule_chunks
-
-        b = beta[:, :, None]
-        slot_of = slot_of[jnp.clip(jnp.arange(N), 0,
-                                   jnp.maximum(n_used - 1, 0))]
-        O, state = delta_rule_chunks(
-            state, lay(q), lay(k), lay(k * b), lay(v * b), lay(g), slot_of,
-            starts_run, fresh, n_used[None])
-        return unlay(O), state, n_used
-
+    ends_run = part == per_run[run_of] - 1
     Qg, Kbar, dec, W, Uh, B = _chunk_terms(
         lay(q), lay(k), lay(v), lay(g), lay(beta))
 
@@ -195,4 +202,6 @@ def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
         0, n_used, body,
         (jnp.zeros((H, dk, dv), jnp.float32), state,
          jnp.zeros((N, H, C, dv), jnp.float32)))
-    return unlay(O), state, n_used
+    O = jnp.moveaxis(O, 1, 2).reshape(total, H, dv)
+    return jnp.where(runs.expanded[:, None, None], O[runs.dest], 0.0), \
+        state, n_used

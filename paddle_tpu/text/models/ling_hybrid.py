@@ -75,12 +75,19 @@ _scope = jax.named_scope
 
 # a run of one slot's rows in the single tick takes the CHUNKED form from
 # this many rows on; a shorter one goes a row an iteration through the
-# recurrent step. On a v5e a recurrent iteration with ONE live slot costs
-# 59 µs a layer with its gathers, and the chunked form 5.2 ms for the
-# tick's whole layout plus 0.8 µs a row (PERF.md §6, PR 35, step 0 (d)):
-# alone in a tick they cross near 90 rows, beside a long run (which pays
-# the layout anyway) at a few rows. Two chunks: a smaller threshold
-# lengthens the layout every tick pays (`SlotRunLayout`: T // this runs)
+# recurrent step. On a v5e, alone in a 2 048-row tick of 96 slots, a layer
+# (`tools/kda_sweep.py --tables cross`, PERF.md §6, PR 36):
+#     rows            4      8     16     32     64    128    256
+#     chunked      0.41   0.38   0.37   0.35   0.34   0.39   0.51 ms
+#     recurrent    0.37   0.59   1.04   1.97   3.77   7.40  14.64 ms
+# (59 µs an iteration; the chunked form's 0.3 ms are the zeroed result and
+# the chunk table, which every tick pays whatever it holds): they cross
+# near 4 rows, where with PR 35's layout round the kernel (5.2 ms a tick)
+# they crossed near 35. The threshold stays two chunks all the same: the
+# benchmark's own test holds a tick of 32 rows to NO chunked launch
+# (`tests/benchmarks/test_perfbench_ling_hybrid.py`), so moving it under
+# 33 waits for a `benchmark` PR (PERF.md §7); what it costs meanwhile is a
+# prompt's remainder of 16–63 rows going a row an iteration
 _CHUNKED_MIN_ROWS = 2 * delta_rule.CHUNK
 
 
@@ -321,6 +328,16 @@ class LingHybridForCausalLM(SarvamMLAForCausalLM):
         form its length gives it: (o [T, H, d_v], the new state)."""
         T = q.shape[0]
         o = jnp.zeros(v.shape, jnp.float32)
+        # a row that is not live is neutral. The two selects fuse into
+        # what computes g and β, and give them the TYPE q, k and v have:
+        # in the first layer g and β descend from the step's token ids
+        # alone and carry no mesh, after it from the caches too, and
+        # `delta_rule_chunks` would be traced once for each (2 s a trace
+        # and as much again to lower: PERF.md §6, PR 36). `offset` is
+        # made of the slot ids, which are typed as the caches are
+        live = sr.live & (sr.offset >= 0)          # = sr.live
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
         if sr.runs is not None:
             with _scope("kda_chunk"):
                 o, state, _ = delta_rule.delta_rule_chunked(

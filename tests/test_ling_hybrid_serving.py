@@ -134,55 +134,140 @@ def test_the_recurrent_kernel_visits_the_live_slots_only(live, fresh):
     assert np.array_equal(np.asarray(got_s)[~on], np.asarray(state)[~on])
 
 
-@pytest.mark.parametrize("runs_of", [
-    pytest.param(((1, 70, 33), (2, 50, 0)), id="two_runs_one_from_0"),
-    pytest.param(((1, 100, 5),), id="one_run_into_a_fourth_chunk"),
-    pytest.param((), id="no_run_at_all"),
-])
-def test_the_chunk_kernel_against_the_plain_chunked_form(runs_of):
-    """The Pallas kernel of the chunked form (interpreted; a chunk a grid
-    step, the state carried in the result's block, (I + A)⁻¹ as a product
-    of I + (−A)^(2^i)) against the plain XLA spelling (batched products,
-    `solve_triangular`): outputs and states to 5e-6, a tick without a
-    run leaves every state bit for bit."""
+def _tick(runs_of, T):
+    """sids, lens [T] of a tick whose runs are (slot, first flat row,
+    rows, first position); every other row dead."""
+    sids = np.zeros((T,), np.int32)
+    lens = np.zeros((T,), np.int32)
+    for slot, at, n, pos0 in runs_of:
+        sids[at:at + n], lens[at:at + n] = slot, pos0 + 1 + np.arange(n)
+    return sids, lens
+
+
+def _both_chunked_forms(state0, q, k, v, g, beta, runs, chunk=dr.CHUNK):
+    """(the plain XLA form's, the interpreted kernel's) results."""
     import functools
     from unittest import mock
 
-    Hk, dk, T = 2, 128, 160
-    rng = np.random.default_rng(0)
-    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa
-    k = f(T, Hk, dk)
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    q, v = f(T, Hk, dk) * dk ** -0.5, f(T, Hk, dk)
-    g = -5.0 * jax.nn.sigmoid(2.0 * f(T, Hk, dk))
-    beta = jax.nn.sigmoid(f(T, Hk))
-    state0 = f(3, Hk, dk, dk)
-    sids = np.zeros((T,), np.int32)
-    lens = np.zeros((T,), np.int32)
-    at = 0
-    for slot, n, pos0 in runs_of:
-        sids[at:at + n], lens[at:at + n] = slot, pos0 + 1 + np.arange(n)
-        at += n
-    runs = SlotRunLayout(jnp.asarray(sids), jnp.asarray(lens), 40,
-                         dr.CHUNK, 0)
     want = dr.delta_rule_chunked(state0, q, k, v, g, beta, runs,
-                                 kernel=False)
+                                 chunk=chunk, kernel=False)
     with mock.patch.object(kernels, "delta_rule_chunks", functools.partial(
             kernels.delta_rule_chunks, interpret=True)):
         got = dr.delta_rule_chunked(state0, q, k, v, g, beta, runs,
-                                    kernel=True)
+                                    chunk=chunk, kernel=True)
+    return want, got
+
+
+def _kernel_rows(T, Hk=2, dk=128, slots=4, seed=0):
+    rng = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa
+    k = f(T, Hk, dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return (f(slots, Hk, dk, dk), f(T, Hk, dk) * dk ** -0.5, k,
+            f(T, Hk, dk), -5.0 * jax.nn.sigmoid(2.0 * f(T, Hk, dk)),
+            jax.nn.sigmoid(f(T, Hk)))
+
+
+@pytest.mark.parametrize("runs_of,chunk", [
+    pytest.param(((1, 0, 70, 33), (2, 70, 50, 0)), 32,
+                 id="two_runs_one_from_0"),
+    pytest.param(((1, 0, 100, 5),), 32, id="one_run_into_a_fourth_chunk"),
+    pytest.param((), 32, id="no_run_at_all"),
+    pytest.param(((1, 37, 75, 12),), 32,
+                 id="a_run_from_a_flat_row_that_is_no_multiple_of_the_chunk"),
+    pytest.param(((3, 3, 45, 0), (1, 50, 41, 9)), 32,
+                 id="two_runs_of_two_slots_lone_rows_between"),
+    pytest.param(((2, 1, 40, 7), (0, 43, 64, 0), (3, 111, 49, 100)), 32,
+                 id="three_runs_lone_rows_between_the_last_to_the_ticks_end"),
+    pytest.param(((1, 90, 70, 2),), 32,
+                 id="a_partial_chunk_whose_copy_would_pass_the_last_row"),
+    pytest.param(((1, 5, 150, 3),), 64, id="chunks_of_64"),
+])
+def test_the_chunk_kernel_against_the_plain_chunked_form(runs_of, chunk):
+    """The Pallas kernel of the chunked form (interpreted; a chunk a grid
+    step read from the tick's flat rows where they lie, the state carried
+    in the result's block, (I + A)⁻¹ as a product of I + (−A)^(2^i))
+    against the plain XLA spelling (the runs laid out again, batched
+    products, `solve_triangular`): outputs and states to 5e-6; a run's
+    last, partial chunk writes nothing past its rows: the rows off the
+    runs (lone rows between them among those) read ZERO; the slots
+    without a run keep their state bit for bit, and a tick without a run
+    every state (passed through, aliased)."""
+    T = 160
+    state0, q, k, v, g, beta = _kernel_rows(T)
+    sids, lens = _tick(runs_of, T)
+    for lone in (0, 48, 159):       # lone rows where no run lies
+        if not lens[lone]:
+            lens[lone] = 500 + lone
+    runs = SlotRunLayout(jnp.asarray(sids), jnp.asarray(lens), 40,
+                         chunk, 0)
+    want, got = _both_chunked_forms(state0, q, k, v, g, beta, runs, chunk)
     assert int(got[2]) == int(want[2]) == sum(
-        -(-n // dr.CHUNK) for _, n, _ in runs_of)
+        -(-n // chunk) for _, _, n, _ in runs_of)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                atol=5e-6)
     np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
                                atol=5e-6)
-    if not runs_of:
-        assert np.array_equal(np.asarray(got[1]), np.asarray(state0))
+    off_runs = np.ones((T,), bool)
+    for _, at, n, _ in runs_of:
+        off_runs[at:at + n] = False
+    assert not np.asarray(got[0])[off_runs].any()
+    for slot in set(range(4)) - {r[0] for r in runs_of}:
+        assert np.array_equal(np.asarray(got[1][slot]),
+                              np.asarray(state0[slot]))
+
+
+def test_the_chunk_kernel_takes_four_heads_as_one_block_diagonal_operand():
+    """Eight heads at a chunk of 32: two GROUPS of 128 / 32 = 4 heads,
+    whose (I + A)⁻¹, T · [W | V] and B · U are one block-diagonal 128 ×
+    128 product a group; against the plain form, head by head."""
+    T = 96
+    state0, q, k, v, g, beta = _kernel_rows(T, Hk=8, seed=1)
+    runs = SlotRunLayout(*map(jnp.asarray, _tick(
+        ((1, 2, 41, 6), (3, 50, 44, 0)), T)), 40, dr.CHUNK, 0)
+    want, got = _both_chunked_forms(state0, q, k, v, g, beta, runs)
+    for a, b in zip(got[:2], want[:2]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-6)
+    assert not np.asarray(got[0])[[0, 1, 43, 49, 94, 95]].any()
+
+
+def test_a_run_continues_from_the_state_the_tick_before_stored():
+    """A prompt of 150 rows in two ticks (86 rows from flat row 3, then
+    64 from flat row 21 of the next): the second tick's run starts from
+    the state the first wrote back; against ONE run of 150 rows and
+    against the recurrence a token at a time."""
+    T, n1, n = 160, 86, 150
+    state0, q, k, v, g, beta = _kernel_rows(2 * T, seed=4)
+    first = SlotRunLayout(*map(jnp.asarray, _tick(((2, 3, n1, 0),), T)),
+                          40, dr.CHUNK, 0)
+    second = SlotRunLayout(*map(jnp.asarray, _tick(((2, 21, n - n1, n1),),
+                                                   T)), 40, dr.CHUNK, 0)
+    rows1 = [a[:T] for a in (q, k, v, g, beta)]
+    rows2 = [a[T:] for a in (q, k, v, g, beta)]
+    _, (o1, st, _) = _both_chunked_forms(state0, *rows1, first)
+    _, (o2, st, _) = _both_chunked_forms(st, *rows2, second)
+    whole = [jnp.concatenate([a[3:3 + n1], b[21:21 + n - n1]])
+             for a, b in zip(rows1, rows2)]
+    want_o, want_s = _by_token(np.zeros_like(state0[2]), *whole)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(
+        [o1[3:3 + n1], o2[21:21 + n - n1]])), want_o, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(st[2]), want_s, atol=2e-5)
+    assert np.array_equal(np.asarray(st[1]), np.asarray(state0[1]))
+
+
+def test_the_chunk_kernel_says_what_it_cannot_take():
+    state0, q, k, v, g, beta = _kernel_rows(64)
+    table = [jnp.zeros((3,), jnp.int32)] * 5 + [jnp.zeros((1,), jnp.int32)]
     with pytest.raises(ValueError, match="power of two"):
-        kernels.delta_rule_chunks(state0, *[jnp.zeros((2, Hk, 48, dk))] * 5,
-                                  *[jnp.zeros((2,), jnp.int32)] * 3,
-                                  jnp.zeros((1,), jnp.int32))
+        kernels.delta_rule_chunks(state0, q, k, v, g, beta,
+                                  jnp.zeros_like(v), *table, chunk=48)
+    with pytest.raises(ValueError, match="whole tiles of 8"):
+        kernels.delta_rule_chunks(state0, q, k, v, g, beta,
+                                  jnp.zeros_like(v), *table)
+    with pytest.raises(ValueError, match="at least a chunk"):
+        kernels.delta_rule_chunks(state0, q[:16], k[:16], v[:16], g[:16],
+                                  beta[:16], jnp.zeros_like(v[:16]), *table,
+                                  interpret=True)
 
 
 # ---- routing ----------------------------------------------------------
@@ -382,6 +467,39 @@ def test_served_through_llmserver_with_two_step_programs():
     assert 0 < stats["moe_assignments_held"] < stats["moe_assignments"]
 
 
+def test_a_tick_program_traces_the_chunk_kernel_once():
+    """Six KDA layers call ONE jitted launch: the first layer's g and β
+    descend from the token ids alone and would carry another type than
+    the later layers' (which descend from the caches too), a second
+    trace of the kernel and a second copy of it in the program (PR 36:
+    4–5 s of every start)."""
+    import functools
+    from unittest import mock
+
+    traced = []
+    plain = kernels.delta_rule_chunks.__wrapped__
+
+    def launch(*args, chunk):
+        traced.append(chunk)
+        return plain(*args, chunk=chunk, interpret=True)
+
+    model = LingHybridForCausalLM(ling_hybrid_tiny(num_heads=8))
+    model.eval()
+    with mock.patch.object(dr, "_pallas_backend_ok", lambda: True), \
+            mock.patch.object(kernels, "delta_rule_chunks", jax.jit(
+                launch, static_argnames=("chunk",))), \
+            mock.patch.object(kernels, "delta_rule_recurrent",
+                              functools.partial(delta_rule_recurrent,
+                                                interpret=True)):
+        eng = _engine(model, token_budget=64, decode_k=1)
+        eng.add_request(np.arange(100, dtype=np.int32), max_new_tokens=2)
+        eng.step()
+    assert traced == [dr.CHUNK]
+
+
 def test_the_threshold_is_two_chunks():
+    """PR 36: without a layout round the kernel the two forms cross near
+    4 rows (the table beside the constant), not 35; the constant waits
+    for the benchmark's own test of a 32-row tick."""
     assert ling_hybrid._CHUNKED_MIN_ROWS == 2 * dr.CHUNK == 64
     assert dr.CHUNK % dr.SUB_BLOCK == 0

@@ -348,22 +348,42 @@ def test_the_delta_rules_recurrent_step_compiles_for_v5e(one_chip):
 
 
 def test_the_delta_rules_chunk_kernel_compiles_for_v5e(one_chip):
-    """PR 35: a 2 048-row tick's layout (95 chunks of 32 rows), 32 heads:
-    a chunk's five operands and a slot's 2 MiB state in and out a grid
-    step; float32 products at HIGHEST, a product with the left operand
-    transposed, dynamic head indices."""
-    from paddle_tpu.ops.pallas_kernels.delta_rule import delta_rule_chunks
+    """PR 36: the chunked form of a 2 048-row tick of 96 slots, 32 heads,
+    from the tick's FLAT rows as the model hands them (`delta_rule_chunked`
+    with its kernel): chunks copied by their row offset from operands
+    left in HBM, a head's rows a strided load, float32 products at
+    HIGHEST, a product with the left operand transposed, dynamic head
+    indices; the state in place, the zeroed result aliased; and NO
+    laid-out copy of the rows: no `[3 040, 32, 128]` float32 (PR 35's
+    five gathers), no temporary as large as one operand."""
+    from paddle_tpu.nn.functional import delta_rule as dr
+    from paddle_tpu.nn.functional.attention import SlotRunLayout
+    from paddle_tpu.text.models.ling_hybrid import _CHUNKED_MIN_ROWS
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    S, H, dk, N, C = 96, 32, 128, 95, 32
+    S, H, dk, T = 96, 32, 128, 2048
     f32 = jnp.float32
-    args = [sds((S, H, dk, dk), f32)] + [sds((N, H, C, dk), f32)] * 5 + [
-        sds((N,), jnp.int32)] * 3 + [sds((1,), jnp.int32)]
+
+    def tick(state, q, k, v, g, beta, sids, lens):
+        runs = SlotRunLayout(sids, lens, _CHUNKED_MIN_ROWS, dr.CHUNK, 0)
+        return dr.delta_rule_chunked(state, q, k, v, g, beta, runs,
+                                     kernel=True)
+
+    args = [sds((S, H, dk, dk), f32)] + [sds((T, H, dk), f32)] * 4 + [
+        sds((T, H), f32)] + [sds((T,), jnp.int32)] * 2
     with jax.enable_x64(False):
-        compiled = jax.jit(delta_rule_chunks, donate_argnums=(0,)).lower(
-            *args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().alias_size_in_bytes == \
-        S * H * dk * dk * 4
+        compiled = jax.jit(tick, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the rows laid out again (what the plain form gathers): 3 040
+    laid = SlotRunLayout(jnp.zeros((T,), jnp.int32),
+                         jnp.zeros((T,), jnp.int32), _CHUNKED_MIN_ROWS,
+                         dr.CHUNK, 0).total
+    assert laid == 3040 and f"f32[{laid},{H},{dk}]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * dk * dk * 4   # in place
+    # beside the zeroed result only β's rows padded to a lane tile (1
+    # MiB) and the layout's index vectors: a tenth of ONE operand
+    assert mem.temp_size_in_bytes < T * H * dk * 4 // 10

@@ -10,8 +10,11 @@ H 32, d_k = d_v 128, float32 state; best of 3 x 10 launches. Tables:
          write of the state: ms, and % of 819 GB/s on 2 x 2.0 MiB a row
   chunk  the CHUNKED form on ONE run of 512 / 1 024 / 2 048 rows of a
          2 048-row tick, chunk 32 / 64 / 128, plain XLA against the Pallas
-         kernel: ms a layer, % of 197 TFLOP/s on the reference's count at
-         chunk 64
+         kernel, both from the tick's FLAT rows (what the model hands
+         them: the plain form's layout is inside its number, the kernel
+         reads the rows where they lie), and the kernel on a run that
+         starts at flat row 37: ms a layer, % of 197 TFLOP/s on the
+         reference's count at chunk 64
   gmm    the held experts' two grouped products (128 experts, d 2560,
          width 768) at 24 / 192 / 512 / 4 096 assignments by tiles (k, n):
          ms, % of 819 GB/s on the weights of the experts touched
@@ -118,22 +121,25 @@ def main(argv=None):
                       "form": "pallas" if kernel else "xla", "ms": ms,
                       "hbm_share": 2 * state_bytes * S / (ms / 1e3) / HBM})
 
-    def tick_rows(T, S, n, pos0=0):
-        """A tick of T rows: slot 1 has a run of n rows from pos0."""
+    def tick_rows(T, S, n, pos0=0, at=0):
+        """A tick of T rows: slot 1 has a run of n rows from pos0, its
+        first at flat row `at`."""
         sids = np.zeros((T,), np.int32)
         lens = np.zeros((T,), np.int32)
-        sids[:n] = 1
-        lens[:n] = pos0 + 1 + np.arange(n)
+        sids[at:at + n] = 1
+        lens[at:at + n] = pos0 + 1 + np.arange(n)
         return jnp.asarray(sids), jnp.asarray(lens)
 
     T, S = (128, 3) if rehearse else (2048, 96)
     if "chunk" in tables:
         q, k, v, g, beta = rows_of(T)
-        for n in ((128,) if rehearse else (512, 1024, 2048)):
-            sids, lens = tick_rows(T, S, n, 7)
-            for C, kernel in ((32, False), (32, True)) if rehearse else (
-                    (32, False), (64, False), (128, False), (32, True),
-                    (64, True), (128, True)):
+        kernels = [(C, True) for C in ((32,) if rehearse else (32, 64, 128))]
+        plain = [(C, False) for C, _ in kernels]
+        for n, at in ((96, 0), (75, 37)) if rehearse else (
+                (512, 0), (1024, 0), (2048, 0), (1024, 37)):
+            sids, lens = tick_rows(T, S, n, 7, at)
+            # the plain form's time does not depend on where the run lies
+            for C, kernel in kernels if at else plain + kernels:
                 def fn(st, q, k, v, g, beta, sids, lens, C=C, kernel=kernel):
                     runs = SlotRunLayout(sids, lens, 64, C, 0)
                     o, st, _ = dr.delta_rule_chunked(
@@ -144,7 +150,8 @@ def main(argv=None):
                     lens), carry=0)
                 flops = n * H * (2 * 64 * dk + 64 * 2 * dk + 4 * dk * dk
                                  + 64 * dk + 2 * dk * dk)
-                note({"table": "chunk", "run_rows": n, "chunk": C,
+                note({"table": "chunk", "run_rows": n, "first_row": at,
+                      "chunk": C,
                       "form": "pallas" if kernel else "xla", "ms": ms,
                       "peak_share": flops / (ms / 1e3) / PEAK_BF16})
 
