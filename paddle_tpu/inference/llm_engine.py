@@ -67,6 +67,7 @@ tests/test_llm_engine.py); eos semantics follow the shared contract
 """
 import collections
 import contextlib
+import functools
 import itertools
 import os
 import queue
@@ -150,6 +151,62 @@ _TOK_PER_DISPATCH = _obs.gauge(
 # shared with jit.TrainStep's probe — ONE definition (the registry
 # would raise on a labelnames divergence between two copies)
 from ..jit import _DONATION_HELD
+
+
+# what `_Request.tokens` holds in place of a token that a dispatched step
+# made on the device and the host has not read yet (docs/SERVING.md
+# "One step ahead")
+_IN_FLIGHT = -1
+
+
+@functools.lru_cache(maxsize=None)
+def _token_carry(sharding):
+    """The two small programs that keep a step's tokens on the device
+    until the host reads them, their results placed as the pools are
+    (`sharding`), so that every call after the first finds its
+    executable:
+
+    carry_tokens(last, src, idx)  the last token each slot made: slot s
+        takes src[idx[s], s] where idx[s] >= 0 (a window's emits
+        [k, S + C] at rem - 1, a tick's picks [S] at 0), else keeps
+        last[s]
+    merge_tokens(host, last)      a window's first tokens: last[s] where
+        the host holds none (host[s] < 0), else host[s]. A row that
+        stopped at its stop token carried the pad -1 and reads token 0
+        (what it makes then is thrown away)
+    """
+    def carry_tokens(last, src, idx):
+        slots = last.shape[0]
+        src = src.reshape(-1, src.shape[-1])[:, :slots]
+        got = src[jnp.maximum(idx, 0), jnp.arange(slots)]
+        return jnp.where(idx >= 0, got.astype(last.dtype), last)
+
+    def merge_tokens(host, last):
+        return jnp.where(host < 0, jnp.maximum(last, 0), host)
+
+    return (jax.jit(carry_tokens, out_shardings=sharding),
+            jax.jit(merge_tokens, out_shardings=sharding))
+
+
+class _StepRead:
+    """One dispatched program whose result the host has not read: how
+    to read it (`deliver(read)`), the step it belongs to, its `rows`
+    [(slot, request, index in `request.tokens` of the first token it
+    makes, tokens it makes)], the device result (a window's emits, a
+    tick's picks or None), the model's counters of a tick, and when it
+    was dispatched. A tick keeps what its gauges need: `fed` (rows
+    through the model; its `rows` are those at a sampling frontier) and
+    `restricted` (a straggler tick)."""
+
+    __slots__ = ("deliver", "step", "rows", "out", "counters", "t0",
+                 "fed", "restricted")
+
+    def __init__(self, deliver, step, rows, out, counters=None, fed=0,
+                 restricted=False):
+        self.deliver, self.step, self.rows, self.out = (deliver, step,
+                                                        rows, out)
+        self.counters, self.fed, self.restricted = counters, fed, restricted
+        self.t0 = _time.perf_counter()
 
 
 class PoolExhausted(RuntimeError):
@@ -801,6 +858,9 @@ class _Request:
         # what it holds of cache kind n (`_CacheKindState` keeps it)
         self.kind_pages = collections.defaultdict(_HeldPages)
         self.n_prefilled = 0      # kv-written tokens (reset on preempt)
+        # tokens of `tokens` still on the device (`_IN_FLIGHT` in their
+        # place until the step that made them is read)
+        self.in_flight = 0
         self.draft_prefilled = 0  # draft-pool valid prefix (speculative)
         self.admit_seq = None     # admission order (preemption picks max)
         self.preemptions = 0
@@ -878,10 +938,12 @@ class _Request:
 
     @property
     def num_generated(self):
-        return len(self.tokens) - self.prompt_len
+        """Tokens the host has read (not those still on the device)."""
+        return len(self.tokens) - self.prompt_len - self.in_flight
 
     def result_array(self):
-        return np.asarray(self.tokens, np.int64)
+        return np.asarray(self.tokens[:len(self.tokens) - self.in_flight],
+                          np.int64)
 
 
 class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
@@ -1083,6 +1145,16 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._sample_streams = itertools.count()
         self._fused_fn = None     # built lazily on the first window
         self._host_sample = None  # jitted sample_tokens for host ticks
+        # one step ahead (docs/SERVING.md): the step dispatched and not
+        # yet read (a list of `_StepRead`, or None), the reads this
+        # step makes (`_reads`), the requests resolved since step()
+        # returned (`_done`), and the last token each slot made on the
+        # device (`_last_tok`, [S]), from which a window takes the
+        # tokens the host has not read
+        self._flight, self._reads, self._done = None, [], []
+        self._carry_tokens, self._merge_tokens = _token_carry(sharding)
+        self._last_tok = jax.device_put(
+            np.zeros((self.num_slots,), np.int32), sharding)
         # staging cache: per-tick host arrays whose values depend only
         # on slot MEMBERSHIP (sid / sample_idx) are device-committed
         # once per slot-assignment generation instead of rebuilt and
@@ -1133,7 +1205,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                       # how the held slots fared in the steps that
                       # dispatched (`_count_slots`)
                       "step_calls": 0, "slots_held": 0,
-                      "slots_prefilling": 0, "slots_stalled": 0}
+                      "slots_prefilling": 0, "slots_stalled": 0,
+                      # of those steps, dispatched while the step before
+                      # was still unread, or after reading it
+                      "steps_overlapped": 0, "steps_drained": 0}
         if self._latent:       # the latent walks' launches, both forms
             self.stats["paged_attn_latent_launches"] = 0
             self.stats["paged_attn_latent_expanded_launches"] = 0
@@ -1474,8 +1549,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         return req
 
     def has_work(self):
-        return bool(self.waiting) or any(
-            r is not None for r in self._slots)
+        """Requests queued or running, or a step not yet read."""
+        return (self._flight is not None or bool(self.waiting)
+                or any(r is not None for r in self._slots))
 
     @property
     def mean_occupancy(self):
@@ -1586,6 +1662,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         the prefill-storm path the disaggregation exists to protect."""
         from .fleet_serving.kv_transfer import KVPagePayload
 
+        if req.in_flight:
+            self._drain()    # the payload carries the tokens
         n = len(req.pages)
         kv, scales = self._gather_pages(req.pages)
         self.stats["kv_pages_exported"] = (
@@ -2111,6 +2189,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 for r in self._slots if r is not None])
         except Exception:  # ptlint: disable=PTL804 (the guard wraps the flight-recorder dump itself)
             pass
+        # the steps in flight are never read: their requests fail here
+        # (one that left its slot at the dispatch too) or resolved before
+        for read in (self._flight or []) + self._reads:
+            for _slot, req, _first, _n in read.rows:
+                if not req.future.done():
+                    req.future.set_exception(exc)
+        self._flight, self._reads = None, []
         for slot, req in enumerate(self._slots):
             if req is not None:
                 self._release(slot, req)
@@ -2153,6 +2238,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         (the router's `cancel`) already counted this cancellation —
         pt_requests_cancelled_total stays exact, one per request."""
         rid = int(request_id)
+        # read the step in flight first: a request that it finished
+        # resolves with its tokens, as it would have a step earlier
+        self._drain()
         for slot, req in enumerate(self._slots):
             if req is not None and req.rid == rid:
                 self._release(slot, req)
@@ -2211,6 +2299,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         (top of step(), armed only once a deadline request exists)."""
         now = _time.perf_counter()
         hit = False
+        if any(r is not None and r.in_flight and r.deadline_t is not None
+               and now > r.deadline_t for r in self._slots):
+            self._drain()   # read what the step in flight made first
         for slot, req in enumerate(self._slots):
             if (req is not None and req.deadline_t is not None
                     and now > req.deadline_t):
@@ -2317,19 +2408,31 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         self._slot_gen += 1  # membership changed: staged arrays stale
 
     def _finish(self, slot, req):
+        self._retire(slot, req)
+        self._resolve(req)
+
+    def _retire(self, slot, req):
+        """A finished request leaves its slot: its pages and slot go to
+        the next. Its future resolves when its last token is read
+        (`_resolve`)."""
         # session pinning reads req.pages — must precede the release
         self._publish_session(req)
         self._release(slot, req)
         self.stats["finished"] += 1
         _FINISHED_TOTAL.inc()
+
+    def _resolve(self, req):
+        """A finished request's future takes its tokens."""
         if req.t_first_admit is not None and req.num_generated:
             dt = _time.perf_counter() - req.t_first_admit
             if dt > 0:
                 _REQ_TOK_RATE.observe(req.num_generated / dt)
-        # a client may have cancel()ed while the request was in flight —
-        # set_result would raise InvalidStateError and the server loop
-        # would read that as a device error and abort EVERYONE
-        if not req.future.cancelled():
+        # a client may have cancel()ed while the request was in flight
+        # (or a deadline cancelled it while its last tokens were on the
+        # device) — set_result would raise InvalidStateError and the
+        # server loop would read that as a device error and abort
+        # EVERYONE
+        if not req.future.done():
             req.future.set_result(req.result_array())
 
     def _preempt(self, slot, req, reason):
@@ -2591,6 +2694,12 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             req = self.sched.pop_next(now)
             if req is None:
                 break
+            if req.in_flight:
+                # preempted while its last tokens were on the device:
+                # its replay prefills them, so read them first
+                self._drain()
+                if req.future.done():
+                    continue        # they held its stop token
             if not self._try_admit(req):
                 self.sched.push_front(req)
                 break
@@ -2666,8 +2775,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         either ONE multi-token decode window (speculative when a draft
         model is configured, else the fused k-scan when decode_k > 1)
         over the rows at their sampling frontier, or one single-tick
-        compiled step → evict finished. Returns the list of requests
-        finished this tick.
+        compiled step → evict finished. Returns the requests whose
+        futures this call resolved.
 
         RAGGED WINDOWS (the PR-8 leftover, fixed): a straggler row
         still chunk-prefilling no longer forces the whole engine onto
@@ -2676,7 +2785,19 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         `step()` call (two dispatches, full progress on both fronts).
         The straggler joins windows at the boundary after its prefill
         completes, and per-request greedy/sampled outputs are
-        schedule-invariant, so nothing observable changes per request."""
+        schedule-invariant, so nothing observable changes per request.
+
+        ONE STEP AHEAD (docs/SERVING.md): a step's tokens stay on the
+        device until the NEXT call has dispatched its own programs, so
+        the device always has the next program queued while the host
+        reads, emits, admits and plans. At dispatch a step advances
+        what lengths alone decide (positions, pages, a request that
+        reaches its length leaves its slot); its read fills the token
+        values in, stops a row at its stop token and resolves futures.
+        A step whose plan needs the values reads the step before first
+        (`_needs_values`), and a call that leaves nothing to dispatch
+        reads its own step too, so `while eng.has_work(): eng.step()`
+        ends with every future resolved."""
         out = self._step()
         if self._kind_stats:
             self._trim_windows()
@@ -2702,7 +2823,47 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             if self._deadlines_armed:
                 self._expire_deadlines()
             span.set(admitted=self._admit())
+        serial = self._needs_values()
+        if serial:
+            self._drain()
         held = self.num_slots - self._slots.count(None)
+        rows = self._dispatch(serial)
+        if rows is not None:
+            self._count_slots(held, *rows)
+        # the step before, read while this one runs; while this step's
+        # reads are still in `_reads`, so that a read that raises fails
+        # their requests too (`abort_all`)
+        self._drain()
+        reads, self._reads = self._reads, []
+        if reads:
+            self._flight = reads
+            if not (self.waiting or any(r is not None
+                                        for r in self._slots)):
+                self._drain()          # nothing left to dispatch
+        out, self._done = self._done, []
+        return out
+
+    def _needs_values(self):
+        """Whether this step's plan needs the token VALUES of the step
+        before (so it reads that step first, and its own at once): a
+        speculative window proposes from them, the prefix trie and a
+        session publish them, a grammar row's state advances on them, a
+        prefill-only hand-off exports them, and every tick of an engine
+        without windows (`decode_k` 1) feeds its rows' last tokens from
+        the host."""
+        if (self._spec is not None or self.prefix_cache is not None
+                or self.decode_k == 1):
+            return True
+        return any(r is not None and (r.grammar is not None
+                                      or r.prefill_only)
+                   for r in self._slots)
+
+    def _dispatch(self, serial):
+        """This step's programs: ONE multi-token window over the rows at
+        their sampling frontier and a single tick for the others, or
+        one single tick over all rows; `serial`: each is read at once.
+        Returns (slots given rows, of them those given a prompt row) or
+        None where nothing was dispatched."""
         if self._spec is not None or self.decode_k > 1:
             active = self._active()
             frontier = [(s, r) for s, r in active
@@ -2711,34 +2872,108 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 for _s, r in frontier:
                     if r.num_generated == 0:
                         r.trace.stamp("first_decode_dispatch")
-                out = (self._spec.try_window(frontier)
-                       if self._spec is not None
-                       else self._try_step_fused(frontier))
+                if self._spec is not None:
+                    out = self._spec.try_window(frontier)
+                    if out is not None:
+                        self._done.extend(out)
+                else:
+                    out = self._try_step_fused(frontier, serial)
                 if out is not None:
                     stragglers = {s for s, r in active
                                   if r.n_prefilled != len(r.tokens) - 1}
                     fed, prefilling = len(frontier), 0
                     if stragglers:
-                        tick, rows = self._step_tick(only_slots=stragglers)
-                        out = out + tick
+                        rows = self._step_tick(stragglers, serial)
                         if rows is not None:
                             fed += rows[0]
                             prefilling += rows[1]
-                    self._count_slots(held, fed, prefilling)
-                    return out
-        out, rows = self._step_tick()
-        if rows is not None:
-            self._count_slots(held, *rows)
-        return out
+                    return fed, prefilling
+        if any(r is not None and r.in_flight for r in self._slots):
+            self._drain()    # a tick feeds its rows' tokens from the host
+        return self._step_tick(None, serial)
+
+    def _land(self, read, serial):
+        """A program was dispatched and its step advanced: a serial step
+        reads it at once, any other with the next step."""
+        if serial:
+            self._deliver(read)
+        else:
+            self._reads.append(read)
+
+    def _drain(self):
+        """Read the step in flight, if there is one."""
+        flight, self._flight = self._flight, None
+        for n, read in enumerate(flight or ()):
+            self._deliver(read, flight[n + 1:])
+
+    def _deliver(self, read, unread=()):
+        """The host's read of one dispatched program (`_StepRead`);
+        `unread`: the reads of its step after it. JAX raises a device
+        error at the read, not at the call, so a read that raises takes
+        a failed dispatch's way: every request still in flight fails
+        and the pools are re-zeroed (`abort_all`)."""
+        try:
+            read.deliver(read)
+        except Exception as e:
+            self._flight = [read, *unread]
+            self.abort_all(e)
+            raise
+
+    def _take_tokens(self, req, first, values, now):
+        """What a read found for `req`: `values` take their places from
+        index `first` of its tokens (unless an earlier read stopped the
+        request before them), a stop token ends the request there and
+        drops what it made after, and a request that has ended leaves
+        its slot (if the dispatch did not) and resolves. Returns how
+        many of `values` it kept."""
+        if not values.size or len(req.tokens) < first + len(values):
+            return 0
+        kept, stop = len(values), False
+        for j, t in enumerate(values):
+            t = int(t)
+            req.tokens[first + j] = t
+            if req.grammar is not None:
+                # host replay of the in-scan DFA advance: gstate
+                # stays a pure function of the emitted tokens
+                req.gstate = req.grammar.advance(req.gstate, t)
+            if req.eos is not None and t == req.eos:
+                kept, stop = j + 1, True  # in-executable masking padded
+                break                     # the rest of the window
+        if stop:
+            # what the row made after its stop token, and any later
+            # step in flight made, is dropped
+            del req.tokens[first + kept:]
+            req.in_flight = 0
+        else:
+            req.in_flight -= kept
+        self.stats["generated"] += kept
+        if first == req.prompt_len:          # replays don't re-count
+            ttft = now - req.t_submit
+            req.t_first_token = now
+            req.trace.stamp("first_token")
+            self._note_timeline(req)
+            _TTFT_SECONDS.observe(ttft)
+            self.sched.note_first_token(req, ttft)
+        if stop or (not req.in_flight and len(req.tokens) >= req.target):
+            if req.slot is not None and self._slots[req.slot] is req:
+                self._retire(req.slot, req)
+            elif stop:
+                self.sched.remove(req)   # preempted with it in flight
+            self._resolve(req)
+            self._done.append(req)
+        return kept
 
     def _count_slots(self, held, fed, prefilling):
         """A step dispatched: of the `held` slots that held a request
         once admission was done, `fed` got a row (window rows, a tick's
         rows), `prefilling` of them at least one prompt row in the tick,
         and the rest none (`stats`; the slots that decoded are
-        `fed - prefilling`)."""
+        `fed - prefilling`); the step before it was still unread
+        (`steps_overlapped`) or read (`steps_drained`)."""
         st = self.stats
         st["step_calls"] += 1
+        st["steps_overlapped" if self._flight is not None
+           else "steps_drained"] += 1
         st["slots_held"] += held
         st["slots_prefilling"] += prefilling
         st["slots_stalled"] += held - fed
@@ -2754,9 +2989,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 self.model, self.decode_k, self.page_size)
         return self._fused_fn
 
-    def _try_step_fused(self, active):
+    def _try_step_fused(self, active, serial):
         """One fused decode window over `active` (the caller's frontier
-        rows — every one at its sampling frontier), or None when the
+        rows — every one at its sampling frontier; `serial`: read at
+        once, `_land`), or None when the
         pool cannot cover even a 1-token window (the single-tick path
         takes the tick and owns preemption). Page capacity for the
         window is reserved UP FRONT; when the pool (or a sequence's
@@ -2778,36 +3014,28 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         if window is None:
             return None
         (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst, gtrans,
-         gmask, gen_before) = window
+         gmask) = window
         fused = self._ensure_fused()
-        t0 = _time.perf_counter()
         try:
             with _trace_span("llm_engine.fused_step", k=self.decode_k,
                              rows=len(active), prefill_tokens=0,
                              decode_tokens=int(rem.sum())):
+                # a row's last token may still be on the device
+                tok0 = self._merge_tokens(tok0, self._last_tok)
                 with _trace_span("llm_engine.dispatch"):
                     emits, (self._kv, self._kv_scales, self._key) = fused(
                         tok0, pos0, rem, fin0, eos, temps, tops, streams,
-                        gst, gtrans, gmask, self._step_tables(),
+                        gst, gtrans, gmask, self._dispatched_tables(),
                         (self._kv, self._kv_scales, self._key))
-                with _trace_span("llm_engine.sync"):
-                    emits = np.asarray(emits)  # once-per-k host sync
-                if self._counter_names:
-                    # the model's counters rode the same array
-                    self._note_counters(
-                        emits[:, self.num_slots:].sum(axis=0))
-                    emits = emits[:, :self.num_slots]
+                self._last_tok = self._carry_tokens(self._last_tok, emits,
+                                                    rem - 1)
         except Exception as e:
             # same contract as the single tick: the donated pytree may
             # already be consumed — fail in-flight work and re-zero
             self.abort_all(e)
             raise
-        # k-boundary SLO accounting: tell the scheduler how long a
-        # window runs so escalation checks fire a boundary EARLY
-        # instead of a boundary late (docs/SERVING.md)
-        self.sched.note_boundary(_time.perf_counter() - t0)
-        with _trace_span("llm_engine.emit"):
-            return self._emit_window(active, emits, rem, gen_before)
+        self._land(self._advance_window(active, rem, emits, serial), serial)
+        return True
 
     def _reserve_window(self, active):
         """Page reservation and host array fill of one fused window:
@@ -2857,9 +3085,8 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         temps = np.zeros((S,), np.float32)
         tops = np.ones((S,), np.float32)
         streams = np.zeros((S,), np.int32)
-        gen_before = {}
         for slot, req in active:
-            tok0[slot] = req.tokens[-1]
+            tok0[slot] = req.tokens[-1]   # `_IN_FLIGHT`: on the device
             pos0[slot] = req.n_prefilled
             fin0[slot] = False
             if req.eos is not None:
@@ -2867,15 +3094,17 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
             temps[slot] = req.temperature
             tops[slot] = req.top_p
             streams[slot] = req.sample_stream
-            gen_before[slot] = req.num_generated
 
         gst, gtrans, gmask = self._grammar_args(active)
         return (tok0, pos0, rem, fin0, eos, temps, tops, streams, gst,
-                gtrans, gmask, gen_before)
+                gtrans, gmask)
 
-    def _emit_window(self, active, emits, rem, gen_before):
-        """A fused window's tokens into their requests: append, finish,
-        counters and gauges. Returns the requests finished."""
+    def _advance_window(self, active, rem, emits, serial):
+        """What a dispatched window does that lengths alone decide: each
+        row's `rem` tokens take their places in flight, its position
+        moves on, and (one step ahead: not `serial`) a request that
+        reaches its length leaves its slot now. Returns the window's
+        read."""
         self.stats["steps"] += 1
         self.stats["fused_steps"] += 1
         self.stats["occupancy_sum"] += len(active) / self.num_slots
@@ -2883,46 +3112,44 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         _STEPS_TOTAL.inc()
         _FUSED_STEPS.inc()
         _DISPATCHES.inc()
-
-        finished = []
-        now = _time.perf_counter()
-        total = 0
+        rows = []
         for slot, req in active:
-            emitted, done = 0, False
-            for j in range(int(rem[slot])):
-                t = int(emits[j, slot])
-                req.tokens.append(t)
-                if req.grammar is not None:
-                    # host replay of the in-scan DFA advance: gstate
-                    # stays a pure function of the emitted tokens
-                    req.gstate = req.grammar.advance(req.gstate, t)
-                emitted += 1
-                if ((req.eos is not None and t == req.eos)
-                        or len(req.tokens) >= req.target):
-                    done = True   # in-executable masking already
-                    break         # padded the rest of the window
-            if self._kind_stats and emitted:
-                self._note_attended(req.n_prefilled, 1, steps=emitted)
-            req.n_prefilled += emitted
-            total += emitted
-            self.stats["generated"] += emitted
-            self.sched.note_tokens(req.tenant, emitted)
-            if gen_before[slot] == 0 and emitted > 0:
-                ttft = now - req.t_submit
-                req.t_first_token = now
-                req.trace.stamp("first_token")
-                self._note_timeline(req)
-                _TTFT_SECONDS.observe(ttft)
-                self.sched.note_first_token(req, ttft)
-            if done:
-                self._finish(slot, req)
-                finished.append(req)
-        self.stats["tokens_in"] += total
-        _TOKENS_TOTAL.labels(phase="decode").inc(total)
-        _TOK_PER_DISPATCH.set(total)
-        _QUEUE_DEPTH.set(len(self.waiting))
-        self._publish_load()
-        return finished
+            n = int(rem[slot])
+            rows.append((slot, req, len(req.tokens), n))
+            req.tokens.extend([_IN_FLIGHT] * n)
+            req.in_flight += n
+            if self._kind_stats:
+                self._note_attended(req.n_prefilled, 1, steps=n)
+            req.n_prefilled += n
+            if not serial and len(req.tokens) >= req.target:
+                self._retire(slot, req)
+        return _StepRead(self._deliver_window, self.stats["step_calls"] + 1,
+                         rows, emits)
+
+    def _deliver_window(self, read):
+        """A window's read: its tokens into their requests (a row at its
+        stop token stops), the model's counters, futures, gauges."""
+        with _trace_span("llm_engine.sync", step=read.step):
+            emits = np.asarray(read.out)   # once-per-k host sync
+        if self._counter_names:
+            # the model's counters rode the same array
+            self._note_counters(emits[:, self.num_slots:].sum(axis=0))
+        # k-boundary SLO accounting: tell the scheduler how long a
+        # window runs (dispatch to read) so escalation checks fire a
+        # boundary EARLY instead of a boundary late (docs/SERVING.md)
+        self.sched.note_boundary(_time.perf_counter() - read.t0)
+        with _trace_span("llm_engine.emit"):
+            now = _time.perf_counter()
+            total = 0
+            for slot, req, first, n in read.rows:
+                kept = self._take_tokens(req, first, emits[:n, slot], now)
+                total += kept
+                self.sched.note_tokens(req.tenant, kept)
+            self.stats["tokens_in"] += total
+            _TOKENS_TOTAL.labels(phase="decode").inc(total)
+            _TOK_PER_DISPATCH.set(total)
+            _QUEUE_DEPTH.set(len(self.waiting))
+            self._publish_load()
 
     def _step_tables(self):
         """The page tables a step is dispatched with: the one table
@@ -2931,6 +3158,14 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         if not self._several:
             return self._caches[0].tables
         return np.stack([c.tables for c in self._caches])
+
+    def _dispatched_tables(self):
+        """`_step_tables()` as an array of its own: a program read one
+        step later may run after the host has moved the tables on, and
+        the runtime may read a host array where it lies (the stack of
+        several kinds is one already)."""
+        tables = self._step_tables()
+        return tables if self._several else tables.copy()
 
     def _write_index(self, slots, positions, kv_lens):
         """The pool row each row of a step writes its K/V to, [T] (0,
@@ -2986,47 +3221,62 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
 
     # ---- single-tick step (prefill / mixed / k=1) ----
 
-    def _host_sample_rows(self, lv, reqs):
-        """Temperature/top-p (+ greedy rows) for a host tick's frontier
-        logits — the SAME `sample_tokens` math the fused scan runs
-        in-executable, position-keyed on the SAME engine key, so a
-        request's draws are identical whichever path serves the tick
-        (that invariance is what makes sampled outputs reproducible
-        across decode_k — tests/test_fused_decode.py pins it).
-
-        Padded to num_slots so the jitted sampler traces ONCE per
-        engine: the frontier row count varies tick-to-tick with
-        arrivals/finishes, and a per-count specialization would stall
-        the serving loop on a fresh vocab-sort compile mid-traffic."""
+    def _frontier_picks(self, logits, sample_slots):
+        """The tick's next token of every slot in `sample_slots`, left
+        on the device: [S] int32 by slot (the other slots' entries mean
+        nothing). Greedy argmax, or temperature/top-p through the SAME
+        `sample_tokens` math the fused scan runs in-executable,
+        position-keyed on the SAME engine key, so a request's draws are
+        identical whichever path serves the tick (that invariance is
+        what makes sampled outputs reproducible across decode_k —
+        tests/test_fused_decode.py pins it). Over all S slots' rows, so
+        each traces ONCE per engine: the frontier row count varies
+        tick-to-tick with arrivals/finishes, and a per-count
+        specialization would stall the serving loop on a fresh
+        vocab-sort compile mid-traffic."""
+        S = self.num_slots
+        lv = logits[0].astype(jnp.float32)
+        frontier = [(s, self._slots[s]) for s in sample_slots]
+        if any(r.grammar is not None for _s, r in frontier):
+            # host-path grammar masking: mask the logit VALUES before
+            # the (single-trace) jitted sampler / argmax — identical
+            # picks to the in-scan mask, zero new traces
+            allow = np.ones((S, lv.shape[1]), bool)
+            for s, r in frontier:
+                if r.grammar is not None:
+                    allow[s] = r.grammar.allowed_np(r.gstate)
+            lv = jnp.where(jnp.asarray(allow), lv, jnp.float32(-1e30))
+        if not any(r.do_sample for _s, r in frontier):
+            # greedy frontier sampling — same pick as generate()'s
+            # default path, so outputs stay token-identical
+            return jnp.argmax(lv, axis=-1).astype(jnp.int32)
         if self._host_sample is None:
             from ..text.models.gpt import sample_tokens
 
             self._host_sample = jax.jit(sample_tokens)
-        n, S = len(reqs), self.num_slots
-        temps = np.zeros((S,), np.float32)   # pad rows: greedy, key 0
+        temps = np.zeros((S,), np.float32)   # other rows: greedy, key 0
         tops = np.ones((S,), np.float32)
         streams = np.zeros((S,), np.int32)
         positions = np.zeros((S,), np.int32)
-        for j, r in enumerate(reqs):
-            temps[j] = r.temperature
-            tops[j] = r.top_p
-            streams[j] = r.sample_stream
-            positions[j] = len(r.tokens)  # index the new token takes
-        lv = jnp.pad(lv, ((0, S - n), (0, 0)))
+        for s, r in frontier:
+            temps[s] = r.temperature
+            tops[s] = r.top_p
+            streams[s] = r.sample_stream
+            positions[s] = len(r.tokens)  # index the new token takes
         return self._host_sample(lv, temps, tops, streams, positions,
-                                 self._key)[:n]
+                                 self._key).astype(jnp.int32)
 
-    def _step_tick(self, only_slots=None):
-        """One single-tick compiled step: plan → dispatch → sample
-        frontiers on the host → evict finished. `only_slots` is the
+    def _step_tick(self, only_slots, serial):
+        """One single-tick compiled step: plan → dispatch → the
+        frontiers' picks (on the device) → advance; its read comes now
+        (`serial`) or with the next step (`_land`). `only_slots` is the
         ragged-window straggler tick (prefill-only rows; see step()).
-        Returns (the requests finished, (slots given rows, of them
-        those given a prompt row) or None where nothing was
-        dispatched)."""
+        Returns (slots given rows, of them those given a prompt row),
+        or None where nothing was dispatched."""
         with _trace_span("llm_engine.plan"):
             planned = self._plan_tick(only_slots)
         if planned is None:
-            return [], None
+            return None
         (plan, i, tok, pos, sid, widx, klen, sample_idx,
          sample_slots) = planned
         with _trace_span("llm_engine.step", rows=len(plan),
@@ -3036,12 +3286,18 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 with _trace_span("llm_engine.dispatch"):
                     logits, (self._kv, self._kv_scales, self._key) = \
                         self._step_fn(
-                            tok, pos, sid, widx, self._step_tables(),
+                            tok, pos, sid, widx, self._dispatched_tables(),
                             klen, sample_idx,
                             (self._kv, self._kv_scales, self._key))
+                counters = picks = None
                 if self._counter_names:
                     logits, counters = logits
-                    self._pending_counters.append(counters)
+                if sample_slots:
+                    picks = self._frontier_picks(logits, sample_slots)
+                    idx = np.full((self.num_slots,), -1, np.int32)
+                    idx[sample_slots] = 0
+                    self._last_tok = self._carry_tokens(self._last_tok,
+                                                        picks, idx)
             except Exception as e:
                 # the donated pools may already be consumed by the
                 # failed dispatch — fail the in-flight work and re-zero
@@ -3050,20 +3306,13 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 # left to do)
                 self.abort_all(e)
                 raise
-            # the span encloses the host's read of the result, so the
-            # program it launched runs inside it on a profile
-            with _trace_span("llm_engine.sync"):
-                nxt = self._read_frontier(logits, sample_slots)
-                if sample_slots and self._pending_counters:
-                    self._note_counters()   # the device is past them
-        with _trace_span("llm_engine.emit"):
-            # a slot decoded where its one row was the sampled frontier
-            sampled = set(sample_slots)
-            prefilling = sum(take > 1 or slot not in sampled
-                             for slot, _, take in plan)
-            return (self._emit_tick(plan, i, sample_slots, nxt,
-                                    only_slots),
-                    (len(plan), prefilling))
+        # a slot decoded where its one row was the sampled frontier
+        sampled = set(sample_slots)
+        prefilling = sum(take > 1 or slot not in sampled
+                         for slot, _, take in plan)
+        self._land(self._advance_tick(plan, i, sample_slots, picks,
+                                      counters, only_slots, serial), serial)
+        return len(plan), prefilling
 
     def _plan_tick(self, only_slots):
         """The single tick's planning up to the dispatch: the plan, its
@@ -3156,57 +3405,19 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         return (plan, i, tok, pos, sid, widx, klen, sample_idx,
                 sample_slots)
 
-    def _read_frontier(self, logits, sample_slots):
-        """The tick's host read: the next token of every slot at its
-        sampling frontier (greedy argmax or `sample_tokens`), as a numpy
-        array; empty when the tick carried prefill rows only."""
-        if not sample_slots:
-            return []
-        rows = jnp.asarray(sample_slots, jnp.int32)
-        lv = jnp.take(logits[0], rows, axis=0).astype(jnp.float32)
-        frontier = [self._slots[s] for s in sample_slots]
-        if any(r.grammar is not None for r in frontier):
-            # host-path grammar masking: mask the logit VALUES before
-            # the (single-trace) jitted sampler / argmax — identical
-            # picks to the in-scan mask, zero new traces
-            allow = np.ones((len(frontier), lv.shape[1]), bool)
-            for jr, r in enumerate(frontier):
-                if r.grammar is not None:
-                    allow[jr] = r.grammar.allowed_np(r.gstate)
-            lv = jnp.where(jnp.asarray(allow), lv, jnp.float32(-1e30))
-        if any(r.do_sample for r in frontier):
-            return np.asarray(self._host_sample_rows(lv, frontier))
-        # greedy frontier sampling — same pick as generate()'s default
-        # path, so outputs stay token-identical
-        return np.asarray(jnp.argmax(lv, axis=-1))
-
-    def _emit_tick(self, plan, i, sample_slots, nxt, only_slots):
-        """A single tick's result into its requests: counters and
-        gauges, prefill progress, token append, finish. Returns the
-        requests finished."""
+    def _advance_tick(self, plan, i, sample_slots, picks, counters,
+                      only_slots, serial):
+        """What a dispatched tick does that lengths alone decide: each
+        row's prefill progress, the prefix trie's publication and a
+        prefill-only hand-off, a frontier row's next token in flight,
+        and (one step ahead: not `serial`) a request that reaches its
+        length leaves its slot now. Returns the tick's read."""
         self.stats["steps"] += 1
         self.stats["tokens_in"] += i
         self.stats["occupancy_sum"] += len(plan) / self.num_slots
         self._note_launches(self._step_fn)
         _STEPS_TOTAL.inc()
         _DISPATCHES.inc()
-        # a ragged-window straggler tick covers only the PREFILL rows:
-        # the load gauges count the whole engine's slots, and the
-        # window's tokens-per-dispatch amortization stamp stays unless
-        # this tick actually decoded something
-        live_now = sum(r is not None for r in self._slots)
-        if only_slots is None or sample_slots:
-            _TOK_PER_DISPATCH.set(len(sample_slots))
-        # the flat-budget split: one decode token per sampling frontier,
-        # everything else is (chunked or preemption-replay) prefill
-        _TOKENS_TOTAL.labels(phase="decode").inc(len(sample_slots))
-        _TOKENS_TOTAL.labels(phase="prefill").inc(i - len(sample_slots))
-        _QUEUE_DEPTH.set(len(self.waiting))
-        _LIVE_SLOTS.set(live_now)
-        _SLOT_OCC.set(live_now / self.num_slots)
-        _PAGE_OCC.set(self._page_occupancy())
-
-        finished = []
         for slot, req, take in plan:
             if self._kind_stats:
                 self._note_attended(req.n_prefilled, take)
@@ -3225,28 +3436,53 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 # export the pages and retire (publish above already
                 # registered the full prompt blocks in the trie)
                 self._finish_prefill(slot, req)
-                finished.append(req)
-        _PAGE_FRAG.set(self.kv_fragmentation())
-        now = _time.perf_counter()
-        for slot, tok_id in zip(sample_slots, nxt):
+                self._done.append(req)
+        rows = []
+        for slot in sample_slots:
             req = self._slots[slot]
-            t = int(tok_id)
-            req.tokens.append(t)
-            if req.grammar is not None:
-                req.gstate = req.grammar.advance(req.gstate, t)
-            self.stats["generated"] += 1
-            if req.num_generated == 1:      # replays don't re-count
-                ttft = now - req.t_submit
-                req.t_first_token = now
-                req.trace.stamp("first_token")
-                self._note_timeline(req)
-                _TTFT_SECONDS.observe(ttft)
-                self.sched.note_first_token(req, ttft)
-            if ((req.eos is not None and t == req.eos)
-                    or len(req.tokens) >= req.target):
-                self._finish(slot, req)
-                finished.append(req)
-        return finished
+            rows.append((slot, req, len(req.tokens), 1))
+            req.tokens.append(_IN_FLIGHT)
+            req.in_flight += 1
+            if not serial and len(req.tokens) >= req.target:
+                self._retire(slot, req)
+        return _StepRead(self._deliver_tick, self.stats["step_calls"] + 1,
+                         rows, picks, counters, fed=i,
+                         restricted=only_slots is not None)
+
+    def _deliver_tick(self, read):
+        """A tick's read: the frontier picks into their requests (a pick
+        that is the stop token stops its row), the model's counters,
+        futures, gauges."""
+        if read.counters is not None:
+            self._pending_counters.append(read.counters)
+        picks = None
+        if read.out is not None:
+            with _trace_span("llm_engine.sync", step=read.step):
+                picks = np.asarray(read.out)
+                if self._pending_counters:
+                    self._note_counters()   # the device is past them
+        with _trace_span("llm_engine.emit"):
+            # a ragged-window straggler tick covers only the PREFILL
+            # rows: the load gauges count the whole engine's slots, and
+            # the window's tokens-per-dispatch amortization stamp stays
+            # unless this tick actually decoded something
+            live_now = sum(r is not None for r in self._slots)
+            sampled = len(read.rows)
+            if not read.restricted or sampled:
+                _TOK_PER_DISPATCH.set(sampled)
+            # the flat-budget split: one decode token per sampling
+            # frontier, everything else is (chunked or preemption-replay)
+            # prefill
+            _TOKENS_TOTAL.labels(phase="decode").inc(sampled)
+            _TOKENS_TOTAL.labels(phase="prefill").inc(read.fed - sampled)
+            _QUEUE_DEPTH.set(len(self.waiting))
+            _LIVE_SLOTS.set(live_now)
+            _SLOT_OCC.set(live_now / self.num_slots)
+            _PAGE_OCC.set(self._page_occupancy())
+            _PAGE_FRAG.set(self.kv_fragmentation())
+            now = _time.perf_counter()
+            for slot, req, first, _n in read.rows:
+                self._take_tokens(req, first, picks[slot:slot + 1], now)
 
 
 # the full `LLMServer.submit` kwarg surface — remote ingresses

@@ -1,7 +1,7 @@
 """The program's own names on a profile (ISSUE 25): every step span is
 a profiler annotation in the default telemetry mode, on the capture's
-clock with its args, the dispatch spans enclose the host's read of
-their result; and the step programs carry one vocabulary of named
+clock with its args, a step's read comes with the next step and names
+the step it reads; and the step programs carry one vocabulary of named
 scopes in every operation's `op_name`, backward included, without
 changing a single operation."""
 import contextlib
@@ -29,7 +29,7 @@ ENGINE_SPANS = {
     "llm_engine.plan": set(),
     "llm_engine.step": {"rows", "prefill_tokens", "decode_tokens"},
     "llm_engine.dispatch": set(),
-    "llm_engine.sync": set(),
+    "llm_engine.sync": {"step"},
     "llm_engine.emit": set(),
 }
 TRAIN_SPANS = {"jit.TrainStep.h2d": set(), "jit.TrainStep": {"step"},
@@ -88,12 +88,14 @@ def _program_spans(trace_dir):
 
 
 def _capture(trace_dir, eng, prompt, step, ids):
-    """Two engine steps (a single tick that prefills and samples, then
-    a fused window) and two train steps under one capture; everything
+    """Three engine steps (a single tick that prefills and samples, a
+    fused window, then the window of the last token, whose call reads
+    every step left) and two train steps under one capture; everything
     compiled beforehand."""
     req = eng.add_request(prompt, max_new_tokens=6)
     jax.profiler.start_trace(str(trace_dir))
     try:
+        eng.step()
         eng.step()
         eng.step()
         step(ids)
@@ -116,6 +118,7 @@ def test_default_mode_capture_holds_every_step_span(tmp_path):
     step, ids = _train_step()
     step(ids)
 
+    first = eng.stats["step_calls"] + 1
     _capture(tmp_path / "on", eng, prompt, step, ids)
     spans = _program_spans(tmp_path / "on")
     by_name = {}
@@ -130,25 +133,32 @@ def test_default_mode_capture_holds_every_step_span(tmp_path):
     (tick,) = by_name["llm_engine.step"]
     assert tick[2] == {"rows": 1, "prefill_tokens": 4,
                        "decode_tokens": 1}
-    (window,) = by_name["llm_engine.fused_step"]
-    assert window[2] == {"k": 4, "rows": 1, "prefill_tokens": 0,
-                         "decode_tokens": 4}
+    assert [w[2] for w in sorted(by_name["llm_engine.fused_step"])] == [
+        {"k": 4, "rows": 1, "prefill_tokens": 0, "decode_tokens": n}
+        for n in (4, 1)]
     assert by_name["llm_engine.admit"][0][2] == {"waiting": 1,
                                                  "admitted": 1}
     assert len(by_name["jit.TrainStep"]) == 2
     assert [s[2]["step"] for s in by_name["jit.TrainStep"]] == [1, 2]
-    # each dispatch span encloses exactly one sync: the device program
-    # a span launched runs inside it; and exactly one jitted call
-    # (`llm_engine.dispatch`), which ends before that sync starts
-    dispatches = by_name["llm_engine.step"] \
-        + by_name["llm_engine.fused_step"]
-    syncs = by_name["llm_engine.sync"]
+    # each dispatch span encloses exactly one jitted call
+    # (`llm_engine.dispatch`) and no read; one step ahead, each step's
+    # read (`llm_engine.sync`, naming its step) comes after the next
+    # step's call, and the last step's (nothing left to dispatch) after
+    # its own
+    dispatches = sorted(by_name["llm_engine.step"]
+                        + by_name["llm_engine.fused_step"])
+    syncs = sorted(by_name["llm_engine.sync"])
     calls = by_name["llm_engine.dispatch"]
-    assert len(syncs) == len(calls) == len(dispatches) == 2
+    assert len(calls) == len(dispatches) == len(syncs) == 3
+    ends = []
     for a, b, _ in dispatches:
-        (sync,) = [(sa, sb) for sa, sb, _ in syncs if a <= sa and sb <= b]
         (call,) = [(ca, cb) for ca, cb, _ in calls if a <= ca and cb <= b]
-        assert call[1] <= sync[0]
+        assert not [sa for sa, _sb, _ in syncs if a <= sa <= b]
+        ends.append(call[1])
+    assert [args["step"] for _a, _b, args in syncs] == [
+        first, first + 1, first + 2]
+    for n, (sync_start, _b, _args) in enumerate(syncs):
+        assert ends[min(n + 1, 2)] <= sync_start
     # at step granularity: never per token or per row
     assert len(spans) <= 2 * 12 + 2 * 5
 
