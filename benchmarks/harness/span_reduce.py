@@ -1,9 +1,8 @@
 """The traced window's `.xplane.pb` → what the span and scope metrics
 read: the program's own spans with their args (host plane), the step
-programs of device 0 with the span that launched each, and every
-device operation's scope with its self time. Read once a run
-(`reduction(ctx)`); per-layer readers pick from it by names they keep
-as data.
+programs of device 0, and every device operation's scope with its self
+time. Read once a run (`reduction(ctx)`); per-layer readers pick from
+it by names they keep as data.
 
     reduction(ctx) -> None | {
       "window": (t0_ns, t1_ns), "busy_s": mean over devices,
@@ -26,14 +25,6 @@ compiler gave it, its root's or its hero's; an operation without
 `op_name` (the compiler's own copies and slices) or with no word on
 its path counts under "". Seconds are SELF time inside the window, as
 in `trace_reduce`.
-
-Which program a span launched. Both dispatch spans enclose the host's
-read of their result, so a step program normally runs inside its span;
-a tick that carries prefill rows only has nothing to read and its
-program may start after its span closed. A module therefore belongs to
-the last dispatch span (of any kind the caller names) that STARTED at
-or before the module's start; modules before the first such span
-belong to none.
 """
 import functools
 import gzip
@@ -175,20 +166,7 @@ def scope_seconds(events, op_names, window):
     return out
 
 
-# ---- spans and the programs they launched ----------------------------
-
-def launched_by(modules, spans, dispatch_names):
-    """[(module, launching span | None)]: each module goes to the last
-    span named in `dispatch_names` that started at or before it."""
-    starts = sorted((s for s in spans if s[0] in dispatch_names),
-                    key=lambda s: s[1])
-    out, i = [], -1
-    for mod in sorted(modules, key=lambda m: m[1]):
-        while i + 1 < len(starts) and starts[i + 1][1] <= mod[1]:
-            i += 1
-        out.append((mod, starts[i] if i >= 0 else None))
-    return out
-
+# ---- the step programs -----------------------------------------------
 
 def turnarounds_ms(modules, pattern):
     """Idle milliseconds between the end of one step program (a module
@@ -308,33 +286,6 @@ def scope_time_share(ctx, scopes):
     if not hit:
         return None
     return 100.0 * sum(hit) / red["busy_s"]
-
-
-def launched_module_seconds(ctx, span, dispatch_spans, module=None):
-    """Durations (s) of device 0's modules launched by spans named
-    `span` (only those matching the regex `module`, if given); None
-    where the trace holds no such span."""
-    red = reduction(ctx)
-    if red is None or not any(s[0] == span for s in red["spans"]):
-        return None
-    rx = re.compile(module) if module else None
-    return [mod[2] / 1e9
-            for mod, by in launched_by(red["modules"], red["spans"],
-                                       dispatch_spans)
-            if by is not None and by[0] == span
-            and (rx is None or rx.search(mod[0]))]
-
-
-def launched_time_share(ctx, span, dispatch_spans):
-    secs = launched_module_seconds(ctx, span, dispatch_spans)
-    if secs is None:
-        return None
-    return 100.0 * sum(secs) / reduction(ctx)["busy_s"]
-
-
-def launched_module_ms_p50(ctx, span, dispatch_spans, module):
-    secs = launched_module_seconds(ctx, span, dispatch_spans, module)
-    return statistics.median(secs) * 1e3 if secs else None
 
 
 def step_turnaround_ms_p50(ctx, module):

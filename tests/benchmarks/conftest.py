@@ -13,6 +13,7 @@ _BENCH = os.path.join(ROOT, "benchmarks")
 if _BENCH not in sys.path:
     sys.path.insert(0, _BENCH)
 
+import perfbench_newarch  # noqa: E402
 from perfbench_pending import manifest_with_pending  # noqa: E402
 
 
@@ -26,3 +27,17 @@ def root_with_pending(tmp_path_factory):
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest_with_pending(), f)
     return root
+
+
+@pytest.fixture(scope="session",
+                params=["as_committed", "one_more_architecture"])
+def bench_root(request, tmp_path_factory):
+    """The checkout a test of the manifest's shape reads: this one as
+    committed, and a copy with one more architecture's configuration,
+    decode cell and readers appended as a change that adds a model
+    appends them (`perfbench_newarch`). A check that holds on the first
+    and not on the second pins something a new cell has to move."""
+    if request.param == "as_committed":
+        return ROOT
+    return perfbench_newarch.copy_with_a_new_architecture(
+        str(tmp_path_factory.mktemp("one_more_architecture")))

@@ -98,8 +98,9 @@ def test_paged_kernel_at_the_serving_cells_geometry(one_chip):
 @pytest.mark.parametrize("config,batch,seq", [
     ("gpt2-medium", 16, 1024), ("cerebras-gpt-1.3b", 2, 2048)])
 def test_flash_forward_dq_dkv(one_chip, config, batch, seq):
-    """Causal flash attention forward and backward (dq, dkv) in bf16 at
-    the training cells' shapes: s1024 x hd64 and s2048 x hd128."""
+    """Causal flash attention forward and backward in bf16 at the
+    training cells' shapes: s1024 x hd64 and s2048 x hd128. The resident
+    path these shapes take has one backward kernel for dq and dk/dv."""
     import jax
     import jax.numpy as jnp
 
@@ -120,4 +121,4 @@ def test_flash_forward_dq_dkv(one_chip, config, batch, seq):
 
     _compile(fwd, x, x, x)
     text = _compile(bwd, x, x, x, x).as_text()
-    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+    assert text.count("tpu_custom_call") >= 2      # fwd, fused bwd

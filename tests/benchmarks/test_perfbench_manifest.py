@@ -1,7 +1,8 @@
 """BENCHMARK.json against the contract's schema, and the harness's
 finder-by-name: a configuration, a mix, a per-layer metric and a cell
 added as NEW files and NEW entries run without an edit to any file that
-is there."""
+is there. The schema's tests read the manifest as committed and with
+one more architecture appended (`bench_root`, conftest.py)."""
 import json
 import os
 import re
@@ -11,7 +12,11 @@ import sys
 
 import pytest
 
+import perfbench_newarch as newarch
 import references
+from harness import traffic
+from perfbench_newarch import manifest
+from run import load_module
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -22,27 +27,23 @@ SOURCES = {"device_trace", "program_span", "program_counter",
            "host_clock"}
 
 
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
 def _line(text, limit=200):
     return (isinstance(text, str) and 1 <= len(text) <= limit
             and "\n" not in text and "\t" not in text)
 
 
-def test_top_level_keys_and_limits():
-    m = manifest()
+def test_top_level_keys_and_limits(bench_root):
+    m = manifest(bench_root)
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 65536
+    assert os.path.getsize(os.path.join(bench_root,
+                                        "BENCHMARK.json")) <= 65536
     assert 1 <= len(m["command"]) <= 32 and all(
         _line(w) for w in m["command"])
     assert 1 <= len(m["paths"]) <= 16
     for p in m["paths"]:
         assert PATH.match(p) and not p.startswith("/") and ".." not in p
-        assert os.path.isdir(os.path.join(ROOT, p))
+        assert os.path.isdir(os.path.join(bench_root, p))
     rs = m["run_seconds"]
     assert isinstance(rs, int) and 1 <= rs <= 51
     # a full check with the full 24 cells has to fit
@@ -53,21 +54,25 @@ def test_top_level_keys_and_limits():
     assert 1 <= len(m["per_layer"]) <= 128
 
 
-def test_every_file_under_paths_is_named_from_a_names_characters():
-    for p in manifest()["paths"]:
-        for d, dirs, files in os.walk(os.path.join(ROOT, p)):
+def test_every_file_under_paths_is_named_from_a_names_characters(
+        bench_root):
+    for p in manifest(bench_root)["paths"]:
+        for d, dirs, files in os.walk(os.path.join(bench_root, p)):
             dirs[:] = [x for x in dirs if x != "__pycache__"]
             for f in files:
-                rel = os.path.relpath(os.path.join(d, f), ROOT)
+                rel = os.path.relpath(os.path.join(d, f), bench_root)
                 assert PATH.match(rel), rel
 
 
-def test_configs():
-    m = manifest()
+def test_configs(bench_root):
+    m = manifest(bench_root)
     names = [c["name"] for c in m["configs"]]
     files = [c["file"] for c in m["configs"]]
     assert len(set(names)) == len(names) and len(set(files)) == len(files)
     used = {w["config"] for w in m["workloads"]}
+    trained = {w["config"] for w in m["workloads"] if traffic.load_mix(
+        os.path.join(bench_root, "benchmarks", "traffic"),
+        w["traffic"])["kind"] == "train_steps"}
     for c in m["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
@@ -75,7 +80,7 @@ def test_configs():
         assert any(c["file"].startswith(p + "/") for p in m["paths"])
         assert len(c["reduced"]) <= 16 and all(
             NAME.match(k) for k in c["reduced"])
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(bench_root, c["file"])) as f:
             body = json.load(f)
         assert body["source"] == c["source"]
         assert body["reduced"] == c["reduced"]
@@ -85,16 +90,21 @@ def test_configs():
                 "reduced", "assumed"} <= set(body)
         assert {"serve", "engine"} <= set(body) or "train" in body
         assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "builders", body["builder"] + ".py"))
-        assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "references", body["reference"] + ".py"))
-        ref = references.load(body["reference"], training="train" in body)
+            bench_root, "benchmarks", "builders", body["builder"] + ".py"))
+        ref = load_module(os.path.join(
+            bench_root, "benchmarks", "references",
+            body["reference"] + ".py"), "shape_ref_" + body["reference"])
         for name in references.SERVING + references.ARITHMETIC:
             assert callable(getattr(ref, name)), name
+        # a configuration that a cell trains needs the reference's
+        # training part, as `run.py` loads it
+        if c["name"] in trained:
+            for name in references.TRAINING:
+                assert hasattr(ref, name), name
 
 
-def test_workloads():
-    m = manifest()
+def test_workloads(bench_root):
+    m = manifest(bench_root)
     cfgs = {c["name"] for c in m["configs"]}
     names = [w["name"] for w in m["workloads"]]
     pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
@@ -105,15 +115,15 @@ def test_workloads():
         assert w["config"] in cfgs and w["chips"] in (1, 4)
         assert _line(w["why"])
         assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "traffic", w["traffic"] + ".json"))
+            bench_root, "benchmarks", "traffic", w["traffic"] + ".json"))
         assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "limits", w["name"] + ".json"))
+            bench_root, "benchmarks", "limits", w["name"] + ".json"))
     four = sum(w["chips"] == 4 for w in m["workloads"])
     assert four <= max(1, len(names) // 4)
 
 
-def test_metrics():
-    m = manifest()
+def test_metrics(bench_root):
+    m = manifest(bench_root)
     cells = {w["name"] for w in m["workloads"]}
     e2e = {x["name"]: x for x in m["end_to_end"]}
     every = [x["name"] for x in m["end_to_end"] + m["per_layer"]]
@@ -144,7 +154,7 @@ def test_metrics():
         # every listed cell reports the end-to-end metric it moves
         assert cells_of(x) and cells_of(x) <= cells_of(e2e[x["moves"]])
         assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "layer_metrics", x["name"] + ".py"))
+            bench_root, "benchmarks", "layer_metrics", x["name"] + ".py"))
         if x["name"].endswith("_roofline") or "mfu" in x["name"]:
             assert x["unit"] == "%"
     for c in cells:                 # every cell reports enough
@@ -156,7 +166,7 @@ def test_metrics():
             "_roofline") or "_roofline." in x["name"]}
         mfu = {x["moves"] for x in mine if "mfu" in x["name"]}
         assert roof and roof <= mfu
-    with open(os.path.join(ROOT, "PERF.md")) as f:
+    with open(os.path.join(bench_root, "PERF.md")) as f:
         perf = f.read()
     for layer in layers:
         assert layer in perf, f"PERF.md's list of layers lacks {layer!r}"
@@ -185,24 +195,13 @@ def test_a_cell_refuses_the_cpu_and_prints_no_result():
                 if ln.strip().startswith("{")]
 
 
-def _copy_of_the_benchmark(root):
-    """`benchmarks/` copied into `root`, the program linked beside
-    it; returns the copy's path."""
-    b = os.path.join(root, "benchmarks")
-    shutil.copytree(os.path.join(ROOT, "benchmarks"), b,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    os.symlink(os.path.join(ROOT, "paddle_tpu"),
-               os.path.join(root, "paddle_tpu"))
-    return b
-
-
 @pytest.fixture(scope="module")
 def copy_with_a_dummy_cell(tmp_path_factory):
     """A temporary copy of the benchmark with a configuration, a mix, a
     per-layer metric and a cell ADDED: new files and new manifest
     entries only."""
     root = str(tmp_path_factory.mktemp("copy"))
-    b = _copy_of_the_benchmark(root)
+    b = newarch.copy_of_the_benchmark(root)
     m = manifest()
     with open(os.path.join(b, "configs", "gpt2-medium.json")) as f:
         cfg = json.load(f)
@@ -279,69 +278,18 @@ def test_a_directory_with_only_the_benchmark_gives_no_result(tmp_path):
 
 # ---- a second ARCHITECTURE from new files alone ---------------------
 
-NEWARCH = os.path.join(ROOT, "tests", "benchmarks", "data", "newarch")
 GPT2_KEYS = ("n_embd", "n_layer", "n_head", "n_inner", "n_positions")
-ONE_LAYER_DROPPED = '''
+ANOTHER_SEEDS_WEIGHTS = '''
 
 _sound = served_token_gaps
 
 
 def served_token_gaps(cfg, w, *args, **kwargs):
-    """The planted fault: the forward pass leaves out the last layer."""
-    w = dict(w, layers={k: v[:-1] for k, v in w["layers"].items()})
+    """The planted fault: the forward pass runs on another seed's
+    weights, so nearly every served token lies off its best."""
+    w = make_weights(cfg, 1, cfg["serve"]["weight_dtype"])
     return _sound(cfg, w, *args, **kwargs)
 '''
-
-
-def _copy_with_a_new_architecture(root, fault=""):
-    """A temporary copy of the benchmark with a configuration that has
-    none of GPT-2's keys, its own reference and builder, a served cell,
-    a cell that trains and three per-layer readers ADDED: new files
-    (tests/benchmarks/data/newarch/) and new manifest entries only."""
-    b = _copy_of_the_benchmark(root)
-    before = {os.path.join(d, f) for d, _s, fs in os.walk(b) for f in fs}
-    for d, _s, files in os.walk(NEWARCH):
-        for f in files:
-            dst = os.path.join(b, os.path.relpath(d, NEWARCH), f)
-            assert dst not in before, f"{dst} would edit, not add"
-            shutil.copy(os.path.join(d, f), dst)
-    if fault:
-        with open(os.path.join(b, "references", "hfdecoder.py"), "a") as f:
-            f.write(fault)
-    shutil.copy(os.path.join(b, "limits", "cgpt1p3b_decode.json"),
-                os.path.join(b, "limits", "newarch_decode.json"))
-    shutil.copy(os.path.join(b, "limits", "gpt2m_train.json"),
-                os.path.join(b, "limits", "newarch_train.json"))
-    with open(os.path.join(b, "configs", "hf-decoder.json")) as f:
-        cfg = json.load(f)
-    assert not set(GPT2_KEYS) & (set(cfg) | set(cfg["rehearse"]))
-    m = manifest()
-    m["configs"].append({
-        "name": "hf-decoder", "source": cfg["source"],
-        "file": "benchmarks/configs/hf-decoder.json", "reduced": [],
-        "why": "a second architecture's keys"})
-    for cell, mix, moves in (
-            ("newarch_decode", "decode_heavy", "decode_tok_s"),
-            ("newarch_train", "pretrain_s1024", "train_tok_s")):
-        m["workloads"].append({
-            "name": cell, "config": "hf-decoder", "traffic": mix,
-            "chips": 1, "why": "a second architecture's keys"})
-        for x in m["end_to_end"]:
-            if x["name"] == moves:
-                x["workloads"].append(cell)
-    for x in m["per_layer"]:      # readers the benchmark has, unedited
-        if x["name"] in ("kv_page_occupancy_peak",
-                         "prefill_token_share.decode"):
-            x["workloads"].append("newarch_decode")
-    for name in ("newarch_serve_flops", "newarch_processed",
-                 "newarch_attended"):
-        m["per_layer"].append({
-            "name": name, "unit": "count", "better": "higher",
-            "source": "program_counter", "layer": "whole model step",
-            "moves": "decode_tok_s", "workloads": ["newarch_decode"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(m, f)
-    return root
 
 
 def _rehearse(root, cell, trace="1"):
@@ -351,8 +299,16 @@ def _rehearse(root, cell, trace="1"):
 
 @pytest.fixture(scope="module")
 def copy_with_a_new_architecture(tmp_path_factory):
-    return _copy_with_a_new_architecture(
-        str(tmp_path_factory.mktemp("newarch")))
+    """A copy with a configuration that has none of GPT-2's keys, its own
+    reference and builder, a served cell, a cell that trains and the
+    served cell's readers ADDED: new files and new entries only."""
+    root = newarch.copy_with_a_new_architecture(
+        str(tmp_path_factory.mktemp("newarch")), train=True)
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "hf-decoder.json")) as f:
+        cfg = json.load(f)
+    assert not set(GPT2_KEYS) & (set(cfg) | set(cfg["rehearse"]))
+    return root
 
 
 def test_a_new_architecture_runs_from_new_files_alone(
@@ -391,9 +347,13 @@ def test_a_new_architecture_runs_from_new_files_alone(
 
 def test_a_fault_planted_in_the_new_reference_reads_not_correct(tmp_path):
     """The dispatch reaches the module the configuration names: with
-    one layer dropped from THAT reference's forward pass, the same run
-    reads `correct` false."""
-    root = _copy_with_a_new_architecture(str(tmp_path), ONE_LAYER_DROPPED)
+    THAT reference's forward pass on another seed's weights, the same
+    run reads `correct` false. The fault moves nearly every token it
+    compares, so it reads so however few requests the one-second window
+    finishes on a loaded host (a fault that moved one or two tokens in
+    a hundred read 0 where the sample held none)."""
+    root = newarch.copy_with_a_new_architecture(str(tmp_path),
+                                                ANOTHER_SEEDS_WEIGHTS)
     p = _rehearse(root, "newarch_decode", trace="0")
     assert p.returncode == 0, p.stderr[-3000:]
     last = json.loads(p.stdout.strip().splitlines()[-1])

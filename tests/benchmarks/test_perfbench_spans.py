@@ -1,5 +1,5 @@
-"""The span and scope reduction (`harness/span_reduce.py`) and the ten
-readers of PR 25, on two trimmed traces recorded on the chip by that PR
+"""The span and scope reduction (`harness/span_reduce.py`) and the
+readers of spans and scopes, on two trimmed traces recorded on the chip
 (data/README.txt: both cells, 0.3 s each, the program's spans and the
 operations' `tf_op` kept), on the older fixture that has neither, and
 on made-up events."""
@@ -16,9 +16,7 @@ from harness import span_reduce as sr, trace_reduce as tr
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 DISPATCH = ("llm_engine.step", "llm_engine.fused_step")
-DECODE_READERS = ("tick_time_share.decode",
-                  "fused_window_device_ms_p50.decode",
-                  "step_turnaround_ms_p50.decode",
+DECODE_READERS = ("step_turnaround_ms_p50.decode",
                   "lm_head_time_share.decode",
                   "unscoped_time_share.decode")
 TRAIN_READERS = ("mlp_time_share.train", "norm_time_share.train",
@@ -85,22 +83,13 @@ def test_recorded_decode_window_spans_and_the_programs_they_launched(
     assert args["llm_engine.fused_step"]["k"] == 8
     assert args["llm_engine.admit"] == {"waiting": 0, "admitted": 0}
     # one fused window, one tick (its step program and the six small
-    # programs of its frontier read), the start of the next window
-    owners = [(mod[0].split("(")[0], by and by[0]) for mod, by in
-              sr.launched_by(red["modules"], red["spans"], DISPATCH)]
-    assert owners[0] == ("jit_pure", "llm_engine.fused_step")
-    assert owners[1] == ("jit_pure", "llm_engine.step")
-    assert {o for _n, o in owners[2:8]} == {"llm_engine.step"}
-    assert owners[8] == ("jit_pure", "llm_engine.fused_step")
-    # every program of the window lies in a tick or a fused span, and
-    # ticks + windows + idle make up the window
-    tick = sr.launched_module_seconds(decode_ctx, DISPATCH[0], DISPATCH)
-    fused = sr.launched_module_seconds(decode_ctx, DISPATCH[1], DISPATCH)
-    assert len(tick) + len(fused) == len(red["modules"]) == 9
+    # programs of its frontier read), the start of the next window: the
+    # programs and the idle time make up the window
+    assert len(red["modules"]) == 9
     window_s = decode_ctx["trace"]["window_s"]
     idle = window_s - red["busy_s"]
-    assert sum(tick) + sum(fused) + idle == pytest.approx(window_s,
-                                                          rel=0.02)
+    assert sum(m[2] for m in red["modules"]) / 1e9 + idle == \
+        pytest.approx(window_s, rel=0.02)
     # the dispatch spans enclose their sync, and the idle gaps carry
     # the program's names
     syncs = [s for s in mine if s[0] == "llm_engine.sync"]
@@ -113,10 +102,6 @@ def test_recorded_decode_window_spans_and_the_programs_they_launched(
 
 def test_decode_readers_on_the_recorded_window(decode_ctx):
     got = {n: _reader(n)(decode_ctx) for n in DECODE_READERS}
-    assert got["tick_time_share.decode"] == pytest.approx(
-        100 * 0.125737039 / 0.287011494, rel=1e-6)
-    assert got["fused_window_device_ms_p50.decode"] == pytest.approx(
-        (154.749786 + 1.569887) / 2)    # the second is cut by the trim
     # fused -> tick 3.83 ms, tick -> fused 4.57 ms of idle
     assert got["step_turnaround_ms_p50.decode"] == pytest.approx(
         4.1981795)
@@ -153,10 +138,7 @@ def test_train_readers_on_the_recorded_window(train_ctx):
     assert train_ctx["trace"]["idle_gaps"][0][0] == "jit.TrainStep"
     # no engine span in a training trace: the decode readers read
     # nothing there (the turnaround needs no span, only step programs)
-    for n in ("tick_time_share.decode",
-              "fused_window_device_ms_p50.decode",
-              "step_turnaround_ms_p50.decode"):
-        assert _reader(n)(train_ctx) is None
+    assert _reader("step_turnaround_ms_p50.decode")(train_ctx) is None
 
 
 def test_a_trace_without_spans_or_scopes_reads_nothing(old_ctx):
@@ -176,26 +158,6 @@ def test_a_trace_without_spans_or_scopes_reads_nothing(old_ctx):
 
 def _span(name, a, b, **args):
     return (name, a, b, args)
-
-
-def test_a_module_belongs_to_the_span_that_launched_it():
-    spans = [_span("llm_engine.fused_step", 100, 400),
-             _span("llm_engine.sync", 150, 390),
-             _span("llm_engine.emit", 400, 420),
-             _span("llm_engine.step", 500, 520),      # nothing to read:
-             _span("llm_engine.fused_step", 600, 900)]   # closes early
-    modules = [("jit_before(1)", 10, 50), ("jit_pure(1)", 120, 250),
-               ("jit_pure(2)", 530, 60), ("jit_pure(1)", 610, 250),
-               ("jit__argmax(3)", 870, 5)]
-    got = [(m[0], by and by[0:2]) for m, by in
-           sr.launched_by(modules, spans, DISPATCH)]
-    assert got == [
-        ("jit_before(1)", None),
-        ("jit_pure(1)", ("llm_engine.fused_step", 100)),
-        # started after its tick span had closed: still the tick's
-        ("jit_pure(2)", ("llm_engine.step", 500)),
-        ("jit_pure(1)", ("llm_engine.fused_step", 600)),
-        ("jit__argmax(3)", ("llm_engine.fused_step", 600))]
 
 
 def test_a_gap_between_two_step_programs_is_a_turnaround():
@@ -283,11 +245,6 @@ def test_made_up_trace_through_the_readers(tmp_path):
     assert red["window"] == (1000, 2000) and red["scoped"]
     assert red["busy_s"] == pytest.approx(200e-9)
     assert red["spans"] == [("llm_engine.step", 1050, 1600, {"rows": 3})]
-    # a module inside a tick span counts as a tick: 400 of 200 busy ns
-    # is what the made-up numbers give, unclamped
-    assert _reader("tick_time_share.decode")(ctx) == pytest.approx(200.0)
-    # no fused span in the trace: nothing to read
-    assert _reader("fused_window_device_ms_p50.decode")(ctx) is None
     assert _reader("lm_head_time_share.decode")(ctx) == pytest.approx(25.0)
     # an operation with no word and one with no name are unscoped
     assert _reader("unscoped_time_share.decode")(ctx) == pytest.approx(50.0)
@@ -303,7 +260,6 @@ def test_a_build_without_scopes_reads_as_unscoped(tmp_path):
     assert _reader("unscoped_time_share.train")(ctx) == pytest.approx(100.0)
     assert _reader("lm_head_time_share.decode")(ctx) is None
     assert _reader("optimizer_time_share.train")(ctx) is None
-    assert _reader("tick_time_share.decode")(ctx) is None
 
 
 def test_trim_tool_keeps_what_the_reductions_read(tmp_path):

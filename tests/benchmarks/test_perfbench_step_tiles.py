@@ -5,13 +5,13 @@ fixtures (data/README*.txt), whose programs predate the names, the
 dispatch span and the counters, and on a tiny engine's own counters."""
 import gzip
 import importlib.util
-import json
 import os
 import shutil
 
 import pytest
 
 from harness import idle_split, span_reduce as sr, trace_reduce as tr
+from perfbench_newarch import cells_of, manifest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
@@ -61,43 +61,57 @@ def _reader(name):
 
 # ---- the manifest -----------------------------------------------------
 
-def test_the_new_readers_close_the_list():
-    """A new entry goes at the end of its list: one put in the middle
-    shifts what stood after it and reads as a change to it."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    mine = per_layer[-len(NEW):]
-    assert [p["name"] for p in mine] == list(NEW)
+def _block(per_layer, names):
+    """The entries named `names`, checked to stand together in that
+    order wherever they are in the list."""
+    at = [p["name"] for p in per_layer].index(names[0])
+    block = per_layer[at:at + len(names)]
+    assert [p["name"] for p in block] == list(names)
+    return block
+
+
+def test_the_new_readers_close_the_list(bench_root):
+    """The readers every decode cell shares stand together, in their
+    order, and list every cell that reports `decode_tok_s`: a cell added
+    later is appended to the workloads and to the readers' lists, and
+    its own readers after them."""
+    m = manifest(bench_root)
+    decode = set(cells_of(m, "decode_tok_s"))
+    assert set(DECODE_CELLS) <= decode
+    mine = _block(m["per_layer"], list(NEW))
     for p in mine:
         assert (p["unit"], p["better"], p["source"], p["layer"]) == \
             NEW[p["name"]]
         assert p["moves"] == "decode_tok_s"
-        assert p["workloads"] == DECODE_CELLS
+        assert set(p["workloads"]) >= decode
         assert os.path.exists(os.path.join(
-            ROOT, "benchmarks", "layer_metrics", p["name"] + ".py"))
+            bench_root, "benchmarks", "layer_metrics", p["name"] + ".py"))
     # what the cells' CPU rehearsals print may hold no such name
     for p in mine:
         if p["source"] == "program_counter":
             assert "time_share" not in p["name"]
             assert not p["name"].endswith("_roofline.decode")
-    assert all(p["workloads"] == ["ling3flash_manyseq_decode"]
-               for p in per_layer[-5 - len(NEW):-len(NEW)])
+    # every older reader that the four decode cells share lists every
+    # decode cell too
+    shared = [p for p in m["per_layer"]
+              if set(DECODE_CELLS) <= set(p.get("workloads", decode))]
+    assert len(shared) > len(NEW)
+    assert all(set(p.get("workloads", decode)) >= decode for p in shared)
 
 
-def test_the_single_cell_block_stays_whole_ahead_of_the_new_readers():
+def test_the_single_cell_block_stays_whole_ahead_of_the_new_readers(
+        bench_root):
     """`ling3flash_manyseq_decode`'s own five readers keep their order and
-    stand together, now just ahead of the readers every decode cell
-    shares; the cell reads every reader `sarvam105b_longdoc_decode`
-    reads."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        m = json.load(f)
+    stand together, wherever the block is; the cell reads every reader
+    `sarvam105b_longdoc_decode` reads."""
+    m = manifest(bench_root)
     per_layer, cell = m["per_layer"], "ling3flash_manyseq_decode"
     own = [p["name"] for p in per_layer if p.get("workloads") == [cell]]
     assert own == ["kda_time_share.decode", "kda_proj_time_share.decode",
                    "kda_recur_roofline.decode", "kda_chunk_roofline.decode",
                    "kda_chunked_share_of_rows.decode"]
-    assert [p["name"] for p in per_layer[-5 - len(NEW):-len(NEW)]] == own
-    assert m["workloads"][-1]["name"] == cell
+    _block(per_layer, own)
+    assert cell in {w["name"] for w in m["workloads"]}
 
     def reads(name):
         return {p["name"] for p in per_layer
@@ -223,29 +237,20 @@ MS = 1_000_000     # ns
 
 def test_a_tick_that_starts_after_the_next_window_opened_is_a_tick(
         monkeypatch):
-    """The case the launching span gets wrong: a tick with only prompt
-    rows has nothing to read, its span closes before its program runs,
-    and the next window's span opens first. By name it stays a tick."""
+    """A tick with only prompt rows has nothing to read, its span closes
+    before its program runs, and the next window's span opens first: a
+    guess by the span over a program's start would call it a window. By
+    name it stays a tick."""
     spans = [("llm_engine.fused_step", 100 * MS, 400 * MS),
              ("llm_engine.step", 500 * MS, 520 * MS),
              ("llm_engine.fused_step", 525 * MS, 900 * MS)]
     modules = [("jit_pure_window(1)", 120 * MS, 130 * MS),
                ("jit_pure_tick(2)", 530 * MS, 100 * MS),
                ("jit_pure_window(1)", 640 * MS, 134 * MS)]
-    owners = [(m[0], by[0]) for m, by in sr.launched_by(
-        modules, [(n, a, b, {}) for n, a, b in spans],
-        ("llm_engine.step", "llm_engine.fused_step"))]
-    assert owners[1] == ("jit_pure_tick(2)", "llm_engine.fused_step")
     red = _red(spans, modules, busy_s=364 * MS / 1e9)
     monkeypatch.setattr(sr, "reduction", lambda _ctx: red)
     ctx = {"obs": {}, "trace": _trace([], window=(0, 1000 * MS),
                                       modules=modules)}
-    # by the span over its start: no tick at all, and the tick's 100 ms
-    # among the windows
-    assert _reader("tick_time_share.decode")(ctx) == 0.0
-    assert _reader("fused_window_device_ms_p50.decode")(ctx) == \
-        pytest.approx(130.0)
-    # by name
     assert _reader("tick_program_time_share.decode")(ctx) == \
         pytest.approx(100 * 100 / 364)
     assert _reader("tick_program_ms_p50.decode")(ctx) == \
