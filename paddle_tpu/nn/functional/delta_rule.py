@@ -4,7 +4,10 @@ forms a serving step takes, raw `jax.numpy` in and out. A head's state
 
     S_t = (I − β_t k_t k_tᵀ) Diag(α_t) S_{t−1} + β_t k_t v_tᵀ,  o_t = S_tᵀ q_t
 
-with α_t = exp(g_t) a key CHANNEL (g ≤ 0) and β_t a head.
+with α_t = exp(g_t) a key CHANNEL (g ≤ 0) and β_t a head: KDA's gate,
+`g` [..., H, d_k]. A gate of ONE decay a head (gated DeltaNet's), `g`
+[..., H], is the same recurrence with α_t the same on every channel; the
+shape of `g` chooses it, in both forms.
 
     RECURRENT  `delta_rule_step`: one row a slot, every slot at once.
                Each row reads its slot's state once and writes it once;
@@ -29,12 +32,19 @@ with α_t = exp(g_t) a key CHANNEL (g ≤ 0) and β_t a head.
                against a REFERENCE row, a sub-block of 16 rows each (the
                last row before the sub-block of i): both factors then
                lie in (e^-80·…, e^80] for g ≥ −5 and neither overflows
-               float32, which exp(−G_j) alone would after 18 rows. A
+               float32, which exp(−G_j) alone would after 18 rows. With
+               a decay a HEAD the ratio is one number a pair of rows,
+                 D[i, j] = exp(G_i − G_j) ≤ 1  (j ≤ i),
+               so A = β ⊙ (K Kᵀ ⊙ D), B = Q Kᵀ ⊙ D and K̄ = K ⊙ exp(G_C −
+               G) need no reference row and hold for a gate of ANY size
+               (gated DeltaNet's is unbounded below); β may reach 2. A
                Pallas kernel on a TPU (a chunk a grid step, copied from
                the flat rows where they lie, the state carried in the
-               result's block); in plain XLA elsewhere: the runs laid
-               out again so that each starts a chunk, batched products,
-               `solve_triangular`, a `fori_loop` over the chunks in use.
+               result's block; with a decay a head from the runs laid
+               out again, heads leading); in plain XLA elsewhere: the
+               runs laid out again so that each starts a chunk, batched
+               products, `solve_triangular`, a `fori_loop` over the
+               chunks in use.
 
 Both forms treat a row that is not live as the identity on the state (α
 = 1, β = 0, k = 0).
@@ -60,14 +70,17 @@ _HI = jax.lax.Precision.HIGHEST
 
 
 def delta_rule_step(state, q, k, v, g, beta, live, fresh, kernel=None):
-    """One recurrent step, a row a slot: q k g [S, H, d_k], v [S, H, d_v],
-    β [S, H], all float32; `live` [S] bool; `fresh` [S] bool: the row is
-    its sequence's first, so its slot's state counts as zero whatever is
-    stored (α = 0 forgets it); `state` [S, H, d_k, d_v] float32. Returns
-    (o [S, H, d_v] float32, unspecified where not live; the new state).
-    `kernel`: None takes the Pallas kernel where Pallas kernels run; a
-    bool says which (`tools/kda_sweep.py` times both)."""
+    """One recurrent step, a row a slot: q k [S, H, d_k], v [S, H, d_v],
+    g [S, H, d_k] or [S, H] (a decay a head), β [S, H], all float32;
+    `live` [S] bool; `fresh` [S] bool: the row is its sequence's first,
+    so its slot's state counts as zero whatever is stored (α = 0 forgets
+    it); `state` [S, H, d_k, d_v] float32. Returns (o [S, H, d_v]
+    float32, unspecified where not live; the new state). `kernel`: None
+    takes the Pallas kernel where Pallas kernels run; a bool says which
+    (`tools/kda_sweep.py` times both)."""
     S = state.shape[0]
+    if g.ndim == beta.ndim:          # a decay a head: on every channel
+        g = jnp.broadcast_to(g[..., None], k.shape)
     lv = live[:, None, None]
     a = jnp.where(lv, jnp.where(fresh[:, None, None], 0.0, jnp.exp(g)), 1.0)
     k = jnp.where(lv, k, 0.0)
@@ -125,26 +138,55 @@ def _chunk_terms(q, k, v, g, beta):
             sol[..., :dk], sol[..., dk:], B)
 
 
+def _head_chunk_terms(q, k, v, g, beta):
+    """`_chunk_terms` for a decay a HEAD, g [N, H, C]: the ratios of
+    two rows' decays are one number, exp(G_i − G_j) ≤ 1 below the
+    diagonal, whatever the gate's size."""
+    C = k.shape[2]
+    G = jnp.cumsum(g, axis=2)                                  # ≤ 0
+    tri = jnp.arange(C)[:, None] - jnp.arange(C)[None, :]
+    D = jnp.where(tri >= 0, jnp.exp(jnp.minimum(
+        G[..., :, None] - G[..., None, :], 0.0)), 0.0)         # [N,H,C,C]
+    kk = jnp.einsum("nhik,nhjk->nhij", k, k, precision=_HI)
+    qk = jnp.einsum("nhik,nhjk->nhij", q, k, precision=_HI)
+    A = jnp.where(tri > 0, kk * D, 0.0) * beta[..., None]
+    B = qk * D
+    gam = jnp.exp(G)[..., None]
+    dk = k.shape[-1]
+    rhs = jnp.concatenate([k * gam, v], axis=-1) * beta[..., None]
+    sol = jax.scipy.linalg.solve_triangular(
+        A + jnp.eye(C, dtype=A.dtype), rhs, lower=True, unit_diagonal=True)
+    last = G[:, :, -1:]
+    return (q * gam, k * jnp.exp(last - G)[..., None],
+            jnp.broadcast_to(jnp.exp(last), last.shape[:2] + (dk,)),
+            sol[..., :dk], sol[..., dk:], B)
+
+
 def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
                        kernel=None):
     """The CHUNKED form over the runs of `runs` (a `SlotRunLayout`) of a
-    flat step's rows: q k g [T, H, d_k], v [T, H, d_v], β [T, H],
-    float32; `state` [S, H, d_k, d_v] float32. A run whose first row's kv
-    length is 1 (position 0) starts from zero, any other from its slot's
-    stored state. Returns (o [T, H, d_v] float32, zero off the runs'
-    rows; the new state; chunks run). `kernel`: None takes the Pallas
-    kernel where Pallas kernels run (T holds a chunk, H whole tiles of 8
-    heads), which reads the runs' rows where they lie; a bool says which.
-    The plain form lays the runs out again, a run from a multiple of
-    `chunk` (`runs.align` is then `chunk`, spare 0)."""
+    flat step's rows: q k [T, H, d_k], g [T, H, d_k] or [T, H] (a decay
+    a head), v [T, H, d_v], β [T, H], float32; `state` [S, H, d_k, d_v]
+    float32. A run whose first row's kv length is 1 (position 0) starts
+    from zero, any other from its slot's stored state. Returns (o [T, H,
+    d_v] float32, zero off the runs' rows; the new state; chunks run).
+    `kernel`: None takes the Pallas kernel where Pallas kernels run; a
+    bool says which. With a decay a channel the kernel reads the runs'
+    rows where they lie (T holds a chunk, H whole tiles of 8 heads); the
+    plain form, and the kernel of a decay a head, take the runs laid out
+    again, a run from a multiple of `chunk` (`runs.align` is then
+    `chunk`, spare 0)."""
     T, H, dk = k.shape
     dv = v.shape[-1]
     C = int(chunk)
+    head_gate = g.ndim == beta.ndim
     if kernel is None:
-        kernel = _pallas_backend_ok() and T >= C and H % 8 == 0
+        kernel = _pallas_backend_ok() and (
+            head_gate or (T >= C and H % 8 == 0))
+    flat = kernel and not head_gate
     # chunk n is chunk `part` of run `run_of`: a run's chunks in order,
     # the runs in the order of their rows
-    N = T // C + runs.max_runs if kernel else runs.total // C
+    N = T // C + runs.max_runs if flat else runs.total // C
     per_run = -(-runs.run_rows // C)
     ends = jnp.cumsum(per_run)
     n_used = ends[-1]
@@ -155,18 +197,19 @@ def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
     starts_run = part == 0
     slot_of = runs.run_slots[run_of]
     fresh = runs.run_first[run_of] == 1
+    # past the chunks in use, a kernel names the last used chunk's slot
+    # (no block moves)
+    last_slot = slot_of[jnp.minimum(chunks, jnp.maximum(n_used - 1, 0))]
 
-    if kernel:
+    if flat:
         from ...ops.pallas_kernels.delta_rule import delta_rule_chunks
 
         # a run's first FLAT row: the one its first laid-out row reads
         row0 = runs.src[runs.run_row0][run_of] + part * C
         live = jnp.clip(runs.run_rows[run_of] - part * C, 0, C)
-        # past the chunks in use: the last used chunk's slot (no block moves)
-        slot_of = slot_of[jnp.minimum(chunks, jnp.maximum(n_used - 1, 0))]
         o, state = delta_rule_chunks(
             state, q, k, v, g, beta, jnp.zeros((T, H, dv), jnp.float32),
-            row0, live, slot_of, starts_run, fresh, n_used[None], chunk=C)
+            row0, live, last_slot, starts_run, fresh, n_used[None], chunk=C)
         return o, state, n_used
 
     total = runs.total
@@ -179,8 +222,21 @@ def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
         x = x.reshape((N, C) + x.shape[1:])
         return jnp.moveaxis(x, 2, 1)                           # [N,H,C,·]
 
+    def unlay(O):
+        O = jnp.moveaxis(O, 1, 2).reshape(total, H, dv)
+        return jnp.where(runs.expanded[:, None, None], O[runs.dest], 0.0)
+
+    if kernel:                                  # a decay a head
+        from ...ops.pallas_kernels.delta_rule import delta_rule_head_chunks
+
+        O, state = delta_rule_head_chunks(
+            state, lay(q), lay(k), lay(v), lay(g), lay(beta), last_slot,
+            starts_run, fresh, n_used[None])
+        return unlay(O), state, n_used
+
     ends_run = part == per_run[run_of] - 1
-    Qg, Kbar, dec, W, Uh, B = _chunk_terms(
+    terms = _head_chunk_terms if head_gate else _chunk_terms
+    Qg, Kbar, dec, W, Uh, B = terms(
         lay(q), lay(k), lay(v), lay(g), lay(beta))
 
     def body(n, carry):
@@ -202,6 +258,4 @@ def delta_rule_chunked(state, q, k, v, g, beta, runs, chunk=CHUNK,
         0, n_used, body,
         (jnp.zeros((H, dk, dv), jnp.float32), state,
          jnp.zeros((N, H, C, dv), jnp.float32)))
-    O = jnp.moveaxis(O, 1, 2).reshape(total, H, dv)
-    return jnp.where(runs.expanded[:, None, None], O[runs.dest], 0.0), \
-        state, n_used
+    return unlay(O), state, n_used

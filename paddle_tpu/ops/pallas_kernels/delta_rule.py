@@ -32,7 +32,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["delta_rule_recurrent", "delta_rule_chunks"]
+__all__ = ["delta_rule_recurrent", "delta_rule_chunks",
+           "delta_rule_head_chunks"]
 
 
 def _kernel(order_ref, n_ref, s_ref, a_ref, k_ref, kb_ref, q_ref, vb_ref,
@@ -383,3 +384,274 @@ def delta_rule_chunks(state, q, k, v, g, beta, o, row0, live, slot_of,
       jnp.pad(beta, ((0, 0), (0, 128 - H)))[:, None, :],
       o.reshape(T * H, dv))
     return new_o.reshape(T, H, dv), new
+
+
+# ---- the chunked form with a decay a head -------------------------------
+#
+# Gated DeltaNet's gate is ONE log decay a head and token, unbounded below
+# (g = −exp(A_log) · softplus(·)), and its β reaches 2. The arithmetic is
+# `nn/functional/delta_rule.py`'s head-gate form: with G a head's
+# cumulative log decay in the chunk, D[i, j] = exp(G_i − G_j) ≤ 1 under
+# the diagonal needs no reference row and no clamp. Against the kernel
+# above:
+#   LAID-OUT, HEAD-MAJOR operands `[H, N·C, d]`: the runs laid out again
+#            as the plain form lays them (a run from a multiple of C,
+#            rows past its end zero, which are neutral), heads leading,
+#            widths in whole lane tiles. A chunk is then ONE aligned copy
+#            a operand of every head's C rows, a head's rows `[C, d]` a
+#            plain load, and the result one aligned copy back: any head
+#            count and any width (d_k 96, d_v 192) serve, where the flat
+#            rows' strided loads need heads in tiles of 8 and rows of 128
+#            lanes.
+#   G        g arrives as β does, a row a lane tile, and is summed once a
+#            chunk for every head `[C, 128]`, then transposed by a product
+#            with the identity (exact at HIGHEST): a head's G is a column
+#            AND a row.
+#   T        by DOUBLING: the inverse of each diagonal block of b rows is
+#            known, and T_2b = T_b + T_b P_b T_b (P_b: P on the off-
+#            diagonal blocks that pair them) gives that of 2b; two
+#            products a level. The product Π (I + P^(2^i)) of the kernel
+#            above sums powers of P, which with β near 2 grow as ‖P‖^m
+#            and cancel in float32; no term here is larger than T.
+#   groups   of 128 / C heads; a last group of the heads left over.
+
+
+def _head_chunk_kernel(slot_ref, first_ref, fresh_ref, used_ref, s_ref,
+                       q_hbm, k_hbm, v_hbm, g_hbm, b_hbm, o_hbm, s_out_ref,
+                       qbuf, kbuf, vbuf, gbuf, bbuf, obuf, in_sems, out_sems,
+                       *, chunk):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = pl.program_id(0)
+    C = chunk
+    H, dk, dv = s_ref.shape[1:]
+    dkp = qbuf.shape[-1]
+    hb = max(1, 128 // C)                   # heads a full group
+    hi = jax.lax.Precision.HIGHEST
+    f32 = jnp.float32
+
+    def mm(a, b, dims=(((1,), (0,)), ((), ()))):
+        return jax.lax.dot_general(a, b, dims, precision=hi,
+                                   preferred_element_type=f32)
+
+    def copies(m, half):
+        """Chunk m's five copies into buffer `half` (to start, or the
+        same descriptors to wait on)."""
+        rows = pl.ds(pl.multiple_of(m * C, C), C)
+        return [pltpu.make_async_copy(src.at[:, rows], buf.at[half],
+                                      in_sems.at[half, i])
+                for i, (src, buf) in enumerate((
+                    (q_hbm, qbuf), (k_hbm, kbuf), (v_hbm, vbuf)))] + [
+            pltpu.make_async_copy(src.at[rows], buf.at[half],
+                                  in_sems.at[half, 3 + i])
+            for i, (src, buf) in enumerate(((g_hbm, gbuf), (b_hbm, bbuf)))]
+
+    def write(m, half, start):
+        dma = pltpu.make_async_copy(
+            obuf.at[half], o_hbm.at[:, pl.ds(pl.multiple_of(m * C, C), C)],
+            out_sems.at[half])
+        dma.start() if start else dma.wait()
+
+    def stacked(xs):
+        return jnp.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
+
+    def padded(x, rows, lanes):
+        """x [r, w] in the top left of zeros [rows, lanes]."""
+        if x.shape[0] < rows:
+            x = jnp.concatenate(
+                [x, jnp.zeros((rows - x.shape[0], x.shape[1]), f32)], axis=0)
+        if x.shape[1] < lanes:
+            x = jnp.concatenate(
+                [x, jnp.zeros((rows, lanes - x.shape[1]), f32)], axis=1)
+        return x
+
+    @pl.when((n == 0) & (used_ref[0] == 0))
+    def _():                    # no chunk at all: slot 0 passes through
+        s_out_ref[...] = s_ref[...]
+
+    @pl.when(n < used_ref[0])
+    def _():
+        half = n % 2
+
+        @pl.when(n == 0)
+        def _():
+            for c in copies(0, 0):
+                c.start()
+
+        @pl.when(n + 1 < used_ref[0])
+        def _():
+            for c in copies(n + 1, 1 - half):
+                c.start()
+
+        for c in copies(n, half):
+            c.wait()
+
+        starts = first_ref[n] == 1
+        fresh = fresh_ref[n] == 1
+        row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+        lane_h = jax.lax.broadcasted_iota(jnp.int32, (C, 128), 1)
+        sub_h = jax.lax.broadcasted_iota(jnp.int32, (128, C), 0)
+        beta_rows = bbuf[half, :, 0, :]                        # [C, 128]
+        # every head's cumulative log decay, ≤ 0: columns [C, 128], and
+        # the same transposed, rows [128, C]
+        G_all = gbuf[half, :, 0, :]
+        s = 1
+        while s < C:
+            G_all = G_all + jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, (C, 128), 0) >= s,
+                pltpu.roll(G_all, s, 0), 0.0)
+            s *= 2
+        G_rows = mm(G_all, (row == col).astype(f32),
+                    (((0,), (0,)), ((), ())))
+
+        def a_head(h, j, nh):
+            """Head h's state-free terms: its blocks of the group's P
+            and B, [β Γ ⊙ K | β V], Γ ⊙ Q, K̄, the chunk's decay, S."""
+            q, k, v = (buf[half, h] for buf in (qbuf, kbuf, vbuf))
+            beta = jnp.sum(jnp.where(lane_h == h, beta_rows, 0.0), axis=1,
+                           keepdims=True)                      # [C, 1]
+            G = jnp.sum(jnp.where(lane_h == h, G_all, 0.0), axis=1,
+                        keepdims=True)                         # [C, 1]
+            D = jnp.exp(jnp.minimum(G - jnp.sum(
+                jnp.where(sub_h == h, G_rows, 0.0), axis=0, keepdims=True),
+                0.0))                                          # [C, C]
+            prod = mm(jnp.concatenate([k, q], axis=0), k,
+                      (((1,), (1,)), ((), ())))                # [2C, C]
+
+            def on_diagonal(x):
+                """[C, C] as block (j, j) of the group's rows [C, nh·C]."""
+                parts = ([jnp.zeros((C, j * C), f32)] if j else []) + [x] \
+                    + ([jnp.zeros((C, (nh - j - 1) * C), f32)]
+                       if j < nh - 1 else [])
+                return jnp.concatenate(parts, axis=1) if len(parts) > 1 \
+                    else x
+
+            gam = jnp.exp(G)
+            last = G[C - 1:C, :]                               # [1, 1]
+            S0 = jnp.where(starts, jnp.where(fresh, 0.0, s_ref[0, h]),
+                           s_out_ref[0, h])
+            return (on_diagonal(jnp.where(row > col, prod[:C] * D, 0.0)
+                                * -beta),
+                    on_diagonal(jnp.where(row >= col, prod[C:] * D, 0.0)),
+                    jnp.concatenate([k * gam, v], axis=1) * beta, q * gam,
+                    k * jnp.exp(last - G), jnp.exp(last),
+                    padded(S0, dkp, v.shape[-1]))
+
+        def group(h0, nh):
+            """Heads h0 … h0 + nh − 1 (nh static): their C × C algebra as
+            ONE block-diagonal [nh·C, nh·C] operand."""
+            GC = nh * C
+            g_row = jax.lax.broadcasted_iota(jnp.int32, (GC, GC), 0)
+            g_col = jax.lax.broadcasted_iota(jnp.int32, (GC, GC), 1)
+            P, B, R, Qg, Kbar, decay, S0 = zip(*(
+                a_head(h0 + j, j, nh) for j in range(nh)))
+            P, B = stacked(P), stacked(B)
+            # (I − P)⁻¹: the inverses of blocks of b rows → of 2b
+            Tm = (g_row == g_col).astype(f32) + jnp.where(
+                (g_row // 2 == g_col // 2) & (g_row % 2 == 1), P, 0.0)
+            b = 2
+            while b < C:
+                pair = (g_row // (2 * b) == g_col // (2 * b)) \
+                    & ((g_row // b) % 2 == 1) & ((g_col // b) % 2 == 0)
+                Tm = Tm + mm(Tm, mm(jnp.where(pair, P, 0.0), Tm))
+                b *= 2
+            WU = mm(Tm, stacked(R))                    # [GC, d_k + d_v]
+            U, QS = [], []
+            for j in range(nh):
+                mine = slice(j * C, (j + 1) * C)
+                XS = mm(jnp.concatenate([WU[mine, :dkp], Qg[j]], axis=0),
+                        S0[j])
+                U.append(WU[mine, dkp:] - XS[:C])
+                QS.append(XS[C:])
+            BU = mm(B, stacked(U))
+            for j in range(nh):
+                obuf[half, h0 + j] = QS[j] + BU[j * C:(j + 1) * C]
+                new = decay[j] * S0[j] + mm(
+                    Kbar[j], U[j], (((0,), (0,)), ((), ())))
+                s_out_ref[0, h0 + j] = new[:dk, :dv]
+
+        def full_group(gi, carry):
+            group(gi * hb, hb)
+            return carry
+
+        jax.lax.fori_loop(0, H // hb, full_group, 0)
+        if H % hb:
+            group(H - H % hb, H % hb)
+
+        # the chunk's rows back, a chunk after the copy before it
+        @pl.when(n > 0)
+        def _():
+            write(n - 1, 1 - half, False)
+
+        write(n, half, True)
+
+        @pl.when(n == used_ref[0] - 1)
+        def _():
+            write(n, half, False)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def delta_rule_head_chunks(state, q, k, v, g, beta, slot_of, starts_run,
+                           fresh, n_used, interpret=False):
+    """The chunked form with a decay a HEAD over chunks laid out as the
+    plain form lays them: state [S, H, d_k, d_v]; q k [N, H, C, d_k], v
+    [N, H, C, d_v], g β [N, H, C] float32, rows past a run's end zero;
+    chunk n < n_used [1] of slot slot_of[n] (past `n_used`: the last used
+    chunk's slot); starts_run / fresh [N] whether it begins a run and
+    whether that run begins its sequence → (o [N, H, C, d_v], the new
+    state). Any head count (≤ 128) and widths; C a power of two, 8 …
+    128."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, dk, dv = state.shape
+    N, C = q.shape[0], q.shape[2]
+    if C & (C - 1) or C < 8 or C > 128 or H > 128:
+        raise ValueError(f"chunks of {C} rows of {H} heads: a power of "
+                         "two of 8 … 128 rows, at most 128 heads")
+    lanes = lambda d: -(-d // 128) * 128                  # noqa: E731
+    dkp, dvp = lanes(dk), lanes(dv)
+
+    def head_major(x, d):
+        """[N, H, C, w] → [H, N·C, d]."""
+        x = jnp.moveaxis(x, 1, 0).reshape(H, N * C, x.shape[-1])
+        return jnp.pad(x, ((0, 0), (0, 0), (0, d - x.shape[-1])))
+
+    def by_lanes(x):
+        """[N, H, C] → [N·C, 1, 128]: a row a lane tile."""
+        x = jnp.swapaxes(x, 1, 2).reshape(N * C, H)
+        return jnp.pad(x, ((0, 0), (0, 128 - H)))[:, None, :]
+
+    def by_slot(n, slot, *_):
+        return (slot[n], 0, 0, 0)
+
+    slab = pl.BlockSpec((1, H, dk, dv), by_slot)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    lane_rows = pltpu.VMEM((2, C, 1, 128), jnp.float32)
+    o, new = pl.pallas_call(
+        functools.partial(_head_chunk_kernel, chunk=C),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(N,),
+            in_specs=[slab, hbm, hbm, hbm, hbm, hbm],
+            out_specs=[hbm, slab],
+            scratch_shapes=[
+                pltpu.VMEM((2, H, C, dkp), jnp.float32),
+                pltpu.VMEM((2, H, C, dkp), jnp.float32),
+                pltpu.VMEM((2, H, C, dvp), jnp.float32),
+                lane_rows, lane_rows,
+                pltpu.VMEM((2, H, C, dvp), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 5)),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((H, N * C, dvp), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+    )(*(x.astype(jnp.int32) for x in (slot_of, starts_run, fresh, n_used)),
+      state, head_major(q, dkp), head_major(k, dkp), head_major(v, dvp),
+      by_lanes(g), by_lanes(beta))
+    return jnp.moveaxis(o[:, :, :dv].reshape(H, N, C, dv), 0, 1), new

@@ -341,6 +341,13 @@ def _page_grid_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref,
 # over a full context read 20 / 33 / 38 / 53-58 / 61 % of 819 GB/s on a
 # v5e, PERF.md §6, PR 28); 16 MiB no longer fits the scoped VMEM.
 _GQA_GROUP_VMEM_BYTES = 8 * 1024 * 1024
+# The walk unrolls its loop over K/V heads, and each head's scores and
+# weights `[qb·G', group·P]` float32 stand on its stack: at 8 heads
+# (laguna-s-2.1) that fits the default scoped VMEM (58.2 MB); at 30 heads
+# of a tick's 8-row blocks (olmo-hybrid-7b) it takes 59.5 MB, so a walk of
+# more heads is given more of the core's 128 MiB
+_GQA_HEADS_IN_DEFAULT_VMEM = 8
+_GQA_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 
 def _gqa_walks(page_size, kdim, dtype):
@@ -520,6 +527,9 @@ def _gqa_paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
                 pltpu.VMEM((kv_heads, qb * gp, 128), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, gp, kdim),
                                        q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_GQA_VMEM_LIMIT_BYTES)
+        if kv_heads > _GQA_HEADS_IN_DEFAULT_VMEM else None,
         interpret=interpret,
     )
 

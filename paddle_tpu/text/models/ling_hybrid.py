@@ -235,6 +235,86 @@ class _SlotRuns:
                 self.runs.expanded[self.start], 0, self.rows)
 
 
+def short_conv(pre, taps, tail, slot_ids, pos, sr):
+    """The causal depthwise convolution of K taps [K, W] (the last weighs
+    a row's own input) over a step's rows `pre` [T, W] float32: (y [T,
+    W] before the activation, the slots' new tails). `tail` [S, K − 1,
+    W] the rows before each slot's run that the convolution still reads,
+    or None (no cache: every run starts its sequence; `sr` then unused).
+    A step's rows stand side by side a slot (`_SlotRuns` `sr`)."""
+    T, K = pre.shape[0], taps.shape[0]
+    taps = taps.astype(jnp.float32)
+    new_tail = None
+    if tail is not None:
+        # the rows a later step reads back are rounded as stored
+        pre = pre.astype(tail.dtype).astype(jnp.float32)
+    y = pre * taps[K - 1][None, :]
+    for s in range(1, K):
+        prev = jnp.roll(pre, s, axis=0)         # row t − s
+        if tail is None:
+            prev = jnp.where((pos >= s)[:, None], prev, 0.0)
+        else:
+            # before the run: the slot's tail; before the sequence: 0
+            kept = tail[slot_ids, (sr.offset - s + K - 1).clip(0, K - 2)]
+            prev = jnp.where(
+                (sr.offset >= s)[:, None], prev,
+                jnp.where((pos >= s)[:, None],
+                          kept.astype(jnp.float32), 0.0))
+        y = y + prev * taps[K - 1 - s][None, :]
+    if tail is not None:
+        # the last K − 1 rows of each slot's sequence after its run:
+        # the run's own, and the old tail's where the run is shorter
+        i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        back = sr.rows[:, None] - (K - 1) + i              # [S, K − 1]
+        own = pre.astype(tail.dtype)[
+            (sr.start[:, None] + back).clip(0, T - 1)]
+        old = jnp.take_along_axis(
+            tail, (i + sr.rows[:, None]).clip(0, K - 2)[:, :, None],
+            axis=1)
+        new_tail = jnp.where(
+            (sr.rows > 0)[:, None, None],
+            jnp.where((back >= 0)[:, :, None], own, old), tail)
+    return y, new_tail
+
+
+def delta_rule_forms(state, q, k, v, g, beta, pos, sr, words):
+    """A step's rows through the slots' states [S, H, d_k, d_v], each run
+    by the form its length gives it (`_SlotRuns` `sr`): (o [T, H, d_v],
+    the new state). g a decay a channel [T, H, d_k] or a head [T, H];
+    `words` the scopes of the chunked and the recurrent form."""
+    T = q.shape[0]
+    o = jnp.zeros(v.shape, jnp.float32)
+    # a row that is not live is neutral. The two selects fuse into what
+    # computes g and β, and give them the TYPE q, k and v have: in the
+    # first layer g and β descend from the step's token ids alone and
+    # carry no mesh, after it from the caches too, and the chunk kernel
+    # would be traced once for each (2 s a trace and as much again to
+    # lower: PERF.md §6). `offset` is made of the slot ids, which are
+    # typed as the caches are
+    live = sr.live & (sr.offset >= 0)          # = sr.live
+    g = jnp.where(live.reshape((T,) + (1,) * (g.ndim - 1)), g, 0.0)
+    beta = jnp.where(live[:, None], beta, 0.0)
+    if sr.runs is not None:
+        with _scope(words[0]):
+            o, state, _ = delta_rule.delta_rule_chunked(
+                state, q, k, v, g, beta, sr.runs)
+
+    def one(i, carry):
+        o, state = carry
+        live = i < sr.short_rows
+        rows = (sr.short_start + i).clip(0, T - 1)
+        got, state = delta_rule.delta_rule_step(
+            state, q[rows], k[rows], v[rows], g[rows], beta[rows],
+            live, live & (pos[rows] == 0))
+        return o.at[jnp.where(live, rows, T)].set(got, mode="drop"), state
+
+    with _scope(words[1]):
+        if sr.one_row_a_slot:
+            return one(0, (o, state))          # the fused window
+        return jax.lax.fori_loop(0, jnp.max(sr.short_rows), one,
+                                 (o, state))
+
+
 class LingHybridForCausalLM(SarvamMLAForCausalLM):
     """The served model: eager `forward`, and the engine's two step
     bodies over latent pages and state slabs. The MLA layer's arithmetic
@@ -271,40 +351,11 @@ class LingHybridForCausalLM(SarvamMLAForCausalLM):
         sequence)."""
         c = self.config
         T, H, dk = n.shape[0], c.num_heads, c.head_dim
-        hk, K = c.kda_width, c.conv_taps
+        hk = c.kda_width
         p = _mm(n, layer.w_qkva._value)
         pre, a = p[:, :3 * hk], p[:, 3 * hk:]
-        taps = layer.conv._value.astype(jnp.float32)
-        new_tail = None
-        if tail is not None:
-            # the rows a later step reads back are rounded as stored
-            pre = pre.astype(tail.dtype).astype(jnp.float32)
-        y = pre * taps[K - 1][None, :]
-        for s in range(1, K):
-            prev = jnp.roll(pre, s, axis=0)         # row t − s
-            if tail is None:
-                prev = jnp.where((pos >= s)[:, None], prev, 0.0)
-            else:
-                # before the run: the slot's tail; before the sequence: 0
-                kept = tail[slot_ids, (sr.offset - s + K - 1).clip(0, K - 2)]
-                prev = jnp.where(
-                    (sr.offset >= s)[:, None], prev,
-                    jnp.where((pos >= s)[:, None],
-                              kept.astype(jnp.float32), 0.0))
-            y = y + prev * taps[K - 1 - s][None, :]
-        if tail is not None:
-            # the last K − 1 rows of each slot's sequence after its run:
-            # the run's own, and the old tail's where the run is shorter
-            i = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-            back = sr.rows[:, None] - (K - 1) + i              # [S, K − 1]
-            own = pre.astype(tail.dtype)[
-                (sr.start[:, None] + back).clip(0, T - 1)]
-            old = jnp.take_along_axis(
-                tail, (i + sr.rows[:, None]).clip(0, K - 2)[:, :, None],
-                axis=1)
-            new_tail = jnp.where(
-                (sr.rows > 0)[:, None, None],
-                jnp.where((back >= 0)[:, :, None], own, old), tail)
+        y, new_tail = short_conv(pre, layer.conv._value, tail, slot_ids,
+                                 pos, sr)
         y = jax.nn.silu(y).reshape(T, 3, H, dk)
         q = _l2_norm(y[:, 0]) * dk ** -0.5
         k = _l2_norm(y[:, 1])
@@ -324,38 +375,8 @@ class LingHybridForCausalLM(SarvamMLAForCausalLM):
     def _kda_recurrence(self, state, q, k, v, g, beta, pos, sr):
         """The step's rows through the slots' states, each run by the
         form its length gives it: (o [T, H, d_v], the new state)."""
-        T = q.shape[0]
-        o = jnp.zeros(v.shape, jnp.float32)
-        # a row that is not live is neutral. The two selects fuse into
-        # what computes g and β, and give them the TYPE q, k and v have:
-        # in the first layer g and β descend from the step's token ids
-        # alone and carry no mesh, after it from the caches too, and
-        # `delta_rule_chunks` would be traced once for each (2 s a trace
-        # and as much again to lower: PERF.md §6, PR 36). `offset` is
-        # made of the slot ids, which are typed as the caches are
-        live = sr.live & (sr.offset >= 0)          # = sr.live
-        g = jnp.where(live[:, None, None], g, 0.0)
-        beta = jnp.where(live[:, None], beta, 0.0)
-        if sr.runs is not None:
-            with _scope("kda_chunk"):
-                o, state, _ = delta_rule.delta_rule_chunked(
-                    state, q, k, v, g, beta, sr.runs)
-
-        def one(i, carry):
-            o, state = carry
-            live = i < sr.short_rows
-            rows = (sr.short_start + i).clip(0, T - 1)
-            got, state = delta_rule.delta_rule_step(
-                state, q[rows], k[rows], v[rows], g[rows], beta[rows],
-                live, live & (pos[rows] == 0))
-            return o.at[jnp.where(live, rows, T)].set(got, mode="drop"), \
-                state
-
-        with _scope("kda_recur"):
-            if sr.one_row_a_slot:
-                return one(0, (o, state))          # the fused window
-            return jax.lax.fori_loop(0, jnp.max(sr.short_rows), one,
-                                     (o, state))
+        return delta_rule_forms(state, q, k, v, g, beta, pos, sr,
+                                ("kda_chunk", "kda_recur"))
 
     # ---- eager forward (no cache) ------------------------------------
 

@@ -387,3 +387,76 @@ def test_the_delta_rules_chunk_kernel_compiles_for_v5e(one_chip):
     # beside the zeroed result only β's rows padded to a lane tile (1
     # MiB) and the layout's index vectors: a tenth of ONE operand
     assert mem.temp_size_in_bytes < T * H * dk * 4 // 10
+
+
+# olmo-hybrid-7b's full layers: the head-major walk with ONE query head a
+# K/V head (30 of 128, bf16 pools [8 739, 30, 16, 128] = 4 GiB over two
+# layers): the fused window's 128 rows and the tick's 512 in blocks of 8
+# rows of one slot (512 + 128 · 7 rows). 30 heads' scores and weights on
+# the kernel's stack need the raised scoped VMEM (59.5 MB in the tick).
+@pytest.mark.parametrize("tokens,qps", [
+    pytest.param(128, None, id="window_rows"),
+    pytest.param(1408, 8, id="tick_blocks"),
+])
+def test_head_major_walk_at_one_query_head_a_kv_head_compiles_for_v5e(
+        one_chip, tokens, qps):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (8739, 30, 16, 128)
+    args = [sds((tokens, 30, 128), jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds((128, 128), jnp.int32),
+            sds((tokens,), jnp.int32), sds((tokens,), jnp.int32),
+            sds((), jnp.int32)]
+
+    def call(q, k, v, pt, sid, lens, off):
+        return ragged_paged_attention(q, k, v, pt, sid, lens,
+                                      frontier_offset=off, head_major=True,
+                                      q_per_slot=qps)
+
+    with jax.enable_x64(False):
+        text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_the_delta_rules_head_gate_forms_compile_for_v5e(one_chip):
+    """olmo-hybrid-7b's GDN layers at the cell's shapes: 128 slots of 30
+    heads' [96, 256] float32 state (the value width 192 in whole lane
+    tiles), a 512-row tick's chunked form from its runs laid out head-
+    major, and the recurrent step a row a slot, a decay a head: both
+    kernels take the state in place, with no copy of it (a `[…, 96,
+    192]` state would be laid out slot-minor and copied in and out)."""
+    from paddle_tpu.nn.functional import delta_rule as dr
+    from paddle_tpu.nn.functional.attention import SlotRunLayout
+    from paddle_tpu.text.models.ling_hybrid import _CHUNKED_MIN_ROWS
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, H, dk, dv, T = 128, 30, 96, 256, 512
+    f32 = jnp.float32
+    state = sds((S, H, dk, dv), f32)
+
+    def tick(state, q, k, v, g, beta, sids, lens):
+        runs = SlotRunLayout(sids, lens, _CHUNKED_MIN_ROWS, dr.CHUNK, 0)
+        return dr.delta_rule_chunked(state, q, k, v, g, beta, runs,
+                                     kernel=True)
+
+    def step(state, q, k, v, g, beta, live, fresh):
+        return dr.delta_rule_step(state, q, k, v, g, beta, live, fresh,
+                                  kernel=True)
+
+    for fn, rows, tail in (
+            (tick, T, [sds((T,), jnp.int32)] * 2),
+            (step, S, [sds((S,), jnp.bool_)] * 2)):
+        args = [state, sds((rows, H, dk), f32), sds((rows, H, dk), f32),
+                sds((rows, H, dv), f32), sds((rows, H), f32),
+                sds((rows, H), f32)] + tail
+        with jax.enable_x64(False):
+            compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+                *args).compile()
+        assert compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"') == 1
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == S * H * dk * dv * 4   # in place
+        assert mem.temp_size_in_bytes < S * H * dk * dv * 4 // 50
