@@ -710,9 +710,9 @@ class _CompiledStepBase:
     # the jitted function's name is the program's on a device trace:
     # `jit_pure_tick`, `jit_pure_window` (a reader that searches for
     # `jit_pure` finds both; docs/OBSERVABILITY.md)
-    # page-major paged-attention launches ONE dispatch makes, by the
-    # body the kernel built for their shapes ({"mxu": n, "vpu": n});
-    # known once the program is traced, i.e. from its first dispatch on
+    # paged-attention launches ONE dispatch makes, by the body the
+    # kernel built for their shapes ({"mxu": n, "vpu": n}, and "mha" /
+    # "gqa" / "latent" once traced); known from its first dispatch on
     launches = {}
 
     def cache_size(self):
@@ -1212,6 +1212,9 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
         if self._latent:       # the latent walks' launches, both forms
             self.stats["paged_attn_latent_launches"] = 0
             self.stats["paged_attn_latent_expanded_launches"] = 0
+        if any(k.head_major for k in kinds):  # the head-major walk's bodies
+            self.stats["paged_attn_mha_launches"] = 0
+            self.stats["paged_attn_gqa_launches"] = 0
         # the model's own step counters (e.g. an expert layer's), summed
         # into `stats` under their names; a tick's arrive with the next
         # read the ENGINE thread makes anyway (`_note_counters`: never a
@@ -2082,6 +2085,10 @@ class LLMEngine:  # ptlint: thread-shared (scraped by /metrics)
                 self.stats["paged_attn_mxu_launches"],
             "paged_attn_vpu_launches":
                 self.stats["paged_attn_vpu_launches"],
+            "paged_attn_mha_launches":
+                self.stats.get("paged_attn_mha_launches", 0),
+            "paged_attn_gqa_launches":
+                self.stats.get("paged_attn_gqa_launches", 0),
             "tokens_per_dispatch": _TOK_PER_DISPATCH.value,
             "admission_p50_s": _ADMIT_SECONDS.quantile(0.5),
             "admission_p99_s": _ADMIT_SECONDS.quantile(0.99),

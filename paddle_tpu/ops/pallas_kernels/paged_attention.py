@@ -343,9 +343,9 @@ def _page_grid_kernel(sid_ref, pt_ref, lens_ref, off_ref, q_ref, k_ref,
 _GQA_GROUP_VMEM_BYTES = 8 * 1024 * 1024
 # The walk unrolls its loop over K/V heads, and each head's scores and
 # weights `[qb·G', group·P]` float32 stand on its stack: at 8 heads
-# (laguna-s-2.1) that fits the default scoped VMEM (58.2 MB); at 30 heads
-# of a tick's 8-row blocks (olmo-hybrid-7b) it takes 59.5 MB, so a walk of
-# more heads is given more of the core's 128 MiB
+# (laguna-s-2.1) that fits the default scoped VMEM; a walk of more heads
+# (grouped queries only: one query head a K/V head takes
+# `_mha_walk_kernel`) is given more of the core's 128 MiB
 _GQA_HEADS_IN_DEFAULT_VMEM = 8
 _GQA_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
@@ -608,9 +608,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, slot_ids,
             # no lower bound: far enough below 0 that the frontier
             # offset never lifts it above
             kv_starts = jnp.full((tokens,), -(2 ** 30), jnp.int32)
-        call = _gqa_paged_call(q.shape, q.dtype, k_pool.shape,
-                               k_pool.dtype, page_tables.shape[1],
-                               int(q_per_slot or 1), interpret)
+        call = _head_major_call(q.shape, q.dtype, k_pool.shape,
+                                k_pool.dtype, page_tables.shape[1],
+                                int(q_per_slot or 1), interpret)
         return call(jnp.asarray(slot_ids, jnp.int32),
                     jnp.asarray(page_tables, jnp.int32).reshape(-1),
                     jnp.asarray(kv_lens, jnp.int32),
@@ -1398,3 +1398,249 @@ def latent_expanded_attention(q, pool, w_uk, w_uv, page_tables, run_slots,
     return call(i32(run_slots), i32(run_row0), i32(run_first), i32(run_rows),
                 i32(page_tables).reshape(-1), q, w_uk,
                 jnp.swapaxes(w_uv, 1, 2), pool)
+
+
+# ---- the head-major walk at ONE query head a K/V head ------------------
+# Below the latent walks on purpose (nothing above moves).
+#
+# Where the query heads ARE the K/V heads (G = 1: multi-head attention),
+# `_gqa_walk_kernel` pads each head's one query row to a sublane tile and
+# multiplies the heads one behind the other: 2 · KV small dependent
+# products a group. This body takes the heads in BLOCKS of `_MHA_HEADS`
+# instead, as `_mxu_body` takes all of a page-major pool's: a block's
+# queries `q[qb·Hb, D]` meet its heads' keys of a span of the group's
+# pages, ONE `[Hb·span·P, D]` operand of the group buffer `[2, KV,
+# group·P, D]` as it lies, in ONE product, a head-match mask keeps each
+# row's own head, and `p · V[Hb·span·P, D]` is exact because a masked
+# weight is 0. The copies, the bounds a row and the finalisation are the
+# head-major walk's.
+
+# the heads a product takes: a sublane tile of bf16 queries, so that a
+# block's `[qb, Hb, D]` queries are `[qb·Hb, D]` as they lie (the query's
+# heads are padded to whole blocks: rows no column matches, which
+# finalise to zeros and are dropped)
+_MHA_HEADS = 16
+# the float32 score tile `[qb·Hb, Hb·span·P]` of one product stays within
+# this: the fused window's lone rows take a whole group of 17 pages in
+# one span (272 KiB), a tick's blocks of 8 rows spans of 8 pages (1 MiB)
+_MHA_SCORE_BYTES = 1024 * 1024
+
+
+def _mha_spans(qb, group, page_size):
+    """A group's pages in spans of one product each, a static function
+    of the block's rows: `((first page, pages), ...)`."""
+    per = _MHA_SCORE_BYTES // (4 * qb * _MHA_HEADS * _MHA_HEADS * page_size)
+    per = max(1, min(group, per))
+    return tuple((p0, min(per, group - p0)) for p0 in range(0, group, per))
+
+
+def _mha_walk_kernel(sid_ref, pt_ref, lens_ref, starts_ref, off_ref, q_ref,
+                     k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref,
+                     m_ref, l_ref, *, pages_per_seq, group, spans):
+    """One grid step = one query block: `q_ref` [qb, nb·Hb, D] (qb rows
+    of ONE slot, the heads padded to `nb` blocks of Hb), the walk over
+    the block's live pages the loop in here, as `_gqa_walk_kernel`
+    walks. `k_buf`/`v_buf` `[2, KV, group·P, D]`; `acc_ref` [nb, qb·Hb,
+    D], `m_ref`/`l_ref` [nb, qb·Hb, 128]: matmul row r of a block is
+    head r % Hb of block row r // Hb; column c of a span of `n` pages is
+    head c // (n·P) of the block at the span's token c % (n·P)."""
+    b = pl.program_id(0)
+    qb, _, dim = q_ref.shape
+    kv_heads, rows = k_buf.shape[1], k_buf.shape[2]
+    page_size = rows // group
+    hb = _MHA_HEADS
+    mm = qb * hb
+    scale = 1.0 / math.sqrt(dim)
+    precision = (jax.lax.Precision.HIGHEST
+                 if k_buf.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    # per-row bounds from SMEM (static unroll); a padding row (length
+    # 0) bounds nothing
+    kvlens, starts = [], []
+    for i in range(qb):
+        base = lens_ref[b * qb + i]
+        kvlens.append(jnp.where(base > 0, base + off_ref[0], 0))
+        starts.append(jnp.maximum(jnp.where(
+            base > 0, starts_ref[b * qb + i] + off_ref[0], 0), 0))
+    kvmax = kvlens[0]
+    first = jnp.where(kvlens[0] > 0, starts[0], 2 ** 30)
+    for i in range(1, qb):
+        kvmax = jnp.maximum(kvmax, kvlens[i])
+        first = jnp.minimum(first, jnp.where(kvlens[i] > 0, starts[i],
+                                             2 ** 30))
+    first_page = jnp.where(kvmax > 0, first, 0) // page_size
+    n_pages = jnp.maximum(
+        jnp.minimum((kvmax + (page_size - 1)) // page_size, pages_per_seq)
+        - first_page, 0)
+    n_groups = (n_pages + (group - 1)) // group
+    # one slot per block (the slot-major contract): its first row names it
+    table = sid_ref[b * qb] * pages_per_seq + first_page
+
+    # a group's dead tail is never copied: what it multiplies (weight
+    # exactly 0) must be finite, so the buffers start from zeros
+    @pl.when(b == 0)
+    def _zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    def live_in(g):
+        return jnp.minimum(group, n_pages - g * group)
+
+    def group_copies(g, half, start_copy):
+        def one(i, carry):
+            page = pt_ref[table + g * group + i] if start_copy else 0
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                dma = pltpu.make_async_copy(
+                    hbm.at[page],
+                    buf.at[half, :, pl.ds(i * page_size, page_size), :],
+                    sems.at[half])
+                dma.start() if start_copy else dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(g), one, None)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    # once a block, outside the group loop: each matmul row's bounds, and
+    # for each shape of product the column's token in its span where its
+    # head is the row's, a token no bound reaches where it is not
+    block_row = jax.lax.broadcasted_iota(jnp.int32, (mm, 1), 0) // hb
+    lo = jnp.zeros((mm, 1), jnp.int32)
+    hi = jnp.zeros((mm, 1), jnp.int32)
+    for i in range(qb):
+        lo = jnp.where(block_row == i, starts[i], lo)
+        hi = jnp.where(block_row == i, kvlens[i], hi)
+    blocks = [(h0, min(hb, kv_heads - h0)) for h0 in range(0, kv_heads, hb)]
+    head_pos = {}
+    for _, heads in blocks:
+        for _, n in spans:
+            width = n * page_size
+            row = jax.lax.broadcasted_iota(jnp.int32, (mm, heads * width), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (mm, heads * width), 1)
+            head_pos[heads, n] = jnp.where(col // width == row % hb,
+                                           col % width, 2 ** 30)
+
+    def attend(g, half, p0, n):
+        """Pages [p0, p0 + n) of group g, every block of heads."""
+        at = pl.ds(p0 * page_size, n * page_size)
+        base = (first_page + g * group + p0) * page_size
+        for blk, (h0, heads) in enumerate(blocks):
+            q = q_ref[:, pl.ds(h0, hb)].reshape(mm, dim)
+            k = k_buf[half, pl.ds(h0, heads), at].reshape(-1, dim)
+            v = v_buf[half, pl.ds(h0, heads), at].reshape(-1, dim)
+            pos = head_pos[heads, n]
+            valid = (pos >= lo - base) & (pos < hi - base)
+            # scaled in f32 AFTER the product: q is not rounded again
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[blk][:, :1]
+            l_prev = l_ref[blk][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a row that has attended nothing yet (a padding row, a
+            # padding head) still has m_new == NEG_INF: the mask again
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[blk] = alpha * acc_ref[blk] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            m_ref[blk] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[blk] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    @pl.when(n_groups > 0)
+    def _first():
+        group_copies(0, 0, True)
+
+    def one_group(g, carry):
+        half = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _next():
+            group_copies(g + 1, 1 - half, True)
+
+        group_copies(g, half, False)
+        live = live_in(g)
+        for p0, n in spans:
+            # a span past the group's live pages holds no row's token
+            @pl.when(p0 < live)
+            def _span(p0=p0, n=n):
+                attend(g, half, p0, n)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, one_group, None)
+    for blk, (h0, _) in enumerate(blocks):
+        l = l_ref[blk][:, :1]
+        # padding rows and heads attended nothing: l == 0 -> zeros
+        o_ref[:, pl.ds(h0, hb)] = (
+            acc_ref[blk] / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype).reshape(qb, hb, dim)
+
+
+@functools.lru_cache(maxsize=None)
+def _mha_paged_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
+                    qb, interpret):
+    """The head-major launch at G = 1 for one set of static shapes, ONE
+    jitted function a set (as `_paged_call`)."""
+    tokens, heads, dim = q_shape
+    _, kv_heads, page_size, kdim = pool_shape
+    if heads != kv_heads or kdim != dim:
+        raise ValueError(
+            f"one query head a K/V head of one head_dim: q {heads} x {dim}, "
+            f"pool {kv_heads} x {kdim}")
+    if not _gqa_walks(page_size, kdim, pool_dtype):
+        raise ValueError(
+            f"a head-major {jnp.dtype(pool_dtype).name} pool needs "
+            f"head_dim % 128 == 0 and pages of whole sublane tiles; got "
+            f"page_size {page_size}, head_dim {kdim}")
+    if tokens % qb:
+        raise ValueError(f"{tokens} rows are not whole blocks of {qb}")
+    page_bytes = kv_heads * page_size * kdim * jnp.dtype(pool_dtype).itemsize
+    group = max(1, min(pages_per_seq,
+                       _GQA_GROUP_VMEM_BYTES // (4 * page_bytes)))
+    width = -(-heads // _MHA_HEADS) * _MHA_HEADS
+    blocks = width // _MHA_HEADS
+    q_spec = pl.BlockSpec((qb, width, dim), lambda b, *_: (b, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    buf = pltpu.VMEM((2, kv_heads, group * page_size, kdim), pool_dtype)
+    stats = pltpu.VMEM((blocks, qb * _MHA_HEADS, 128), jnp.float32)
+    launch = pl.pallas_call(
+        functools.partial(_mha_walk_kernel, pages_per_seq=pages_per_seq,
+                          group=group,
+                          spans=_mha_spans(qb, group, page_size)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(tokens // qb,),
+            in_specs=[q_spec, hbm, hbm], out_specs=q_spec,
+            scratch_shapes=[
+                buf, buf, pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((blocks, qb * _MHA_HEADS, dim), jnp.float32),
+                stats, stats]),
+        out_shape=jax.ShapeDtypeStruct((tokens, width, dim), q_dtype),
+        interpret=interpret,
+    )
+
+    def call(sid, table, lens, starts, off, q, k_pool, v_pool):
+        if width != heads:
+            q = jnp.pad(q, ((0, 0), (0, width - heads), (0, 0)))
+        out = launch(sid, table, lens, starts, off, q, k_pool, v_pool)
+        return out[:, :heads]
+
+    return jax.jit(call, inline=True)
+
+
+def _head_major_call(q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq,
+                     qb, interpret):
+    """The head-major launch for these static shapes, counted in every
+    open `launch_sites()` block by its body: `mha` (`_mha_walk_kernel`)
+    where the query heads are the K/V heads, `gqa` (`_gqa_walk_kernel`)
+    where they share them."""
+    mha = q_shape[1] == pool_shape[1]
+    body = "mha" if mha else "gqa"
+    call = (_mha_paged_call if mha else _gqa_paged_call)(
+        q_shape, q_dtype, pool_shape, pool_dtype, pages_per_seq, qb,
+        interpret)
+    for sites in _open_site_counts.stack:
+        sites[body] = sites.get(body, 0) + 1
+    return call

@@ -43,9 +43,14 @@ def _dense_masked(q, kp, vp, tables, sids, lens, starts):
     return out
 
 
-def _case(dtype, g, page):
+# the walk's page group at 30 K/V heads of 128 (bf16 pages of 16 or
+# float32 pages of 8): 17 pages, 8 MiB over its four buffers
+_GROUP_AT_30_HEADS = 17
+
+
+def _case(dtype, g, page, kv=2, MP=12):
     rng = np.random.default_rng(0)
-    kv, d, S, MP = 2, 128, 3, 12
+    d, S = 128, 3
     n = S * MP + 1
     kp = jnp.asarray(rng.normal(size=(n, kv, page, d)), dtype)
     vp = jnp.asarray(rng.normal(size=(n, kv, page, d)), dtype)
@@ -53,17 +58,37 @@ def _case(dtype, g, page):
     tables = rng.permutation(np.arange(1, n)).reshape(S, MP).astype(
         np.int32)
     sids = np.array([0, 0, 0, 1, 2, 2, 1, 0, 0], np.int32)
-    lens = np.array([5, 6, 7, 90, 33, 34, 0, page * MP - 3, 1], np.int32)
-    return q, kp, vp, tables, sids, lens
+    if MP == 12:
+        lens = [5, 6, 7, 90, 33, 34, 0, page * MP - 3, 1]
+    else:
+        # rows of several page groups: one filling a group exactly (with
+        # and without the frontier offset of 3), one a token into the
+        # next, one into the third, the last page of the table
+        G = _GROUP_AT_30_HEADS * page
+        lens = [5, G - 3, G, 2 * G + 5, 33, G + 1, 0, page * MP - 3, 1]
+    return q, kp, vp, tables, sids, np.array(lens, np.int32)
 
 
 @pytest.mark.parametrize("dtype,page,tol", [("float32", 8, 2e-5),
                                             ("bfloat16", 16, 3e-2)])
-@pytest.mark.parametrize("g,window", [(3, None), (3, 20), (2, 40)])
+# (query heads a K/V head, K/V heads, window, pages a slot); at ONE
+# query head a K/V head the walk takes its heads in blocks
+# (`_mha_walk_kernel`): 30 heads are a block and a short one, 3 less
+# than one; 40 pages a slot at 30 heads walk up to three page groups
+@pytest.mark.parametrize("g,kv,window,mp", [
+    pytest.param(3, 2, None, 12, id="3-None"),
+    pytest.param(3, 2, 20, 12, id="3-20"),
+    pytest.param(2, 2, 40, 12, id="2-40"),
+    pytest.param(1, 30, None, 12, id="1-kv30-None"),
+    pytest.param(1, 30, 20, 12, id="1-kv30-20"),
+    pytest.param(1, 3, None, 12, id="1-kv3-None"),
+    pytest.param(1, 3, 20, 12, id="1-kv3-20"),
+    pytest.param(1, 30, None, 40, id="1-kv30-None-groups"),
+    pytest.param(1, 30, 300, 40, id="1-kv30-300-groups")])
 @pytest.mark.parametrize("offset", [None, 3])
 def test_gqa_kernel_with_a_lower_bound_against_dense_masked_attention(
-        dtype, page, tol, g, window, offset):
-    q, kp, vp, tables, sids, lens = _case(jnp.dtype(dtype), g, page)
+        dtype, page, tol, g, kv, window, mp, offset):
+    q, kp, vp, tables, sids, lens = _case(jnp.dtype(dtype), g, page, kv, mp)
     starts = None if window is None else lens - window
     off = offset or 0
     hi = np.where(lens > 0, lens + off, 0)
@@ -294,28 +319,58 @@ def test_served_through_llmserver_with_the_counters_in_stats():
             "executables": 1, "fused_executables": 1}
 
 
-@pytest.mark.parametrize("rows", [4, 8])
-def test_query_blocks_of_one_slot_read_its_pages_once(rows):
+# (rows a block, K/V heads, query heads a K/V head, dtype, page, tol,
+# pages a slot, window, frontier offset); 40 pages a slot at 30 heads
+# walk up to three page groups of 17 pages
+@pytest.mark.parametrize("rows,kv,g,dtype,page,tol,mp,window,offset", [
+    pytest.param(4, 2, 3, "float32", 8, 2e-5, 12, 24, 0, id="4"),
+    pytest.param(8, 2, 3, "float32", 8, 2e-5, 12, 24, 0, id="8"),
+    pytest.param(8, 30, 1, "bfloat16", 16, 3e-2, 12, 24, 0,
+                 id="8-kv30-g1-bfloat16"),
+    pytest.param(1, 30, 1, "float32", 8, 2e-5, 12, 24, 0,
+                 id="1-kv30-g1-float32"),
+    pytest.param(8, 3, 1, "float32", 8, 2e-5, 12, 24, 0,
+                 id="8-kv3-g1-float32"),
+    pytest.param(1, 3, 1, "bfloat16", 16, 3e-2, 12, 24, 0,
+                 id="1-kv3-g1-bfloat16"),
+    pytest.param(8, 30, 1, "bfloat16", 16, 3e-2, 40, None, 0,
+                 id="8-kv30-g1-bfloat16-groups"),
+    pytest.param(8, 30, 1, "bfloat16", 16, 3e-2, 40, None, 3,
+                 id="8-kv30-g1-bfloat16-groups-offset"),
+    pytest.param(8, 30, 1, "float32", 8, 2e-5, 40, 150, 3,
+                 id="8-kv30-g1-float32-groups-window-offset")])
+def test_query_blocks_of_one_slot_read_its_pages_once(
+        rows, kv, g, dtype, page, tol, mp, window, offset):
     """A tick's rows laid out again in blocks of one slot
     (`SlotBlockLayout`) and the kernel over those blocks give what
     the flat rows give: three prefill chunks of ragged lengths, two
-    decode rows, dead rows at the end."""
+    decode rows, dead rows at the end; at G = 1 through the blocks of
+    heads of `_mha_walk_kernel`, and over several page groups: blocks
+    whose rows end on a group's last page (under the frontier offset
+    too), a chunk into the next group, a block ending two groups in."""
     from paddle_tpu.nn.functional.attention import SlotBlockLayout
 
     rng = np.random.default_rng(3)
-    kv, g, d, page, S, MP = 2, 3, 128, 8, 5, 12
+    d, S, MP = 128, 5, mp
     n = S * MP + 1
-    kp = jnp.asarray(rng.normal(size=(n, kv, page, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n, kv, page, d)), jnp.float32)
+    dtype = jnp.dtype(dtype)
+    kp = jnp.asarray(rng.normal(size=(n, kv, page, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n, kv, page, d)), dtype)
     tables = rng.permutation(np.arange(1, n)).reshape(S, MP).astype(
         np.int32)
-    runs = [(3, 20, 11), (0, 0, 5), (4, 70, 1), (1, 33, 9), (2, 8, 1)]
+    if MP == 12:
+        runs = [(3, 20, 11), (0, 0, 5), (4, 70, 1), (1, 33, 9), (2, 8, 1)]
+    else:
+        G = _GROUP_AT_30_HEADS * page - offset
+        runs = [(3, G - 8, 8), (0, 0, 5), (4, G + 8, 17),
+                (1, 2 * G + offset - 8, 8), (2, 8, 1)]
     sids = np.concatenate([[s] * c for s, _, c in runs] + [[0] * 5])
     lens = np.concatenate([np.arange(a + 1, a + c + 1)
                            for _, a, c in runs] + [[0] * 5])
     sids, lens = sids.astype(np.int32), lens.astype(np.int32)
-    q = jnp.asarray(rng.normal(size=(len(sids), kv * g, d)), jnp.float32)
-    starts = lens - 24
+    q = jnp.asarray(rng.normal(size=(len(sids), kv * g, d)), dtype)
+    # no window: no lower bound (far enough below 0)
+    starts = lens - (window or 2 ** 20)
     lay = SlotBlockLayout(jnp.asarray(sids), jnp.asarray(lens), rows, S)
     dest = np.asarray(lay.dest)
     live = lens > 0
@@ -327,9 +382,80 @@ def test_query_blocks_of_one_slot_read_its_pages_once(rows):
     assert np.array_equal(np.asarray(lay.lens)[dest[live]], lens[live])
     got = ragged_paged_attention(
         lay.spread(q), kp, vp, tables, lay.sids, lay.lens,
-        kv_starts=lay.spread(jnp.asarray(starts)), head_major=True,
+        kv_starts=lay.spread(jnp.asarray(starts)),
+        frontier_offset=offset or None, head_major=True,
         q_per_slot=rows, interpret=True)[lay.dest]
-    want = _dense_masked(q, kp, vp, tables, sids, lens,
-                         np.where(live, starts, 0))
+    hi = np.where(live, lens + offset, 0)
+    want = _dense_masked(q, kp, vp, tables, sids, hi,
+                         np.where(live, starts + offset, 0))
     np.testing.assert_allclose(np.asarray(got, np.float64), want,
-                               atol=2e-5)
+                               atol=tol)
+    assert not np.asarray(got[~live], np.float32).any()   # padding rows
+
+
+def _head_major_model(family):
+    """A model of head-major pools at head_dim 128 (what the walk
+    takes): olmo-hybrid's 3 full heads over 3 K/V heads, or laguna's 4
+    and 6 query heads over 2."""
+    import paddle_tpu as paddle
+    from paddle_tpu.text.models.olmo_hybrid import (OlmoHybridForCausalLM,
+                                                    olmo_hybrid_tiny)
+
+    paddle.seed(7)
+    if family == "olmo":
+        model = OlmoHybridForCausalLM(olmo_hybrid_tiny(
+            hidden_size=384, num_heads=3, num_kv_heads=3))
+    else:
+        model = LagunaForCausalLM(laguna_tiny(head_dim=128))
+    model.eval()
+    return model
+
+
+def _serve_two(model):
+    eng = LLMEngine(model, LLMEngineConfig(
+        num_slots=2, page_size=16, max_model_len=64, token_budget=16,
+        decode_k=4))
+    reqs = [eng.add_request(np.arange(n) % 250, max_new_tokens=7)
+            for n in (5, 19)]
+    while eng.has_work():
+        eng.step()
+    return eng, [r.future.result(timeout=0) for r in reqs]
+
+
+@pytest.mark.parametrize("family,body,layers", [("olmo", "mha", 1),
+                                                ("laguna", "gqa", 9)])
+def test_head_major_launches_are_counted_by_body(monkeypatch, family, body,
+                                                 layers):
+    """With the walk forced on (interpreted): a model whose query heads
+    are its K/V heads runs every head-major launch through the `mha`
+    body, one whose queries share them through the `gqa` body; a tick
+    adds a launch a paged layer, a window of k that k times; the served
+    tokens are the plain path's. Both counters read 0 on the plain
+    path."""
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.ops.pallas_kernels import paged_attention as pak
+    from paddle_tpu.text.models import olmo_hybrid
+
+    model = _head_major_model(family)
+    eng, want = _serve_two(model)
+    for name in ("paged_attn_mha_launches", "paged_attn_gqa_launches"):
+        assert eng.stats[name] == 0 == eng.metrics()[name]
+    kernel = pak.ragged_paged_attention
+    monkeypatch.setattr(attention, "_pallas_backend_ok", lambda: True)
+    monkeypatch.setattr(olmo_hybrid, "_pallas_backend_ok", lambda: True)
+    monkeypatch.setattr(
+        pak, "ragged_paged_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
+    eng, served = _serve_two(model)
+    windows = eng.stats["fused_steps"]
+    ticks = eng.stats["steps"] - windows
+    assert windows > 0 and ticks > 0
+    other = "gqa" if body == "mha" else "mha"
+    assert eng._step_fn.launches[body] == layers
+    assert eng._fused_fn.launches[body] == layers * 4
+    assert other not in eng._step_fn.launches
+    assert eng.stats[f"paged_attn_{body}_launches"] == layers * (
+        ticks + 4 * windows) == eng.metrics()[f"paged_attn_{body}_launches"]
+    assert eng.stats[f"paged_attn_{other}_launches"] == 0
+    for got, ref in zip(served, want):
+        np.testing.assert_array_equal(got, ref)

@@ -389,11 +389,60 @@ def test_the_delta_rules_chunk_kernel_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < T * H * dk * 4 // 10
 
 
+def _head_major_walk(call, args):
+    """The head-major launch `call` makes on `args`: the body counted by
+    `launch_sites()`, the kernel function's name and its compiler
+    parameters."""
+    from paddle_tpu.ops.pallas_kernels.paged_attention import launch_sites
+
+    with launch_sites() as sites, jax.enable_x64(False):
+        jaxpr = jax.make_jaxpr(call)(*args)
+    (launch,) = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+    kernel = launch.params["jaxpr"].debug_info.func_src_info.split()[0]
+    return ({b: n for b, n in sites.items() if n}, kernel,
+            dict(launch.params["compiler_params"]))
+
+
+# laguna-s-2.1's launches as the model makes them (the fused window's 48
+# rows, the tick's 512 in blocks of 8 rows of one slot), full and sliding
+# layers: grouped queries keep `_gqa_walk_kernel` and the default scoped
+# VMEM (a raised limit for every head count cost that cell 7.8 %:
+# PERF.md §6)
+@pytest.mark.parametrize("tokens,heads,pages,qps", [
+    pytest.param(48, 48, 13313, None, id="full_window_rows"),
+    pytest.param(848, 48, 13313, 8, id="full_tick_blocks"),
+    pytest.param(48, 72, 1821, None, id="sliding_window_rows"),
+    pytest.param(848, 72, 1821, 8, id="sliding_tick_blocks"),
+])
+def test_grouped_queries_keep_the_gqa_walk_and_its_compiler_parameters(
+        one_chip, tokens, heads, pages, qps):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (pages, 8, 16, 128)
+    args = [sds((tokens, heads, 128), jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16),
+            sds((48, 320), jnp.int32), sds((tokens,), jnp.int32),
+            sds((tokens,), jnp.int32), sds((tokens,), jnp.int32),
+            sds((), jnp.int32)]
+
+    def call(q, k, v, pt, sid, lens, starts, off):
+        return ragged_paged_attention(
+            q, k, v, pt, sid, lens, kv_starts=starts,
+            frontier_offset=off, head_major=True, q_per_slot=qps)
+
+    assert _head_major_walk(call, args) == (
+        {"gqa": 1}, "_gqa_walk_kernel", {})
+
+
 # olmo-hybrid-7b's full layers: the head-major walk with ONE query head a
 # K/V head (30 of 128, bf16 pools [8 739, 30, 16, 128] = 4 GiB over two
 # layers): the fused window's 128 rows and the tick's 512 in blocks of 8
-# rows of one slot (512 + 128 · 7 rows). 30 heads' scores and weights on
-# the kernel's stack need the raised scoped VMEM (59.5 MB in the tick).
+# rows of one slot (512 + 128 · 7 rows). Both take `_mha_walk_kernel`
+# (blocks of 16 heads, a float32 score tile of at most 1 MiB a product)
+# inside the default scoped VMEM; the parent's head-at-a-time body needed
+# 59.5 MB for the tick's 30 unrolled heads and a raised limit.
 @pytest.mark.parametrize("tokens,qps", [
     pytest.param(128, None, id="window_rows"),
     pytest.param(1408, 8, id="tick_blocks"),
@@ -414,6 +463,8 @@ def test_head_major_walk_at_one_query_head_a_kv_head_compiles_for_v5e(
                                       frontier_offset=off, head_major=True,
                                       q_per_slot=qps)
 
+    assert _head_major_walk(call, args) == ({"mha": 1}, "_mha_walk_kernel",
+                                            {})
     with jax.enable_x64(False):
         text = jax.jit(call).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
